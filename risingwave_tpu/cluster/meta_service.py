@@ -2836,9 +2836,18 @@ class MetaService:
 
     def state(self) -> dict:
         """The ctl/dashboard surface (risectl cluster-info analog)."""
+        import sys
+
+        backend = False
+        if "jax" in sys.modules:
+            from jax._src import xla_bridge
+            backend = xla_bridge.backends_are_initialized()
         now = time.monotonic()
         with self._lock:
             return {
+                # the meta plans and routes; it must never take a chip
+                # from a compute worker on the same host
+                "backend_initialized": backend,
                 "cluster_epoch": self.cluster_epoch,
                 "manifest_epoch":
                     self.versions.current.max_committed_epoch,

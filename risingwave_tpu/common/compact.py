@@ -21,6 +21,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @functools.cache
@@ -62,10 +63,36 @@ def segment_start_positions(starts: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.cummax(jnp.where(starts, idx, 0))
 
 
+def _cumsum_int64(values: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive cumsum of 64-bit integers built from 32-bit scans.
+
+    A 64-bit ``reduce-window`` inside a loop body is refused by the
+    TPU compiler (libtpu 0.0.34: scoped vmem exhausted for lengths
+    2^14..2^16, e.g. q5's 40,960-row pane chunks in the barrier drain
+    loop).  The two's-complement sum is exact modulo 2^64 from limb-wise
+    uint32 scans, the limbs narrow enough that ``n`` of them cannot
+    overflow 32 bits."""
+    n = values.shape[0]
+    bits = 32 - max(n - 1, 1).bit_length()
+    u = values.astype(jnp.uint64)
+    mask = np.uint64((1 << bits) - 1)
+    acc = jnp.zeros_like(u)
+    for shift in range(0, 64, bits):
+        limb = ((u >> np.uint64(shift)) & mask).astype(jnp.uint32)
+        acc = acc + (
+            jnp.cumsum(limb, dtype=jnp.uint32).astype(jnp.uint64)
+            << np.uint64(shift)
+        )
+    return acc.astype(values.dtype)
+
+
 def segmented_sum(values: jnp.ndarray, start_pos: jnp.ndarray) -> jnp.ndarray:
     """Inclusive segmented running sum; the value at each segment's END
-    is the segment total.  cumsum + gather-of-prefix — 4 ops total."""
-    c = jnp.cumsum(values, axis=0, dtype=values.dtype)
+    is the segment total.  cumsum + gather-of-prefix."""
+    if values.dtype in (jnp.int64, jnp.uint64):
+        c = _cumsum_int64(values)
+    else:
+        c = jnp.cumsum(values, axis=0, dtype=values.dtype)
     prev = jnp.maximum(start_pos - 1, 0)
     base = jnp.where(start_pos > 0, c[prev], jnp.zeros((), values.dtype))
     return c - base

@@ -37,17 +37,45 @@ class StorageConfig:
 
 @dataclass
 class StateConfig:
-    """capacity knobs for device state tables (planner defaults)."""
+    """capacity knobs for device state tables (the planner's sizes;
+    ``sql.planner.PlannerConfig`` is this plus the chunk capacity)."""
 
     agg_table_size: int = 1 << 16
     agg_emit_capacity: int = 4096
     join_table_size: int = 1 << 14
     join_bucket_cap: int = 64
     join_out_capacity: int = 1 << 15
+    join_left_table_size: int | None = None
+    join_right_table_size: int | None = None
+    join_left_bucket_cap: int | None = None
+    join_right_bucket_cap: int | None = None
+    #: shared row-pool capacity for degree-adaptive (append-only) join
+    #: sides — replaces dense [size, bucket] buckets so hot keys have
+    #: no per-key cap (ref JoinHashMap's unbounded per-key rows)
+    join_pool_size: int = 1 << 16
+    #: force dense per-key bucket storage even for append-only sides.
+    #: Pool sides bound emission drains by the POOL size, which makes
+    #: `max_windows` large; on deep multiway plans (TPC-H q8/q9) the
+    #: drain while_loop bodies then embed the downstream subgraph and
+    #: XLA:CPU compile memory explodes.  Dense buckets bound drains by
+    #: bucket_cap — with out_capacity >= chunk*2*bucket_cap the plan
+    #: compiles FLAT (no drain loops).  Conformance runs set this.
+    join_force_dense: bool = False
     topn_pool_size: int = 4096
     topn_emit_capacity: int = 1024
     mv_table_size: int = 1 << 16
     mv_ring_size: int = 1 << 20
+    #: per-group value capacity for retractable min/max (ref minput.rs)
+    minput_bucket_cap: int = 64
+    #: dedup-table size per DISTINCT agg call (None = agg_table_size);
+    #: sized for groups x distinct values, not groups
+    distinct_table_size: int | None = None
+    #: overflow-row ring capacity for non-windowed aggs (None = 4x
+    #: chunk_capacity; 0 disables spill-to-host — overflow is then a
+    #: loud error)
+    agg_spill_ring: int | None = None
+    #: host-tier table size (None = 8x agg_table_size)
+    agg_spill_table_size: int | None = None
 
 
 @dataclass

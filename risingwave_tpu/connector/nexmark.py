@@ -76,13 +76,6 @@ def _rand_int(event_id, stream: int, bound: int) -> jnp.ndarray:
     return (_rand(event_id, stream) % np.uint64(bound)).astype(jnp.int64)
 
 
-def _rand_unit(event_id, stream: int) -> jnp.ndarray:
-    """float64 in [0,1)."""
-    return (_rand(event_id, stream) >> np.uint64(11)).astype(jnp.float64) / np.float64(
-        1 << 53
-    )
-
-
 # ---------------------------------------------------------------------------
 # id chaining (canonical generator arithmetic, vectorized)
 
@@ -125,10 +118,43 @@ def _next_base0_auction_id(event_id: jnp.ndarray, stream: int) -> jnp.ndarray:
     )
 
 
+#: log-uniform price curve, ``round(100 * 10^(6 i / 1024))`` at 1,025
+#: knots.  Built with ``decimal`` (software arithmetic, the same digits
+#: on every host), never with a libm ``pow``.
+_PRICE_KNOT_BITS = 10
+
+
+def _price_knots() -> np.ndarray:
+    import decimal
+
+    with decimal.localcontext() as ctx:
+        ctx.prec = 50
+        ln10 = decimal.Decimal(10).ln()
+        n = 1 << _PRICE_KNOT_BITS
+        return np.asarray([
+            int((ln10 * (2 + decimal.Decimal(6 * i) / n)).exp()
+                .to_integral_value(decimal.ROUND_HALF_EVEN))
+            for i in range(n + 1)
+        ], np.int64)
+
+
+_PRICE_KNOTS = _price_knots()
+
+
 def _next_price(event_id: jnp.ndarray, stream: int) -> jnp.ndarray:
-    """Canonical nextPrice: round(10^(U*6) * 100) — long-tail prices."""
-    u = _rand_unit(event_id, stream)
-    return jnp.round(10.0 ** (u * 6.0) * 100.0).astype(jnp.int64)
+    """Canonical nextPrice's long tail — 100 * 10^(6 U), U uniform —
+    in integer arithmetic alone: the top bits of the draw pick a knot of
+    the curve, the next 32 interpolate to the following one.  The chip
+    emulates float64 and rounds ``10.0 ** x`` differently from a host
+    (5% of prices came out one unit apart), and a source that differs by
+    backend breaks every comparison, replay and recovery across them."""
+    r = _rand(event_id, stream)
+    knot = (r >> np.uint64(64 - _PRICE_KNOT_BITS)).astype(jnp.int32)
+    frac = (r >> np.uint64(32 - _PRICE_KNOT_BITS)) & np.uint64(0xFFFFFFFF)
+    knots = jnp.asarray(_PRICE_KNOTS)
+    lo, hi = knots[knot], knots[knot + 1]
+    step = ((hi - lo).astype(jnp.uint64) * frac) >> np.uint64(32)
+    return lo + step.astype(jnp.int64)
 
 
 # ---------------------------------------------------------------------------

@@ -25,7 +25,6 @@ VNODE_COUNT, then vnode→shard by contiguous ranges), so elastic rescale
 
 from __future__ import annotations
 
-import inspect
 from typing import Sequence
 
 import jax
@@ -35,29 +34,28 @@ import numpy as np
 from risingwave_tpu.common.chunk import Chunk, NCol, StrCol
 from risingwave_tpu.common.hash import VNODE_COUNT, compute_vnodes
 
-try:  # jax >= 0.8 (top-level export)
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-#: the replication/varying-manual-axes check kwarg was renamed across
-#: jax releases (check_rep -> check_vma); resolve the spelling once so
-#: every shard_map site works on whichever jax the container bakes in
-_CHECK_KW = next(
-    (kw for kw in ("check_vma", "check_rep")
-     if kw in inspect.signature(_shard_map_impl).parameters),
-    None,
-)
-
 
 def shard_map_nocheck(body, *, mesh, in_specs, out_specs):
-    """``shard_map`` with the replication check disabled, under whatever
-    keyword this jax spells it (check_vma / check_rep) — the per-shard
-    streaming bodies intentionally mix replicated and varying values."""
-    kw = {_CHECK_KW: False} if _CHECK_KW else {}
-    return _shard_map_impl(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
+    """``shard_map`` with the varying-manual-axes check disabled — the
+    per-shard streaming bodies intentionally mix replicated and varying
+    values."""
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
+
+
+def axis_min(x, axis: str):
+    """Minimum of a per-shard scalar over a mesh axis.  Not
+    ``lax.pmin``: the TPU compiler lowers a 64-bit all-reduce for sums
+    only ("Supported lowering only of Sum all reduce"), and watermarks
+    and pending counts are int64 — gather the scalars and reduce here."""
+    return jnp.min(jax.lax.all_gather(x, axis), axis=0)
+
+
+def axis_max(x, axis: str):
+    """Maximum over a mesh axis (see ``axis_min``)."""
+    return jnp.max(jax.lax.all_gather(x, axis), axis=0)
 
 
 #: trace-time exchange audit (profile_q8 --assert --sharded): each

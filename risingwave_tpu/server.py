@@ -34,8 +34,10 @@ from __future__ import annotations
 
 import argparse
 import json
+import sys
 import threading
 import time
+import traceback
 
 
 def _start_metrics_http(render, host: str, port: int):
@@ -67,6 +69,24 @@ def _start_metrics_http(render, host: str, port: int):
     return httpd
 
 
+def _handshake(role: str, **fields) -> None:
+    """The one JSON line every role prints once it is up: ports and
+    ids, whether the native codec loaded (built here if need be), and
+    what JAX sees.  The meta never asks for devices and the serving
+    replica never imports jax — they say only that much (``fields``)."""
+    from risingwave_tpu.storage.codec import native_available
+
+    line = {"role": role, **fields, "native_codec": native_available()}
+    if role in ("single", "compute"):
+        import jax
+
+        devs = jax.devices()
+        line.update(platform=devs[0].platform,
+                    device_kind=devs[0].device_kind,
+                    device_count=len(devs))
+    print(json.dumps(line), flush=True)
+
+
 class SingleNode:
     def __init__(self, config=None, data_dir: str | None = None):
         from risingwave_tpu.sql.engine import Engine
@@ -83,11 +103,21 @@ class SingleNode:
                 self.engine.system_params.get("barrier_interval_ms")
             ) / 1000.0
             t0 = time.monotonic()
-            with self._lock:
-                if self.engine.jobs:
-                    self.engine.tick(barriers=1)
+            try:
+                with self._lock:
+                    if self.engine.jobs:
+                        self.engine.tick(barriers=1)
+            except Exception as e:
+                # a barrier that raised (state overflow, a failed
+                # upload) is counted and logged, never a silently dead
+                # ticker behind a live pgwire port
+                self.engine.metrics.inc("barrier_loop_errors_total")
+                print(f"barrier loop failed: {e!r}", file=sys.stderr)
+                traceback.print_exc()
             elapsed = time.monotonic() - t0
-            self._stop.wait(max(interval - elapsed, 0.0))
+            # never zero: a saturated loop that re-takes the engine
+            # lock at once starves the pgwire sessions waiting for it
+            self._stop.wait(max(interval - elapsed, 0.001))
 
     def start(self, host: str = "127.0.0.1", port: int = 4566,
               ticker: bool = True):
@@ -104,6 +134,16 @@ class SingleNode:
         # pgwire statements and the ticker share the engine lock
         server = pg_serve(self.engine, host, port, engine_lock=self._lock)
         return server
+
+    def render_metrics(self) -> str:
+        """The scrape: the registry, after the on-demand collectors
+        (device readbacks, so between barriers, under the engine
+        lock)."""
+        with self._lock:
+            self.engine.collect_join_metrics()
+            self.engine.collect_checkpoint_metrics()
+            self.engine.collect_shard_metrics()
+        return self.engine.metrics.render_prometheus()
 
     def tick(self, barriers: int = 1,
              chunks_per_barrier: int | None = None) -> None:
@@ -154,11 +194,9 @@ def _run_meta(args) -> None:
     if args.metrics_port:
         _start_metrics_http(meta.metrics.render_prometheus,
                             args.host, args.metrics_port)
-    print(json.dumps({
-        "role": "meta", "pgwire_port": args.port,
-        "rpc_port": meta.rpc_port,
-        "metrics_port": args.metrics_port or None,
-    }), flush=True)
+    _handshake("meta", pgwire_port=args.port, rpc_port=meta.rpc_port,
+               metrics_port=args.metrics_port or None,
+               backend_initialized=meta.state()["backend_initialized"])
 
     stop = threading.Event()
 
@@ -167,8 +205,12 @@ def _run_meta(args) -> None:
             t0 = time.monotonic()
             try:
                 meta.tick()
-            except Exception:
-                pass  # incomplete rounds retry next interval
+            except Exception as e:
+                # incomplete rounds retry next interval — counted and
+                # logged, never silent
+                meta.metrics.inc("cluster_tick_errors_total")
+                print(f"meta tick failed: {e!r}", file=sys.stderr)
+                traceback.print_exc()
             elapsed = time.monotonic() - t0
             stop.wait(max(args.barrier_interval_ms / 1000.0 - elapsed,
                           0.0))
@@ -187,25 +229,27 @@ def _run_meta(args) -> None:
         server.shutdown()
 
 
-def _run_compute(args) -> None:
-    from risingwave_tpu.cluster import ComputeWorker
+def _node_config(args):
+    """``--config-json`` as an RwConfig (single and compute roles)."""
     from risingwave_tpu.common.config import RwConfig
 
-    config = RwConfig.from_dict(json.loads(args.config_json)) \
+    return RwConfig.from_dict(json.loads(args.config_json)) \
         if args.config_json else None
+
+
+def _run_compute(args) -> None:
+    from risingwave_tpu.cluster import ComputeWorker
+
     worker = ComputeWorker(
-        args.meta, args.data_dir or "./data", config=config,
+        args.meta, args.data_dir or "./data", config=_node_config(args),
         host=args.host, port=args.rpc_port,
         heartbeat_interval_s=args.heartbeat_interval,
     ).start()
     if args.metrics_port:
         _start_metrics_http(worker.engine.metrics.render_prometheus,
                             args.host, args.metrics_port)
-    print(json.dumps({
-        "role": "compute", "worker_id": worker.worker_id,
-        "port": worker.port,
-        "metrics_port": args.metrics_port or None,
-    }), flush=True)
+    _handshake("compute", worker_id=worker.worker_id, port=worker.port,
+               metrics_port=args.metrics_port or None)
     try:
         while True:
             time.sleep(3600)
@@ -214,8 +258,6 @@ def _run_compute(args) -> None:
 
 
 def _run_serving(args) -> None:
-    import sys
-
     from risingwave_tpu.serve import ServingWorker
 
     replica = ServingWorker(
@@ -230,14 +272,12 @@ def _run_serving(args) -> None:
     if args.metrics_port:
         _start_metrics_http(replica.metrics.render_prometheus,
                             args.host, args.metrics_port)
-    print(json.dumps({
-        "role": "serving", "replica_id": replica.replica_id,
-        "port": replica.port,
-        "metrics_port": args.metrics_port or None,
-        # the engine-free contract, surfaced at the handshake: tests
-        # parse this line and assert jax never loaded
-        "jax_loaded": "jax" in sys.modules,
-    }), flush=True)
+    # the engine-free contract: tests parse this line and assert jax
+    # never loaded
+    _handshake("serving", replica_id=replica.replica_id,
+               port=replica.port,
+               metrics_port=args.metrics_port or None,
+               jax_loaded="jax" in sys.modules)
     try:
         while True:
             time.sleep(3600)
@@ -259,7 +299,7 @@ def main() -> None:
                    help="meta RPC address (compute/serving roles)")
     p.add_argument("--data-dir", default=None)
     p.add_argument("--config-json", default=None,
-                   help="RwConfig overrides as JSON (compute role)")
+                   help="RwConfig overrides as JSON (single/compute roles)")
     p.add_argument("--heartbeat-interval", type=float, default=0.5)
     p.add_argument("--heartbeat-timeout", type=float, default=3.0)
     p.add_argument("--barrier-interval-ms", type=int, default=1000)
@@ -332,13 +372,13 @@ def main() -> None:
     if args.role == "serving":
         _run_serving(args)
         return
-    node = SingleNode(data_dir=args.data_dir)
+    node = SingleNode(_node_config(args), data_dir=args.data_dir)
     server = node.start(args.host, args.port)
     if args.metrics_port:
-        _start_metrics_http(node.engine.metrics.render_prometheus,
+        _start_metrics_http(node.render_metrics,
                             args.host, args.metrics_port)
-    print(f"listening on {args.host}:{args.port} (psql -h {args.host} "
-          f"-p {args.port} any_db)")
+    _handshake("single", pgwire_port=args.port,
+               metrics_port=args.metrics_port or None)
     try:
         while True:
             time.sleep(3600)

@@ -14,6 +14,7 @@ scheduler (SURVEY.md §2.4), and the batch local-execution mode
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from collections import deque
 from typing import Any, Sequence
@@ -52,8 +53,6 @@ from risingwave_tpu.stream.runtime import StreamingJob
 
 def _ast_map(node, fn):
     """Bottom-up structural map over the (frozen-dataclass) SQL AST."""
-    import dataclasses
-
     if dataclasses.is_dataclass(node) and not isinstance(node, type):
         changed = {}
         for f in dataclasses.fields(node):
@@ -156,18 +155,9 @@ class Engine:
         self.catalog = Catalog()
         if isinstance(config, RwConfig):
             self.rw_config = config
-            st = config.state
             self.config = PlannerConfig(
                 chunk_capacity=config.streaming.chunk_size,
-                agg_table_size=st.agg_table_size,
-                agg_emit_capacity=st.agg_emit_capacity,
-                join_table_size=st.join_table_size,
-                join_bucket_cap=st.join_bucket_cap,
-                join_out_capacity=st.join_out_capacity,
-                topn_pool_size=st.topn_pool_size,
-                topn_emit_capacity=st.topn_emit_capacity,
-                mv_table_size=st.mv_table_size,
-                mv_ring_size=st.mv_ring_size,
+                **dataclasses.asdict(config.state),
             )
             data_dir = data_dir or config.storage.data_directory
         else:
@@ -1026,8 +1016,6 @@ class Engine:
         semantics can never drift from the device kernels (the old
         interpreted `_serve_agg` path re-implemented SQL in host
         Python; it is gone)."""
-        import dataclasses
-
         key = repr(select)
         if not hasattr(self, "_serving_cache"):
             self._serving_cache: dict = {}
@@ -1182,8 +1170,6 @@ class Engine:
         )
 
     def _build_dag_job(self, plan: DagPlan, name: str, ckpt_freq: int):
-        import dataclasses
-
         taps = {n: r for n, r in plan.sources.items()
                 if isinstance(r, MvTap)}
         if not taps:
@@ -1705,8 +1691,6 @@ class Engine:
                 i += 1
             rename[sname] = new_name
             a.sources[new_name] = reader
-
-        import dataclasses
 
         def remap(ref):
             kind, key = ref
@@ -2398,6 +2382,60 @@ class Engine:
         for job in self.jobs:
             if hasattr(job, "drain_uploads"):
                 job.drain_uploads()
+
+    def collect_shard_metrics(self) -> None:
+        """How a mesh job's rows and state spread over its shards — on
+        demand, like collect_join_metrics (device readbacks).
+
+        ``shard_rows{job,shard,op}``: rows in the groups each shard's
+        aggregations hold (``agg`` — as received after the exchange, so
+        partials where a local pre-aggregation ran), rows its joins
+        emitted (``join_out``) and rows its MVs hold (``mv``).
+        ``shard_state_bytes{job,device}``: bytes of the
+        job's state on each device, from where the arrays actually
+        live — a job that landed on one chip shows as one device."""
+        import jax as _jax
+
+        for job in self.jobs:
+            n = getattr(getattr(job, "sharded", job), "n_shards", 1)
+            if n <= 1:
+                continue
+            rows: dict[str, np.ndarray] = {}
+
+            def add(op: str, per_shard) -> None:
+                v = np.asarray(per_shard).reshape(n, -1).sum(axis=1)
+                rows[op] = rows.get(op, 0) + v
+
+            def walk(st) -> None:
+                if hasattr(st, "row_count"):
+                    add("agg", st.row_count)
+                elif hasattr(st, "emit_rows"):
+                    add("join_out", st.emit_rows)
+                elif hasattr(st, "cursor") and hasattr(st, "values"):
+                    add("mv", st.cursor)
+                elif hasattr(st, "table") and hasattr(st, "values"):
+                    add("mv", st.table.occupied)
+                elif isinstance(st, (tuple, list)):
+                    for x in st:
+                        walk(x)
+
+            walk(job.states)
+            for op, per_shard in rows.items():
+                for shard, v in enumerate(per_shard):
+                    self.metrics.set_gauge(
+                        "shard_rows", int(v), job=job.name,
+                        shard=str(shard), op=op,
+                    )
+            by_device: dict[int, int] = {}
+            for leaf in _jax.tree.leaves(job.states):
+                for sh in leaf.addressable_shards:
+                    by_device[sh.device.id] = \
+                        by_device.get(sh.device.id, 0) + sh.data.nbytes
+            for dev, nbytes in by_device.items():
+                self.metrics.set_gauge(
+                    "shard_state_bytes", nbytes, job=job.name,
+                    device=str(dev),
+                )
 
     def collect_checkpoint_metrics(self) -> None:
         """Snapshot-pipeline observability requiring a device readback
@@ -3206,11 +3244,6 @@ class Engine:
         - ``join_drain_windows_per_chunk``: emission windows per probe
           chunk (1 = no amplification re-dispatch).
 
-        Plus ``dag_fused_fallback_total{reason}``: windows a DagJob
-        could NOT run as one fused dispatch (staged plan, host-chunk
-        source) — a silent degradation to per-chunk host dispatches is
-        a throughput cliff, so it is counted per reason.
-
         Sharded jobs export the same gauges with counters SUMMED over
         the shard axis (chunks count per-shard pulls, so per-chunk
         ratios stay comparable to the linear job's).
@@ -3223,11 +3256,6 @@ class Engine:
         for job in self.jobs:
             if not isinstance(job, DagJob):
                 continue
-            for reason, count in job.fused_fallbacks.items():
-                self.metrics.set_gauge(
-                    "dag_fused_fallback_total", count,
-                    job=job.name, reason=reason,
-                )
             n_shards = job.n_shards
             for idx, node in enumerate(job.nodes):
                 if not isinstance(node, JoinNode):

@@ -256,30 +256,24 @@ class CheckpointStore:
                 off += nb
                 if not leaf_dirty.any():
                     continue
-                flat = jnp.asarray(x).reshape(-1)
+                # ONE transfer for a leaf with anything dirty, runs
+                # cut on the host: a device slice per run is a program
+                # per run (its bounds are static), which on the chip
+                # cost q8 18 s a barrier in compiles and round trips
+                flat = np.asarray(x).reshape(-1)
                 n = flat.shape[0]
                 rows, m = ln if ln else (1, n)
                 nb_row = nb // rows
                 for r in range(rows):
                     row_dirty = leaf_dirty[r * nb_row:(r + 1) * nb_row]
-                    if ln and not row_dirty.any():
-                        continue
                     base_el = r * m
                     # coalesce adjacent dirty blocks into runs
-                    b = 0
-                    while b < nb_row:
-                        if not row_dirty[b]:
-                            b += 1
-                            continue
-                        e = b
-                        while e + 1 < nb_row and row_dirty[e + 1]:
-                            e += 1
-                        s_el = base_el + b * block
-                        e_el = base_el + min((e + 1) * block, m)
-                        payload[f"r_{i}_{s_el}"] = np.asarray(
-                            flat[s_el:e_el]
-                        )
-                        b = e + 1
+                    edges = np.flatnonzero(np.diff(
+                        np.r_[False, row_dirty, False]))
+                    for b, e in zip(edges[::2], edges[1::2]):
+                        s_el = base_el + int(b) * block
+                        e_el = base_el + min(int(e) * block, m)
+                        payload[f"r_{i}_{s_el}"] = flat[s_el:e_el].copy()
         return {
             "job": job_name, "epoch": epoch, "kind": kind,
             "payload": payload, "treedef": treedef,

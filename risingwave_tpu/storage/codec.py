@@ -4,7 +4,8 @@ The C++ library (native/rwtpu_codec.cpp) implements the hot host-side
 loops: memcomparable scalar encoding, varint block encode/decode,
 crc32c.  Built on first use with g++ and cached beside the source; a
 pure-numpy fallback keeps the storage layer functional without a
-toolchain.
+toolchain — a failed build or load is logged once with its reason and
+``native_available()`` says false.
 """
 
 from __future__ import annotations
@@ -12,6 +13,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -37,10 +39,20 @@ def _load():
         try:
             if (not os.path.exists(_SO)
                     or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-                subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", _SRC, "-o", _SO],
-                    check=True, capture_output=True,
-                )
+                # several roles may start on one checkout: build under
+                # a name of this process's own and rename into place,
+                # so nobody ever loads a half-written file
+                tmp = f"{_SO}.{os.getpid()}.tmp"
+                try:
+                    subprocess.run(
+                        ["g++", "-O3", "-shared", "-fPIC", _SRC,
+                         "-o", tmp],
+                        check=True, capture_output=True,
+                    )
+                    os.replace(tmp, _SO)
+                finally:
+                    if os.path.exists(tmp):
+                        os.unlink(tmp)
             lib = ctypes.CDLL(_SO)
             u8p = ctypes.POINTER(ctypes.c_uint8)
             i64p = ctypes.POINTER(ctypes.c_int64)
@@ -60,8 +72,13 @@ def _load():
             lib.rw_crc32c.argtypes = [u8p, ctypes.c_int64]
             lib.rw_crc32c.restype = ctypes.c_uint32
             _lib = lib
-        except Exception:
+        except Exception as e:
             _native_failed = True
+            reason = getattr(e, "stderr", None) or e
+            if isinstance(reason, bytes):
+                reason = reason.decode(errors="replace")
+            print(f"native codec unavailable, using numpy: {reason}",
+                  file=sys.stderr, flush=True)
     return _lib
 
 

@@ -88,9 +88,15 @@ def _pack_words(x, nb: int, block: int) -> jnp.ndarray | None:
     pad = nb * block - flat.shape[0]
     if pad:  # trace-time: aligned leaves never materialize a pad copy
         flat = jnp.pad(flat, (0, pad))
-    lanes = flat.reshape(-1, k).astype(jnp.uint64)
-    shifts = (np.arange(k, dtype=np.uint64) * np.uint64(bits))
-    return jnp.sum(lanes << shifts[None, :], axis=1, dtype=jnp.uint64)
+    # word j of a block packs element j of each of its k contiguous
+    # ``block/k``-element runs.  The minor axis stays ``block/k`` wide:
+    # a ``[n/k, k]`` view pads k up to 128 lanes in the TPU's tiled
+    # layout (16x for bytes — q8's 2^23-row string ring asked for 12 GiB)
+    runs = flat.reshape(nb, k, block // k).astype(jnp.uint64)
+    words = runs[:, 0]
+    for j in range(1, k):
+        words = words | (runs[:, j] << np.uint64(j * bits))
+    return words.reshape(-1)
 
 
 def leaf_digest(x, nb: int, block: int) -> jnp.ndarray:

@@ -51,7 +51,11 @@ from risingwave_tpu.stream.runtime import (
     rewind_spill_tier,
 )
 
-from risingwave_tpu.parallel.exchange import shard_map_nocheck
+from risingwave_tpu.parallel.exchange import (
+    axis_max,
+    axis_min,
+    shard_map_nocheck,
+)
 
 #: a dataflow edge endpoint: ("source", name) or ("node", node_id)
 Ref = tuple
@@ -136,7 +140,7 @@ class DagJob(CheckpointPipelineMixin):
         #: windows that could NOT run as one fused dispatch, by reason
         #: (observability: a silent degradation to per-chunk host
         #: dispatches is a throughput cliff — exported as
-        #: ``dag_fused_fallback_total{reason}`` by collect_join_metrics)
+        #: ``dag_fused_fallback_total{reason}`` as it is counted)
         self.fused_fallbacks: dict[str, int] = {}
         self.maintenance_interval = 1
         self._ckpts_since_maintain = 0
@@ -444,7 +448,7 @@ class DagJob(CheckpointPipelineMixin):
         # empty chunks, which are harmless)
         total = pending.total
         if self.mesh is not None:
-            total = jax.lax.pmax(total, self.AXIS)
+            total = axis_max(total, self.AXIS)
 
         def cond(carry):
             sts, w = carry
@@ -768,18 +772,40 @@ class DagJob(CheckpointPipelineMixin):
             reason = "host_chunk_source"
         if reason is not None or n == 1:
             if reason is not None and n > 1:
-                self.fused_fallbacks[reason] = \
-                    self.fused_fallbacks.get(reason, 0) + 1
+                count = self.fused_fallbacks.get(reason, 0) + 1
+                self.fused_fallbacks[reason] = count
+                if self.metrics is not None:
+                    self.metrics.set_gauge(
+                        "dag_fused_fallback_total", count,
+                        job=self.name, reason=reason,
+                    )
             rows = 0
             for _ in range(n):
                 rows += self.chunk_round()
             return rows
         if self.mesh is not None:
             return self._run_chunks_mesh(n)
+        prog = self._multi_prog(n)
+        k0s = {}
+        rows = 0
+        for nm, k in self._pulls:
+            reader = self.sources[nm]
+            # next_base() consumed one cap block; skip the other n*k-1
+            k0s[nm] = jnp.int64(reader.next_base())
+            reader.offset += reader.cap * (n * k - 1)
+            rows += reader.cap * n * k
+        self.states = prog(self.states, k0s)
+        return rows
+
+    def _multi_prog(self, n: int):
+        """The jitted n-round window program (linear or mesh), cached
+        by n."""
         prog = self._fused_multi.get(n)
-        if prog is None:
-            pulls = list(self._pulls)
-            readers = dict(self.sources)
+        if prog is not None:
+            return prog
+        pulls = list(self._pulls)
+        readers = dict(self.sources)
+        if self.mesh is None:
             strides = {
                 nm: readers[nm].cap * getattr(readers[nm], "num_splits", 1)
                 for nm, _ in pulls
@@ -800,39 +826,7 @@ class DagJob(CheckpointPipelineMixin):
                 return jax.lax.fori_loop(0, n, body, states)
 
             prog = jax.jit(_multi, donate_argnums=(0,))
-            # bounded cache: chunks_per_barrier is runtime-mutable and
-            # each distinct n compiles a program — keep the newest few
-            if len(self._fused_multi) >= 4:
-                self._fused_multi.pop(next(iter(self._fused_multi)))
-            self._fused_multi[n] = prog
-        k0s = {}
-        rows = 0
-        for nm, k in self._pulls:
-            reader = self.sources[nm]
-            # next_base() consumed one cap block; skip the other n*k-1
-            k0s[nm] = jnp.int64(reader.next_base())
-            reader.offset += reader.cap * (n * k - 1)
-            rows += reader.cap * n * k
-        self.states = prog(self.states, k0s)
-        return rows
-
-    def _run_chunks_mesh(self, n: int) -> int:
-        """The sharded fused window: n scheduling rounds — per-shard
-        source generation, every exchange collective, join emission
-        drains — as ONE ``shard_map``-ed ``fori_loop`` program between
-        barriers, with the mesh-stacked state donated.
-
-        Per-shard base ordinals come in as one ``[n_shards, n*k]``
-        int64 column per source, computed host-side by the SAME
-        ``next_base()`` sequence the per-chunk path consumes — the
-        generated streams are ordinal-identical to n per-chunk rounds,
-        so fused and unfused runs stay byte-identical."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        prog = self._fused_multi.get(n)
-        if prog is None:
-            pulls = list(self._pulls)
-            readers = dict(self.sources)
+        else:
             spec = self._sharding_spec()
 
             def body(states, *base_cols):
@@ -857,9 +851,27 @@ class DagJob(CheckpointPipelineMixin):
                 in_specs=(spec,) + (spec,) * len(pulls),
                 out_specs=spec,
             ), donate_argnums=(0,))
-            if len(self._fused_multi) >= 4:
-                self._fused_multi.pop(next(iter(self._fused_multi)))
-            self._fused_multi[n] = prog
+        # bounded cache: chunks_per_barrier is runtime-mutable and
+        # each distinct n compiles a program — keep the newest few
+        if len(self._fused_multi) >= 4:
+            self._fused_multi.pop(next(iter(self._fused_multi)))
+        self._fused_multi[n] = prog
+        return prog
+
+    def _run_chunks_mesh(self, n: int) -> int:
+        """The sharded fused window: n scheduling rounds — per-shard
+        source generation, every exchange collective, join emission
+        drains — as ONE ``shard_map``-ed ``fori_loop`` program between
+        barriers, with the mesh-stacked state donated.
+
+        Per-shard base ordinals come in as one ``[n_shards, n*k]``
+        int64 column per source, computed host-side by the SAME
+        ``next_base()`` sequence the per-chunk path consumes — the
+        generated streams are ordinal-identical to n per-chunk rounds,
+        so fused and unfused runs stay byte-identical."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        prog = self._multi_prog(n)
         rows = 0
         base_cols = []
         sharding = NamedSharding(self.mesh, P(self.AXIS))
@@ -896,7 +908,7 @@ class DagJob(CheckpointPipelineMixin):
             # empty, which is harmless)
             p = frag.pending_total(states_idx)
             if self.mesh is not None:
-                p = jax.lax.pmax(p, self.AXIS)
+                p = axis_max(p, self.AXIS)
             return p > 0
 
         def cond(carry):
@@ -937,8 +949,8 @@ class DagJob(CheckpointPipelineMixin):
             raw = new_states[idx][i].max_ts
             if self.mesh is not None:
                 # global watermark = min over shards (the reference's
-                # min-of-upstream-actors alignment, as ONE ICI pmin)
-                raw = jax.lax.pmin(raw, self.AXIS)
+                # min-of-upstream-actors alignment, as ONE ICI gather)
+                raw = axis_min(raw, self.AXIS)
             has = raw != WM_NONE
             val = jnp.where(has, raw - ex.delay_us, jnp.int64(WM_SAFE_FLOOR))
             out.append((Watermark(ex.ts_col, val), has))
@@ -984,7 +996,7 @@ class DagJob(CheckpointPipelineMixin):
                         and ex.ts_col == src_col:
                     raw = new_states[key][i].max_ts
                     if self.mesh is not None:
-                        raw = jax.lax.pmin(raw, self.AXIS)
+                        raw = axis_min(raw, self.AXIS)
                     has = raw != WM_NONE
                     val = jnp.where(
                         has, raw - ex.delay_us, jnp.int64(WM_SAFE_FLOOR)
@@ -1150,30 +1162,29 @@ class DagJob(CheckpointPipelineMixin):
                 new_states[idx] = node.join.maybe_rehash(new_states[idx])
         return tuple(new_states)
 
+    def _make_maintain_prog(self):
+        if self.mesh is None:
+            return jax.jit(self._maintain_impl, donate_argnums=(0,))
+        spec = self._sharding_spec()
+
+        def body(states):
+            local = jax.tree.map(lambda x: x[0], states)
+            out = self._maintain_impl(tuple(local))
+            return jax.tree.map(lambda x: x[None], out)
+
+        return jax.jit(shard_map_nocheck(
+            body, mesh=self.mesh, in_specs=(spec,), out_specs=spec,
+        ), donate_argnums=(0,))
+
     def _maintain(self, sealed) -> None:
         if self._maintain_prog is None:
-            if self.mesh is None:
-                self._maintain_prog = jax.jit(
-                    self._maintain_impl, donate_argnums=(0,)
-                )
-            else:
-                spec = self._sharding_spec()
-
-                def body(states):
-                    local = jax.tree.map(lambda x: x[0], states)
-                    out = self._maintain_impl(tuple(local))
-                    return jax.tree.map(lambda x: x[None], out)
-
-                self._maintain_prog = jax.jit(shard_map_nocheck(
-                    body, mesh=self.mesh, in_specs=(spec,),
-                    out_specs=spec,
-                ), donate_argnums=(0,))
+            self._maintain_prog = self._make_maintain_prog()
         self.states = self._maintain_prog(self.states)
         if self._counters is None:
             return
         values = np.asarray(self._counters)  # THE one device sync
         residual = check_counter_values(
-            self.name, self.counter_labels, values
+            self.name, self.counter_labels, values, self.metrics
         )
         for _ in range(64):
             if not residual:
@@ -1185,7 +1196,8 @@ class DagJob(CheckpointPipelineMixin):
                     self.states, self._barrier_epoch_arg(sealed)
                 )
             residual = check_counter_values(
-                self.name, self.counter_labels, np.asarray(self._counters)
+                self.name, self.counter_labels,
+                np.asarray(self._counters), self.metrics,
             )
 
     # -- checkpoint / recovery ------------------------------------------

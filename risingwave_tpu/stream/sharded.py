@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from risingwave_tpu.parallel.exchange import shard_map_nocheck
+from risingwave_tpu.parallel.exchange import axis_min, shard_map_nocheck
 
 from risingwave_tpu.common.chunk import Chunk
 from risingwave_tpu.parallel.exchange import shuffle_chunk
@@ -187,7 +187,7 @@ class ShardedJob:
         exchange dispatchers and taking the min across upstream actors
         (src/stream/src/executor/merge.rs watermark alignment).  Here
         each shard's WatermarkFilter holds a local max_ts; the global
-        watermark is ``lax.pmin`` over the mesh axis — one ICI
+        watermark is the minimum over the mesh axis — one ICI
         collective per barrier — then every executor in both halves
         applies its cleaning/EOWC hook.  A shard that has seen no data
         pins the global watermark at the WM_NONE sentinel, so cleaning
@@ -203,7 +203,7 @@ class ShardedJob:
         for i, ex in enumerate(local_execs):
             if not isinstance(ex, WatermarkFilterExecutor):
                 continue
-            graw = jax.lax.pmin(locs[i].max_ts, self.AXIS)
+            graw = axis_min(locs[i].max_ts, self.AXIS)
             val = jnp.where(
                 graw == WM_NONE,
                 jnp.int64(WM_SAFE_FLOOR),
@@ -260,6 +260,9 @@ class ShardedStreamingJob:
     Round-1 scope: traceable sources, no watermark-driven cleaning in
     the sharded path (planner gates eligibility).
     """
+
+    #: optional MetricsRegistry (the engine attaches its own)
+    metrics = None
 
     def __init__(self, sharded: ShardedJob, reader, name: str,
                  checkpoint_frequency: int = 1, checkpoint_store=None):
@@ -338,7 +341,8 @@ class ShardedStreamingJob:
                     self._gather_counters(self.states)
                 )  # THE one device sync
                 residual = check_counter_values(
-                    self.name, self._counter_labels, values
+                    self.name, self._counter_labels, values,
+                    self.metrics,
                 )
                 # pathological pending beyond the device drain bound:
                 # finish with host-looped flushes before committing
@@ -349,6 +353,7 @@ class ShardedStreamingJob:
                     residual = check_counter_values(
                         self.name, self._counter_labels,
                         jax.device_get(self._gather_counters(self.states)),
+                        self.metrics,
                     )
                 self._ckpts_since_maintain = 0
             self._ckpts_since_snapshot += 1

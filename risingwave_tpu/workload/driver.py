@@ -95,12 +95,10 @@ def _spawn(role: str, meta_port: int, data_dir: str, idx: int = 0):
             "--data-dir", data_dir, "--heartbeat-interval", "0.25"]
     if role == "compute":
         argv += ["--config-json", json.dumps(CONFIG)]
-    env = dict(os.environ, JAX_PLATFORMS=os.environ.get(
-        "JAX_PLATFORMS", "cpu"))
+    # the child inherits this environment as it is
     return subprocess.Popen(
         argv, stdout=subprocess.DEVNULL,
         stderr=open(os.path.join(data_dir, f"{role}{idx}.log"), "wb"),
-        env=env,
     )
 
 

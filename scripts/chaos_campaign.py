@@ -150,8 +150,7 @@ def _wait_port(port: int, deadline_s: float = 120.0) -> None:
 
 
 def _env(fault_env: dict | None) -> dict:
-    env = dict(os.environ, JAX_PLATFORMS=os.environ.get(
-        "JAX_PLATFORMS", "cpu"))
+    env = dict(os.environ)
     env.pop("RWT_FAULTS", None)
     if fault_env:
         env["RWT_FAULTS"] = json.dumps(fault_env)

@@ -63,8 +63,7 @@ READS = [
 
 
 def _spawn_worker(meta_port: int, data_dir: str, idx: int):
-    env = dict(os.environ, JAX_PLATFORMS=os.environ.get(
-        "JAX_PLATFORMS", "cpu"))
+    # the child inherits this environment as it is
     return subprocess.Popen(
         [sys.executable, "-m", "risingwave_tpu.server",
          "--role", "compute", "--meta", f"127.0.0.1:{meta_port}",
@@ -72,7 +71,6 @@ def _spawn_worker(meta_port: int, data_dir: str, idx: int):
          "--heartbeat-interval", "0.25"],
         stdout=subprocess.DEVNULL,
         stderr=open(os.path.join(data_dir, f"worker{idx}.log"), "wb"),
-        env=env,
     )
 
 

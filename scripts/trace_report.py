@@ -73,8 +73,7 @@ def _free_port() -> int:
 
 
 def _env() -> dict:
-    env = dict(os.environ, JAX_PLATFORMS=os.environ.get(
-        "JAX_PLATFORMS", "cpu"))
+    env = dict(os.environ)
     env.pop("RWT_FAULTS", None)
     return env
 

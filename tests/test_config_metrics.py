@@ -732,7 +732,6 @@ def test_dag_fused_fallback_counter_exported():
     eng.tick(barriers=1, chunks_per_barrier=4)
     job = eng.jobs[0]
     assert job.fused_fallbacks.get("host_chunk_source", 0) >= 1
-    eng.collect_join_metrics()
     got = eng.metrics.get("dag_fused_fallback_total", job=job.name,
                           reason="host_chunk_source")
     assert got >= 1
@@ -828,3 +827,27 @@ def test_workload_txn_metrics_exported():
     assert 'le="60"' in text
     assert m.quantile("workload_txn_seconds", 0.99,
                       type="new_order") == 60.0
+
+
+def test_state_section_reaches_the_planner_whole():
+    """Every key of the config file's ``state`` section is a planner
+    size: join and pool sizes come from the file too (``PlannerConfig``
+    is ``StateConfig`` plus the chunk capacity)."""
+    import dataclasses
+
+    from risingwave_tpu.common.config import RwConfig, StateConfig
+
+    cfg = RwConfig.from_dict({
+        "streaming": {"chunk_size": 2048},
+        "state": {"join_left_table_size": 1 << 10,
+                  "join_right_table_size": 1 << 9,
+                  "join_pool_size": 1 << 11, "mv_ring_size": 1 << 12},
+    })
+    planner = Engine(cfg).config
+    assert planner.chunk_capacity == 2048
+    assert planner.join_left_table_size == 1 << 10
+    assert planner.join_right_table_size == 1 << 9
+    assert planner.join_pool_size == 1 << 11
+    assert planner.mv_ring_size == 1 << 12
+    for f in dataclasses.fields(StateConfig):
+        assert getattr(planner, f.name) == getattr(cfg.state, f.name)

@@ -5,6 +5,7 @@ scale: the same queries run as maintained MVs and their contents are
 cross-checked against a numpy reimplementation of the query.
 """
 
+import jax.numpy as jnp
 import numpy as np
 
 from risingwave_tpu.common.types import DataType
@@ -48,7 +49,7 @@ def test_q1_currency_conversion():
     )
 
 
-def test_q7_style_windowed_max():
+def test_q7_style_windowed_max(accel_tuned):
     """q7-ish: max price + bid count per 10s tumbling window."""
     cap = 512
     src = NexmarkSplitReader("bid", chunk_capacity=cap)
@@ -79,7 +80,7 @@ def test_q7_style_windowed_max():
     assert got == want
 
 
-def test_q8_style_windowed_join():
+def test_q8_style_windowed_join(accel_tuned):
     """q8-ish: persons joined with auctions by seller in the same window."""
     cap = 256
     gen = NexmarkGenerator()
@@ -159,3 +160,22 @@ def test_nexmark_splits_partition_the_stream():
     # offsets checkpoint per split
     assert parts[0].state() == {"table": "bid", "split_id": 0,
                                 "offset": 128}
+
+
+def test_price_is_integer_arithmetic_on_a_fixed_curve():
+    """The generator's price must be the same on every backend (the
+    chip emulates float64 and rounded ``10.0 ** x`` differently from a
+    host in 5% of rows): integer arithmetic over knots that ``decimal``
+    builds, checked here against plain Python integers."""
+    from risingwave_tpu.connector import nexmark as nx
+
+    knots = [int(k) for k in nx._PRICE_KNOTS]
+    assert knots[0] == 100 and knots[-1] == 100_000_000
+    assert all(a <= b for a, b in zip(knots, knots[1:]))
+    ids = np.arange(5000, dtype=np.int64) * 7919
+    got = np.asarray(nx._next_price(jnp.asarray(ids), 5))
+    draws = np.asarray(nx._rand(jnp.asarray(ids), 5))
+    for r, g in zip(draws.tolist(), got.tolist()):
+        k, frac = r >> 54, (r >> 22) & 0xFFFFFFFF
+        assert g == knots[k] + (((knots[k + 1] - knots[k]) * frac) >> 32)
+    assert got.min() >= 100 and got.max() < 100_000_000

@@ -86,7 +86,7 @@ def test_join_retraction():
     assert rows == []
 
 
-def test_join_multiset_duplicates():
+def test_join_multiset_duplicates(accel_tuned):
     j = _join()
     st = j.init_state()
     # two identical left rows — multiset semantics
@@ -275,7 +275,7 @@ def _brute_inner(lrows, rrows):
     )
 
 
-def test_pool_join_hot_key_exceeds_any_bucket():
+def test_pool_join_hot_key_exceeds_any_bucket(accel_tuned):
     """One key holding 200 rows (far past any dense bucket_cap) joins
     fully: the pool has no per-key depth limit."""
     import jax

@@ -167,3 +167,66 @@ def test_background_ticker_advances_jobs():
     finally:
         n.stop()
         server.shutdown()
+
+
+def test_entry_points_import_without_a_backend():
+    """One process for each chip: a parent that only imports the entry
+    points (and ``chip_smoke``, which is only ever a parent) must not
+    initialise a JAX backend — it would hold the chip its children
+    need."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import risingwave_tpu.server, risingwave_tpu.sql.engine, "
+        "risingwave_tpu.cluster.meta_service, risingwave_tpu.pgwire, "
+        "risingwave_tpu.ctl, chip_smoke\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, "-c", code], cwd=root,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+
+
+def test_single_node_handshake_and_config_json(tmp_path):
+    """The default role prints one JSON handshake line like the others
+    — with the device it sees and whether the native codec loaded — and
+    honours ``--config-json``; SIGINT stops it in order, exit code 0."""
+    import json
+    import os
+    import signal
+    import socket
+    import subprocess
+    import sys
+
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "risingwave_tpu.server", "--port",
+         str(port), "--data-dir", str(tmp_path), "--config-json",
+         json.dumps({"streaming": {"chunk_size": 256},
+                     "state": {"join_pool_size": 1 << 12}})],
+        cwd=root, stdout=subprocess.PIPE, text=True)
+    try:
+        hs = json.loads(proc.stdout.readline())
+        assert hs["role"] == "single" and hs["pgwire_port"] == port
+        assert hs["platform"] == "cpu" and hs["device_count"] >= 1
+        assert hs["native_codec"] is True
+        c = MiniPgClient("127.0.0.1", port)
+        c.query("CREATE TABLE t (k BIGINT, v BIGINT)")
+        c.query("CREATE MATERIALIZED VIEW m AS "
+                "SELECT k, sum(v) AS s FROM t GROUP BY k")
+        c.query("INSERT INTO t VALUES (1, 2), (1, 3)")
+        c.query("FLUSH")
+        assert c.query("SELECT k, s FROM m")[1] == [("1", "5")]
+        c.close()
+        proc.send_signal(signal.SIGINT)
+        assert proc.wait(60) == 0
+    finally:
+        if proc.poll() is None:
+            proc.kill()

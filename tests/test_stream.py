@@ -3,6 +3,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from risingwave_tpu.common.chunk import Chunk
 from risingwave_tpu.common.types import DataType, Field, Schema
@@ -80,7 +81,7 @@ def _agg_fragment(table_size=64, emit_capacity=8):
     return Fragment([agg]), agg
 
 
-def test_hash_agg_insert_then_update():
+def test_hash_agg_insert_then_update(accel_tuned):
     frag, agg = _agg_fragment()
     states = frag.init_states()
     states, _ = frag.step(states, Chunk.from_pretty(
@@ -109,7 +110,7 @@ def test_hash_agg_insert_then_update():
     assert rows == [(2, 1, 2, 15), (3, 1, 3, 16)]  # U- old, U+ new
 
 
-def test_hash_agg_retraction_to_empty():
+def test_hash_agg_retraction_to_empty(accel_tuned):
     frag, agg = _agg_fragment()
     states = frag.init_states()
     states, _ = frag.step(states, Chunk.from_pretty(
@@ -143,7 +144,7 @@ def test_hash_agg_retraction_to_empty():
     assert outs[0].to_rows() == [(0, 1, 1, 3)]
 
 
-def test_hash_agg_emit_overflow_drains():
+def test_hash_agg_emit_overflow_drains(accel_tuned):
     # 12 dirty groups, emit capacity 8 -> runtime drains in 2 flushes
     frag, agg = _agg_fragment(table_size=64, emit_capacity=8)
     states = frag.init_states()
@@ -159,7 +160,7 @@ def test_hash_agg_emit_overflow_drains():
     assert int(agg.pending_dirty(states[0])) == 0
 
 
-def test_hash_agg_min_max_append_only():
+def test_hash_agg_min_max_append_only(accel_tuned):
     schema = Schema.of(("g", DataType.INT64), ("v", DataType.INT64))
     agg = HashAggExecutor(
         schema,
@@ -210,7 +211,7 @@ def test_materialize_upsert():
     assert rows == [(1, 11), (3, 30)]
 
 
-def test_append_only_materialize_ring():
+def test_append_only_materialize_ring(accel_tuned):
     schema = Schema.of(("v", DataType.INT64))
     mv = AppendOnlyMaterialize(schema, ring_size=16)
     frag = Fragment([mv])
@@ -225,7 +226,7 @@ def test_append_only_materialize_ring():
     assert [r[0] for r in rows] == list(range(10))
 
 
-def test_agg_into_materialize_chain():
+def test_agg_into_materialize_chain(accel_tuned):
     """agg flush output flows through trailing materialize in one fragment."""
     schema = Schema.of(("g", DataType.INT64), ("v", DataType.INT64))
     agg = HashAggExecutor(
@@ -336,3 +337,18 @@ def test_run_chunks_multi_dispatch_equivalence():
     rows_b = sorted(map(tuple, b.execute("SELECT * FROM m")))
     assert job_b.source.offset == off_a
     assert rows_b == rows_a and len(rows_a) > 0
+
+
+@pytest.mark.parametrize("n", [1, 7, 8192, 40960])
+def test_cumsum_int64_from_limbs_matches_numpy(n):
+    """``compact._cumsum_int64`` (the chip's 64-bit scan, built from
+    uint32 limb scans) is exact modulo 2^64, negatives included."""
+    from risingwave_tpu.common.compact import _cumsum_int64
+
+    rng = np.random.default_rng(n)
+    x = rng.integers(-2**62, 2**62, n, dtype=np.int64)
+    np.testing.assert_array_equal(
+        np.asarray(_cumsum_int64(jnp.asarray(x))), np.cumsum(x))
+    y = rng.integers(0, 2**64 - 1, n, dtype=np.uint64)
+    np.testing.assert_array_equal(
+        np.asarray(_cumsum_int64(jnp.asarray(y))), np.cumsum(y))

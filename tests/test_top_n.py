@@ -63,7 +63,7 @@ def test_plain_top2_asc():
     assert mv == Counter({(0, 10): 1, (0, 20): 1})
 
 
-def test_group_top1_desc():
+def test_group_top1_desc(accel_tuned):
     top = GroupTopNExecutor(
         S, group_by=[col("g")], order_by=[(col("v"), True)], limit=1,
         pool_size=16, emit_capacity=8,
@@ -109,7 +109,7 @@ def test_topn_offset():
     assert mv == Counter({(0, 20): 1, (0, 30): 1})
 
 
-def test_topn_duplicate_values():
+def test_topn_duplicate_values(accel_tuned):
     top = GroupTopNExecutor(
         S, group_by=[], order_by=[(col("v"), False)], limit=3,
         pool_size=16, emit_capacity=8,

@@ -1,0 +1,176 @@
+"""The chip's compiler, asked without the chip.
+
+The main-path programs — built through ``Engine`` at the sizes
+``chip_smoke.py`` runs — are lowered for ONE described TPU v5e chip and
+compiled by the installed TPU compiler: what it refuses here (a program
+that does not fit, an op it cannot lower, a scoped-memory fault) costs no
+chip time.  ``accel_tuned()`` is forced true, so the branches compiled
+are the ones the chip takes (``lax.top_k`` compaction, sort-based
+pre-aggregation), which no CPU test otherwise reaches.
+
+A compile that passes is not a run: results and times come from
+``chip_smoke.py`` on the chip.
+
+Everything that touches the topology lives in module-scoped fixtures of
+this one file (one process at a time may load the TPU's library; under
+xdist only the worker that is given this file does).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import chip_smoke
+from risingwave_tpu.common import compact
+from risingwave_tpu.common.config import RwConfig
+from risingwave_tpu.sql import Engine
+from risingwave_tpu.stream import hash_agg
+
+CFG = chip_smoke.FULL
+HBM_BYTES = 16 << 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def chip_branches():
+    """The chip's trace-time branches, and no persistent cache: an entry
+    written by a deviceless compile cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        compact.accel_tuned.cache_clear()
+        mp.setattr(compact, "accel_tuned", lambda: True)
+        mp.setattr(hash_agg, "accel_tuned", lambda: True)
+        yield
+    compact.accel_tuned.cache_clear()
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _job(view: str):
+    eng = Engine(RwConfig.from_dict({
+        "streaming": {"chunk_size": CFG["chunk"]}, "state": CFG["state"],
+    }))
+    eng.execute(chip_smoke.SOURCES.format(rate=CFG["rate"]))
+    eng.execute(chip_smoke.VIEWS[view])
+    return eng.jobs[0]
+
+
+@pytest.fixture(scope="module")
+def jobs():
+    """Built once per view, on the CPU, through the SQL front end."""
+    cache: dict = {}
+
+    def get(view: str):
+        if view not in cache:
+            cache[view] = _job(view)
+        return cache[view]
+
+    return get
+
+
+def _compile(prog, one_chip, *args):
+    """Lower for the described chip from shapes alone; return the
+    compiled program's memory analysis."""
+    def sds(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=one_chip),
+            tree)
+
+    mem = prog.lower(*(sds(a) for a in args)).compile().memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"args={mem.argument_size_in_bytes >> 20}MiB "
+          f"temp={mem.temp_size_in_bytes >> 20}MiB total={total >> 20}MiB")
+    assert total < HBM_BYTES
+    return mem
+
+
+K0 = np.int64(0)
+EPOCH = np.int64(1)
+
+
+def test_q7_step(jobs, one_chip):
+    job = jobs("q7")
+    _compile(job._fused, one_chip, job.states, K0)
+
+
+def test_q7_barrier(jobs, one_chip):
+    job = jobs("q7")
+    _compile(job.fragment._barrier, one_chip, job.states, EPOCH)
+
+
+def test_q5_fused_window(jobs, one_chip):
+    """The multi-chunk window program at the smoke's chunks_per_barrier."""
+    job = jobs("q5")
+    _compile(job._multi_prog(CFG["chunks_per_barrier"]), one_chip,
+             job.states, K0)
+
+
+def test_q5_barrier(jobs, one_chip):
+    """The pane chain's second agg runs inside the barrier's drain loop
+    on 40,960-row chunks: the 64-bit reduce-window the compiler refused
+    there is what ``compact._cumsum_int64`` replaced."""
+    job = jobs("q5")
+    _compile(job.fragment._barrier, one_chip, job.states, EPOCH)
+
+
+def test_q8_step_person(jobs, one_chip):
+    job = jobs("q8")
+    prog, fused = job._make_step("p")
+    assert fused
+    _compile(prog, one_chip, job.states, K0)
+
+
+def test_q8_barrier(jobs, one_chip):
+    job = jobs("q8")
+    _compile(job._make_barrier_prog(), one_chip, job.states, EPOCH)
+
+
+def test_int64_cumsum_in_loop(one_chip):
+    """libtpu 0.0.34 runs out of scoped vmem on ``jnp.cumsum`` of int64
+    inside a loop body at lengths 2^14..2^16; the limb-wise scan must
+    not."""
+    def loop(x):
+        return jax.lax.fori_loop(
+            0, 3, lambda _, v: compact._cumsum_int64(v) + 1, x)
+
+    _compile(jax.jit(loop), one_chip,
+             jax.ShapeDtypeStruct((40960,), jnp.int64))
+
+
+def test_string_ring_digest_fits(one_chip):
+    """q8's 2^23-row MV ring has a 24-byte name column; its block digest
+    once asked for 12 GiB of padding (a ``[n/8, 8]`` view in the TPU's
+    tiled layout)."""
+    from risingwave_tpu.storage.digest import (
+        DEFAULT_BLOCK_ELEMS, leaf_block_count, leaf_digest,
+    )
+    shape = (CFG["state"]["mv_ring_size"], 24)
+    nb = leaf_block_count(shape, DEFAULT_BLOCK_ELEMS)
+    prog = jax.jit(lambda x: leaf_digest(
+        x.reshape(-1), nb, DEFAULT_BLOCK_ELEMS))
+    mem = _compile(prog, one_chip, jax.ShapeDtypeStruct(shape, jnp.uint8))
+    assert mem.temp_size_in_bytes < 8 << 30
