@@ -19,9 +19,28 @@ Model
 - A **span** is a finished interval: dict with ``trace_id``,
   ``span_id`` (``"<role>:<n>"`` — unique cluster-wide without
   coordination), ``parent_id``, ``role``, ``name``, ``ts`` (wall
-  seconds), ``dur`` (seconds), ``attrs``, ``thread``.  Only completed
-  spans enter the ring: a SIGKILL loses at most the spans in flight,
-  and the survivors still parse (satellite: truncated-but-parseable).
+  seconds), ``t_mono`` (``time.monotonic()`` at entry: on Linux one
+  clock for every process of a host, the one a client stamps its
+  requests with), ``dur`` (seconds), ``attrs``, ``thread``.  Only
+  completed spans enter the ring: a SIGKILL loses at most the spans
+  in flight, and the survivors still parse (satellite:
+  truncated-but-parseable).
+- The **served single node** roots its own traces: one tree a barrier
+  (``tick-<n>``), a pgwire statement (``read-<n>``) and a scrape
+  (``scrape-<n>``), opened with ``root()``.
+- **Every span is a counter**: a finished span adds its seconds and 1
+  to ``trace_span_seconds_total{span[,job]}`` /
+  ``trace_span_total{span[,job]}`` of a metrics registry (``job``
+  where the span carries that attribute, so ``DROP`` retires them with
+  the job's other series).  Which registry: the one the span was
+  opened with (``metrics=``), else its parent's on this thread, else
+  the one ``configure(metrics=...)`` named — a process may hold many
+  engines, and an engine's spans never land in another's registry.
+- **One clock with the device trace**: where ``configure(annotate=
+  ...)`` handed in ``jax.profiler.TraceAnnotation`` (the roles that
+  hold a chip), a span is also an annotation on the profiler's host
+  plane, beside the device's operations.  This module never imports
+  jax itself: ``--role serving`` boots without it.
 - **Overhead contract**: ``sample_n == 0`` disables tracing — `span()`
   returns a module-level null singleton (zero allocations, no clock
   reads) and ``sampled_span()`` likewise.  ``sample_n >= 1`` records
@@ -59,6 +78,9 @@ class _NullSpan:
     def set(self, **attrs) -> "_NullSpan":
         return self
 
+    def drop(self) -> None:
+        pass
+
     @property
     def ctx(self):
         return None
@@ -71,18 +93,26 @@ class _Span:
     """One in-flight span; records itself into the ring on exit."""
 
     __slots__ = ("_rec", "trace_id", "span_id", "parent_id", "name",
-                 "attrs", "_t0", "_ts", "_pushed")
+                 "attrs", "_sink", "_ann", "_t0", "_ts", "_mono",
+                 "_pushed", "_dropped")
 
-    def __init__(self, rec, trace_id, span_id, parent_id, name, attrs):
+    def __init__(self, rec, trace_id, span_id, parent_id, name, attrs,
+                 sink=None):
         self._rec = rec
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
         self.name = name
         self.attrs = attrs
+        #: the metrics registry this span (and its children on this
+        #: thread) count into
+        self._sink = sink
+        self._ann = None
         self._t0 = 0.0
         self._ts = 0.0
+        self._mono = 0.0
         self._pushed = False
+        self._dropped = False
 
     @property
     def ctx(self) -> tuple:
@@ -94,24 +124,38 @@ class _Span:
         self.attrs.update(attrs)
         return self
 
+    def drop(self) -> None:
+        """Leave no record on exit: an idle poll that found nothing to
+        do is not an interval worth a ring entry."""
+        self._dropped = True
+
     def __enter__(self) -> "_Span":
+        annotate = self._rec._annotate
+        if annotate is not None:
+            self._ann = annotate(self.name)
+            self._ann.__enter__()
         self._ts = time.time()
+        self._mono = time.monotonic()
         self._t0 = time.perf_counter()
-        stack = self._rec._stack()
-        stack.append((self.trace_id, self.span_id))
+        self._rec._stack().append(
+            (self.trace_id, self.span_id, self._sink, self))
         self._pushed = True
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         if self._pushed:
             stack = self._rec._stack()
-            if stack and stack[-1] == (self.trace_id, self.span_id):
+            if stack and stack[-1][1] == self.span_id:
                 stack.pop()
             self._pushed = False
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
-        self._rec._record(self, dur)
+        if not self._dropped:
+            self._rec._record(self, dur)
         return False
 
 
@@ -127,16 +171,27 @@ class SpanRecorder:
         self._ring: list = []
         self._head = 0
         self._ids = itertools.count(1)
+        self._roots = itertools.count(1)
         self._sample_ctr = itertools.count()
         self._tls = threading.local()
+        #: ``name -> context manager`` (``jax.profiler.TraceAnnotation``
+        #: where the role holds a chip), or None
+        self._annotate = None
+        #: where a span with no registry of its own counts, or None
+        self._metrics = None
 
     def configure(self, role: str | None = None,
                   sample_n: int | None = None,
-                  capacity: int | None = None) -> "SpanRecorder":
+                  capacity: int | None = None,
+                  annotate=None, metrics=None) -> "SpanRecorder":
         if role is not None:
             self.role = role
         if sample_n is not None:
             self.sample_n = sample_n
+        if annotate is not None:
+            self._annotate = annotate
+        if metrics is not None:
+            self._metrics = metrics
         if capacity is not None and capacity != self.capacity:
             with self._lock:
                 self.capacity = capacity
@@ -158,37 +213,61 @@ class SpanRecorder:
     def current(self) -> tuple | None:
         """The active (trace_id, span_id) on THIS thread, or None."""
         s = getattr(self._tls, "stack", None)
-        return s[-1] if s else None
+        return s[-1][:2] if s else None
 
     def activate(self, ctx) -> "_CtxGuard | _NullSpan":
         """Adopt a remote context (an RPC frame's ``trace`` key) for
         the current thread.  No span is recorded — children attach."""
         if not self.enabled or not ctx:
             return NULL_SPAN
-        return _CtxGuard(self, (ctx[0], ctx[1]))
+        return _CtxGuard(self, (ctx[0], ctx[1], None, None))
 
     # -- span creation ---------------------------------------------------
     def span(self, name: str, ctx: tuple | None = None,
-             trace_id: str | None = None, **attrs):
+             trace_id: str | None = None, metrics=None, **attrs):
         """Open a control-plane span.  Parent resolution: explicit
         ``ctx`` (cross-thread/cross-process) > the thread's active
-        span > root (``trace_id`` names a fresh trace)."""
+        span > root (``trace_id`` names a fresh trace).  ``metrics``
+        names the registry this span and its children on this thread
+        count into (default: the parent's)."""
         if self.sample_n <= 0:
             return NULL_SPAN
+        s = getattr(self._tls, "stack", None)
         if ctx is not None:
             tid, parent = ctx[0], ctx[1]
+        elif s:
+            tid, parent, inherited, _ = s[-1]
+            if metrics is None:
+                metrics = inherited
+        elif trace_id is not None:
+            tid, parent = trace_id, None
         else:
-            cur = self.current()
-            if cur is not None:
-                tid, parent = cur
-            elif trace_id is not None:
-                tid, parent = trace_id, None
-            else:
-                return NULL_SPAN  # no trace active: nothing to attach to
+            return NULL_SPAN  # no trace active: nothing to attach to
         if trace_id is not None:
             tid = trace_id
         span_id = f"{self.role}:{next(self._ids)}"
-        return _Span(self, tid, span_id, parent, name, attrs)
+        return _Span(self, tid, span_id, parent, name, attrs, metrics)
+
+    def root(self, trace: str, name: str, metrics=None, **attrs):
+        """Open the root of a fresh trace ``<trace>-<n>`` (the single
+        node's ``tick`` / ``read`` / ``scrape``), ``n`` counted by
+        this process.  Where a trace is already active on this thread
+        no second root opens: the caller joins the active span (the
+        served node's barrier loop opens ``tick`` before it takes the
+        engine lock, and ``Engine.tick`` under it adds none), and
+        attributes it sets land there."""
+        if self.sample_n <= 0:
+            return NULL_SPAN
+        s = getattr(self._tls, "stack", None)
+        if s:
+            return _Joined(s[-1][3], attrs)
+        return self.span(name, trace_id=f"{trace}-{next(self._roots)}",
+                         metrics=metrics, **attrs)
+
+    def held(self, lock, name: str) -> "_Held":
+        """``with rec.held(lock, "tick.lock_wait"):`` — take ``lock``
+        inside a span of that name (the wait), hold it for the body."""
+        return _Held(self, lock, name)
 
     def sampled_span(self, name: str, trace_id: str | None = None,
                      ctx: tuple | None = None, **attrs):
@@ -217,6 +296,7 @@ class SpanRecorder:
             "role": self.role,
             "name": span.name,
             "ts": span._ts,
+            "t_mono": span._mono,
             "dur": dur,
             "attrs": span.attrs,
             "thread": threading.current_thread().name,
@@ -227,7 +307,13 @@ class SpanRecorder:
             else:
                 self._ring[self._head] = entry
                 self._head = (self._head + 1) % self.capacity
-        return None
+        sink = span._sink if span._sink is not None else self._metrics
+        if sink is not None:
+            job = span.attrs.get("job")
+            labels = {"span": span.name} if job is None \
+                else {"span": span.name, "job": job}
+            sink.inc("trace_span_seconds_total", dur, **labels)
+            sink.inc("trace_span_total", 1.0, **labels)
 
     def _snapshot_locked(self) -> list:
         return self._ring[self._head:] + self._ring[:self._head]
@@ -247,6 +333,49 @@ class SpanRecorder:
             self._head = 0
 
 
+class _Joined:
+    """``root()`` inside an active trace: no span of its own, the
+    active one takes the attributes (none where the context was
+    adopted from another process)."""
+
+    __slots__ = ("_span",)
+
+    def __init__(self, span, attrs):
+        self._span = span
+        self.set(**attrs)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **attrs) -> "_Joined":
+        if self._span is not None:
+            self._span.attrs.update(attrs)
+        return self
+
+    @property
+    def ctx(self):
+        return None if self._span is None else self._span.ctx
+
+
+class _Held:
+    __slots__ = ("_rec", "_lock", "_name")
+
+    def __init__(self, rec, lock, name):
+        self._rec, self._lock, self._name = rec, lock, name
+
+    def __enter__(self):
+        with self._rec.span(self._name):
+            self._lock.acquire()
+        return self
+
+    def __exit__(self, *exc):
+        self._lock.release()
+        return False
+
+
 class _CtxGuard:
     __slots__ = ("_rec", "_ctx", "_pushed")
 
@@ -263,7 +392,7 @@ class _CtxGuard:
     def __exit__(self, *exc):
         if self._pushed:
             stack = self._rec._stack()
-            if stack and stack[-1] == self._ctx:
+            if stack and stack[-1] is self._ctx:
                 stack.pop()
             self._pushed = False
         return False
@@ -326,10 +455,11 @@ def tree_check(spans: list[dict]) -> dict:
         # coverage applies to the BARRIER PATH only: checkpoint
         # uploads are async by contract and sampled serving reads
         # attach to an already-committed round — both legitimately
-        # outlive the root span
+        # outlive the root span (with their ``.fetch`` / ``.encode``
+        # / ... children)
         async_ok = {"ckpt_prepare", "ckpt_commit", "serving_read"}
         for s in spans:
-            if s is r or s["name"] in async_ok:
+            if s is r or s["name"].split(".")[0] in async_ok:
                 continue
             if s["ts"] < r0 - slack or s["ts"] + s["dur"] > r1 + slack:
                 covered = False

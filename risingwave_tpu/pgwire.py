@@ -30,6 +30,7 @@ import socketserver
 import struct
 import threading
 
+from risingwave_tpu.common.trace import GLOBAL_TRACE
 from risingwave_tpu.common.types import DataType
 
 # pg type OIDs for the text protocol
@@ -151,10 +152,7 @@ class _Handler(socketserver.BaseRequestHandler):
                 if tag == b"Q":
                     sql = body.rstrip(b"\x00").decode()
                     try:
-                        with lock:
-                            cols, rows = engine.query(sql)
-                        self._results(f, sql, cols, rows,
-                                      with_desc=True)
+                        self._statement(f, {"sql": sql}, with_desc=True)
                     except Exception as e:
                         self._error(f, str(e))
                     self._ready(f)
@@ -257,13 +255,7 @@ class _Handler(socketserver.BaseRequestHandler):
                         p = portals.get(name)
                         if p is None:
                             raise ValueError(f"unknown portal {name!r}")
-                        if "rows" not in p:
-                            with lock:
-                                p["cols"], p["rows"] = engine.query(
-                                    p["sql"]
-                                )
-                        self._results(f, p["sql"], p["cols"],
-                                      p["rows"], with_desc=False)
+                        self._statement(f, p, with_desc=False)
                     elif tag == b"C":  # Close
                         kind = body[:1]
                         name, _ = self._take_cstr(body, 1)
@@ -283,6 +275,27 @@ class _Handler(socketserver.BaseRequestHandler):
                     in_error = True
         finally:
             f.close()
+
+    def _statement(self, f, p: dict, with_desc: bool) -> None:
+        """Run one statement (``Q``, or ``Execute`` of portal ``p``,
+        whose rows an eager Describe may hold already) and write its
+        results: one ``read-<n>`` span tree — the wait for the engine
+        lock, ``engine.query`` under it, the rows going out."""
+        engine = self.server.engine
+        sql = p["sql"]
+        with GLOBAL_TRACE.root(
+                "read", "read", metrics=getattr(engine, "metrics", None),
+                kind=sql.split(None, 1)[0].upper() if sql.strip() else "",
+        ) as sp:
+            if "rows" not in p:
+                with GLOBAL_TRACE.held(self.server.engine_lock,
+                                       "read.lock_wait"):
+                    with GLOBAL_TRACE.span("read.execute"):
+                        p["cols"], p["rows"] = engine.query(sql)
+            sp.set(rows=len(p["rows"] or ()))
+            with GLOBAL_TRACE.span("read.send"):
+                self._results(f, sql, p["cols"], p["rows"],
+                              with_desc=with_desc)
 
     @staticmethod
     def _take_cstr(body: bytes, off: int) -> tuple[str, int]:

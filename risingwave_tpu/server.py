@@ -39,23 +39,30 @@ import threading
 import time
 import traceback
 
+from risingwave_tpu.common.trace import GLOBAL_TRACE, to_chrome_trace
+
 
 def _start_metrics_http(render, host: str, port: int):
     """Per-role stdlib ``/metrics`` endpoint (the unified metrics
     plane's per-process scrape surface — the meta's ``ctl cluster
     metrics`` aggregates the same text over RPC, so a Prometheus
-    deployment can scrape either each process or just the meta)."""
+    deployment can scrape either each process or just the meta), and
+    ``/trace`` beside it (``_render_trace``)."""
     import http.server
 
     class _Handler(http.server.BaseHTTPRequestHandler):
         def do_GET(self):  # noqa: N802 — http.server API
-            if self.path.split("?")[0] not in ("/", "/metrics"):
+            path, _, query = self.path.partition("?")
+            if path == "/trace":
+                body, ctype = _render_trace(query), "application/json"
+            elif path in ("/", "/metrics"):
+                body = render().encode()
+                ctype = "text/plain; version=0.0.4"
+            else:
                 self.send_error(404)
                 return
-            body = render().encode()
             self.send_response(200)
-            self.send_header("Content-Type",
-                             "text/plain; version=0.0.4")
+            self.send_header("Content-Type", ctype)
             self.send_header("Content-Length", str(len(body)))
             self.end_headers()
             self.wfile.write(body)
@@ -67,6 +74,20 @@ def _start_metrics_http(render, host: str, port: int):
     threading.Thread(target=httpd.serve_forever,
                      name="metrics-http", daemon=True).start()
     return httpd
+
+
+def _render_trace(query: str) -> bytes:
+    """``GET /trace``: this process's span ring as JSON, oldest first
+    (``?format=chrome``: Chrome ``trace_event`` JSON for Perfetto) —
+    for one process what ``ctl cluster trace`` is for a cluster.  Takes
+    no engine lock."""
+    from urllib.parse import parse_qs
+
+    q = parse_qs(query)
+    spans = GLOBAL_TRACE.dump((q.get("trace_id") or [None])[0])
+    if (q.get("format") or [""])[0] == "chrome":
+        return json.dumps(to_chrome_trace(spans)).encode()
+    return json.dumps({"role": GLOBAL_TRACE.role, "spans": spans}).encode()
 
 
 def _handshake(role: str, **fields) -> None:
@@ -104,9 +125,7 @@ class SingleNode:
             ) / 1000.0
             t0 = time.monotonic()
             try:
-                with self._lock:
-                    if self.engine.jobs:
-                        self.engine.tick(barriers=1)
+                self._tick_once()
             except Exception as e:
                 # a barrier that raised (state overflow, a failed
                 # upload) is counted and logged, never a silently dead
@@ -118,6 +137,19 @@ class SingleNode:
             # never zero: a saturated loop that re-takes the engine
             # lock at once starves the pgwire sessions waiting for it
             self._stop.wait(max(interval - elapsed, 0.001))
+
+    def _tick_once(self) -> None:
+        """One barrier of the loop, one ``tick-<n>`` span tree: the
+        root runs from asking for the engine lock (``tick.lock_wait``
+        is what reads and scrapes take out of every tick) to
+        ``Engine.tick`` returning."""
+        if not self.engine.jobs:
+            return  # nothing to drive: no barrier, no tree
+        with GLOBAL_TRACE.root("tick", "tick",
+                               metrics=self.engine.metrics):
+            with GLOBAL_TRACE.held(self._lock, "tick.lock_wait"):
+                if self.engine.jobs:
+                    self.engine.tick(barriers=1)
 
     def start(self, host: str = "127.0.0.1", port: int = 4566,
               ticker: bool = True):
@@ -138,12 +170,16 @@ class SingleNode:
     def render_metrics(self) -> str:
         """The scrape: the registry, after the on-demand collectors
         (device readbacks, so between barriers, under the engine
-        lock)."""
-        with self._lock:
-            self.engine.collect_join_metrics()
-            self.engine.collect_checkpoint_metrics()
-            self.engine.collect_shard_metrics()
-        return self.engine.metrics.render_prometheus()
+        lock).  One ``scrape-<n>`` span tree."""
+        with GLOBAL_TRACE.root("scrape", "render_metrics",
+                               metrics=self.engine.metrics):
+            with GLOBAL_TRACE.held(self._lock,
+                                   "render_metrics.lock_wait"):
+                with GLOBAL_TRACE.span("render_metrics.collect"):
+                    self.engine.collect_join_metrics()
+                    self.engine.collect_checkpoint_metrics()
+                    self.engine.collect_shard_metrics()
+            return self.engine.metrics.render_prometheus()
 
     def tick(self, barriers: int = 1,
              chunks_per_barrier: int | None = None) -> None:
@@ -245,6 +281,7 @@ def _run_compute(args) -> None:
         host=args.host, port=args.rpc_port,
         heartbeat_interval_s=args.heartbeat_interval,
     ).start()
+    GLOBAL_TRACE.configure(metrics=worker.engine.metrics)
     if args.metrics_port:
         _start_metrics_http(worker.engine.metrics.render_prometheus,
                             args.host, args.metrics_port)
@@ -350,8 +387,6 @@ def main() -> None:
     # trace-lite identity + sampling, wired BEFORE any role boots so
     # even registration RPCs carry (or drop) trace context uniformly.
     # A compute --config-json may override via ClusterConfig.
-    from risingwave_tpu.common.trace import GLOBAL_TRACE
-
     sample_n, capacity = args.trace_sample_n, args.trace_buffer_spans
     if args.config_json:
         try:
@@ -362,6 +397,14 @@ def main() -> None:
             pass
     GLOBAL_TRACE.configure(role=args.role, sample_n=sample_n,
                            capacity=capacity)
+    if args.role in ("single", "compute"):
+        # the roles that hold a chip import jax anyway: their spans
+        # are also annotations on the profiler's host plane, on one
+        # clock with the device's operations (a TraceMe with no
+        # session active costs tens of nanoseconds)
+        import jax.profiler
+
+        GLOBAL_TRACE.configure(annotate=jax.profiler.TraceAnnotation)
 
     if args.role == "meta":
         _run_meta(args)
@@ -373,6 +416,7 @@ def main() -> None:
         _run_serving(args)
         return
     node = SingleNode(_node_config(args), data_dir=args.data_dir)
+    GLOBAL_TRACE.configure(metrics=node.engine.metrics)
     server = node.start(args.host, args.port)
     if args.metrics_port:
         _start_metrics_http(node.render_metrics,

@@ -2166,58 +2166,105 @@ class Engine:
     # -- the global barrier loop ----------------------------------------
     def tick(self, barriers: int = 1,
              chunks_per_barrier: int | None = None) -> None:
-        """Advance every streaming job (meta's PeriodicBarriers analog)."""
+        """Advance every streaming job (meta's PeriodicBarriers analog).
+
+        One span tree a call: the served node's ``_tick_loop`` opens the
+        ``tick`` root (it also times its wait for the engine lock) and
+        this attaches under it; called with no trace active (an
+        in-process engine, ``FLUSH``, tests) it opens the root itself."""
         if chunks_per_barrier is None:
             chunks_per_barrier = int(
                 self.system_params.get("chunks_per_barrier")
             )
         # runtime-mutable cadence (ref ALTER SYSTEM SET applies live)
-        ckpt_freq = int(self.system_params.get("checkpoint_frequency"))
-        maint = int(self.system_params.get(
-            "maintenance_interval_checkpoints"
-        ))
-        snap_iv = int(self.system_params.get(
-            "snapshot_interval_checkpoints"
-        ))
+        cadence = self._barrier_cadence()
         stall_hook = self._storage_stall_hook \
             if self.hummock is not None else None
-        upload_window = int(self.system_params.get(
-            "checkpoint_upload_window"
-        ))
-        for _ in range(barriers):
+        with GLOBAL_TRACE.root("tick", "tick", metrics=self.metrics,
+                               barriers=barriers) as sp:
+            rows = 0
+            for _ in range(barriers):
+                for job in self.jobs:
+                    if hasattr(job, "write_stall_hook"):
+                        job.write_stall_hook = stall_hook
+                    rows += self._job_barrier(job, chunks_per_barrier,
+                                              cadence)
+            # batch boundary = durability point: uploads sealed inside
+            # the window pipelined against the barrier loop; they must
+            # land before tick() returns (tests/FLUSH/restart
+            # determinism).  Cluster workers are driven via tick_job
+            # instead — there the seal/ack split is the meta's global
+            # protocol.
             for job in self.jobs:
-                job.checkpoint_frequency = ckpt_freq
-                job.maintenance_interval = maint
-                job.snapshot_interval = snap_iv
-                job.upload_window = upload_window
-                if getattr(job, "metrics", None) is None:
-                    job.metrics = self.metrics
-                if hasattr(job, "write_stall_hook"):
-                    job.write_stall_hook = stall_hook
-                t0 = time.perf_counter()
-                if hasattr(job, "run_chunks"):
-                    # traceable sources batch the whole inter-barrier
-                    # window into one dispatch (q1 host-overhead fix)
-                    rows = job.run_chunks(chunks_per_barrier)
-                else:
-                    rows = 0
-                    for _ in range(chunks_per_barrier):
-                        rows += job.chunk_round()
-                t1 = time.perf_counter()
-                job.inject_barrier()
-                t2 = time.perf_counter()
-                self.metrics.inc("stream_rows_total", rows, job=job.name)
-                self._observe_barrier(job.name, t2 - t0,
-                                      dispatch=t1 - t0, seal=t2 - t1)
-        # batch boundary = durability point: uploads sealed inside the
-        # window pipelined against the barrier loop; they must land
-        # before tick() returns (tests/FLUSH/restart determinism).
-        # Cluster workers are driven via tick_job instead — there the
-        # seal/ack split is the meta's global protocol.
-        for job in self.jobs:
-            if hasattr(job, "drain_uploads"):
-                job.drain_uploads()
-            self._export_checkpoint_gauges(job)
+                if hasattr(job, "drain_uploads"):
+                    job.drain_uploads()
+                self._export_checkpoint_gauges(job)
+            sp.set(rows=rows, epoch=max(
+                (getattr(j, "sealed_epoch", j.committed_epoch)
+                 for j in self.jobs), default=0))
+
+    def _barrier_cadence(self) -> tuple[int, int, int, int]:
+        """(checkpoint_frequency, maintenance interval, snapshot
+        interval, upload window), as the system parameters stand."""
+        get = self.system_params.get
+        return (int(get("checkpoint_frequency")),
+                int(get("maintenance_interval_checkpoints")),
+                int(get("snapshot_interval_checkpoints")),
+                int(get("checkpoint_upload_window")))
+
+    def _job_barrier(self, job, chunks_per_barrier: int, cadence: tuple,
+                     fenced: bool = False) -> int:
+        """ONE job across ONE barrier — the body ``tick`` and
+        ``tick_job`` share: the window's chunks (``run_chunks``: one
+        asynchronous dispatch where the source is traceable, so its
+        span is host time), the fenced source drain of a partitioned
+        job, then the seal (``inject_barrier``).  Feeds
+        ``stream_rows_total``, ``barrier_latency_seconds`` and
+        ``barrier_phase_seconds{phase}``; returns the rows pulled."""
+        (job.checkpoint_frequency, job.maintenance_interval,
+         job.snapshot_interval, job.upload_window) = cadence
+        if getattr(job, "metrics", None) is None:
+            job.metrics = self.metrics
+        name = job.name
+        t0 = time.perf_counter()
+        with GLOBAL_TRACE.span("run_chunks", metrics=self.metrics,
+                               job=name) as sp:
+            if hasattr(job, "run_chunks"):
+                # traceable sources batch the whole inter-barrier
+                # window into one dispatch (q1 host-overhead fix)
+                rows = job.run_chunks(chunks_per_barrier)
+            else:
+                rows = sum(job.chunk_round()
+                           for _ in range(chunks_per_barrier))
+            sp.set(rows=rows)
+        t1 = time.perf_counter()
+        if fenced:
+            # Exchange-lite: a partitioned barrier consumes EXACTLY to
+            # the round fence, however many chunks that takes — every
+            # partition's cursor seals ON the fence, so handover
+            # cursor checks hold even though shuffled partitions see
+            # different owned-row densities.  (Bounded: pending() is
+            # capped by min(local history, fence).)
+            with GLOBAL_TRACE.span("source_drain", metrics=self.metrics,
+                                   job=name):
+                for _ in range(1 << 20):
+                    if not self._fenced_pending(job):
+                        break
+                    rows += job.run_chunks(chunks_per_barrier) \
+                        if hasattr(job, "run_chunks") \
+                        else job.chunk_round()
+        t2 = time.perf_counter()
+        with GLOBAL_TRACE.span("inject_barrier", metrics=self.metrics,
+                               job=name):
+            job.inject_barrier()
+        t3 = time.perf_counter()
+        self.metrics.inc("stream_rows_total", rows, job=name)
+        self._observe_barrier(
+            name, t3 - t0, dispatch=t1 - t0,
+            source_drain=(t2 - t1) if fenced else None,
+            seal=t3 - t2,
+        )
+        return rows
 
     def tick_job(self, name: str, chunks_per_barrier: int = 1,
                  source_limits: dict | None = None) -> int:
@@ -2234,55 +2281,10 @@ class Engine:
         job = self._job_by_name(name)
         if source_limits:
             self._apply_source_limits(job, source_limits)
-        ckpt_freq = int(self.system_params.get("checkpoint_frequency"))
-        job.checkpoint_frequency = ckpt_freq
-        job.maintenance_interval = int(self.system_params.get(
-            "maintenance_interval_checkpoints"
-        ))
-        job.snapshot_interval = int(self.system_params.get(
-            "snapshot_interval_checkpoints"
-        ))
-        job.upload_window = int(self.system_params.get(
-            "checkpoint_upload_window"
-        ))
-        if getattr(job, "metrics", None) is None:
-            job.metrics = self.metrics
-        t0 = time.perf_counter()
-        with GLOBAL_TRACE.span("dispatch", job=name) as _sp:
-            if hasattr(job, "run_chunks"):
-                rows = job.run_chunks(chunks_per_barrier)
-            else:
-                rows = 0
-                for _ in range(chunks_per_barrier):
-                    rows += job.chunk_round()
-            _sp.set(rows=rows)
-        t1 = time.perf_counter()
         fenced = bool(source_limits) \
             and getattr(job, "n_vnodes", None) is not None
-        if fenced:
-            # Exchange-lite: a partitioned barrier consumes EXACTLY to
-            # the round fence, however many chunks that takes — every
-            # partition's cursor seals ON the fence, so handover
-            # cursor checks hold even though shuffled partitions see
-            # different owned-row densities.  (Bounded: pending() is
-            # capped by min(local history, fence).)
-            with GLOBAL_TRACE.span("source_drain", job=name):
-                for _ in range(1 << 20):
-                    if not self._fenced_pending(job):
-                        break
-                    rows += job.run_chunks(chunks_per_barrier) \
-                        if hasattr(job, "run_chunks") \
-                        else job.chunk_round()
-        t2 = time.perf_counter()
-        with GLOBAL_TRACE.span("seal", job=name):
-            job.inject_barrier()
-        t3 = time.perf_counter()
-        self.metrics.inc("stream_rows_total", rows, job=job.name)
-        self._observe_barrier(
-            job.name, t3 - t0, dispatch=t1 - t0,
-            source_drain=(t2 - t1) if fenced else None,
-            seal=t3 - t2,
-        )
+        self._job_barrier(job, chunks_per_barrier,
+                          self._barrier_cadence(), fenced)
         self._export_checkpoint_gauges(job)
         # the SEAL, not the durable commit: the cluster's global epoch
         # advances only when every job's upload acks (meta polls
@@ -3805,6 +3807,12 @@ class Engine:
                 else entry.job.vnodes), n_vn
 
     def _mv_rows(self, entry: CatalogEntry):
+        """Every row of a view, on the host (a device readback, under
+        the engine lock: span ``_mv_rows``)."""
+        with GLOBAL_TRACE.span("_mv_rows", mv=entry.name):
+            return self._mv_rows_impl(entry)
+
+    def _mv_rows_impl(self, entry: CatalogEntry):
         from risingwave_tpu.stream.sharded import ShardedStreamingJob
 
         vn_set, n_vn = self._mv_vnode_set(entry)
@@ -3853,7 +3861,8 @@ class Engine:
             state = state[i]
         if vn_set is not None:
             state = self._vnode_filtered_mv_state(state, vn_set, n_vn)
-        return entry.mv_executor.to_host(state)
+        with GLOBAL_TRACE.span("_mv_rows.to_host"):
+            return entry.mv_executor.to_host(state)
 
     @staticmethod
     def _order_permutation(chunk, order_by, n_rows: int) -> list[int]:

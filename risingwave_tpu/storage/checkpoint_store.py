@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from risingwave_tpu.common.trace import GLOBAL_TRACE
 from risingwave_tpu.storage.digest import (
     DEFAULT_BLOCK_ELEMS,
     digest_leaves,
@@ -240,40 +241,44 @@ class CheckpointStore:
             kind = "full"
 
         payload: dict[str, np.ndarray] = {}
-        if kind == "full":
-            host = jax.device_get(
-                [jnp.asarray(x).reshape(-1) for x in leaves]
-            )
-            for i, (h, s) in enumerate(zip(host, shapes)):
-                payload[f"leaf_{i}"] = np.asarray(h).reshape(s)
-        else:
-            # fetch only dirty runs, flat per leaf; lane leaves walk
-            # per shard row so no run crosses a shard boundary
-            off = 0
-            for i, (x, nb, shape, ln) in enumerate(
-                    zip(leaves, nblocks, shapes, lanes)):
-                leaf_dirty = dirty[off:off + nb]
-                off += nb
-                if not leaf_dirty.any():
-                    continue
-                # ONE transfer for a leaf with anything dirty, runs
-                # cut on the host: a device slice per run is a program
-                # per run (its bounds are static), which on the chip
-                # cost q8 18 s a barrier in compiles and round trips
-                flat = np.asarray(x).reshape(-1)
-                n = flat.shape[0]
-                rows, m = ln if ln else (1, n)
-                nb_row = nb // rows
-                for r in range(rows):
-                    row_dirty = leaf_dirty[r * nb_row:(r + 1) * nb_row]
-                    base_el = r * m
-                    # coalesce adjacent dirty blocks into runs
-                    edges = np.flatnonzero(np.diff(
-                        np.r_[False, row_dirty, False]))
-                    for b, e in zip(edges[::2], edges[1::2]):
-                        s_el = base_el + int(b) * block
-                        e_el = base_el + min(int(e) * block, m)
-                        payload[f"r_{i}_{s_el}"] = flat[s_el:e_el].copy()
+        # the device→host transfer (and the cut into runs), apart from
+        # the diff above
+        with GLOBAL_TRACE.span("ckpt_prepare.fetch", job=job_name,
+                               kind=kind):
+            if kind == "full":
+                host = jax.device_get(
+                    [jnp.asarray(x).reshape(-1) for x in leaves]
+                )
+                for i, (h, s) in enumerate(zip(host, shapes)):
+                    payload[f"leaf_{i}"] = np.asarray(h).reshape(s)
+            else:
+                # fetch only dirty runs, flat per leaf; lane leaves walk
+                # per shard row so no run crosses a shard boundary
+                off = 0
+                for i, (x, nb, shape, ln) in enumerate(
+                        zip(leaves, nblocks, shapes, lanes)):
+                    leaf_dirty = dirty[off:off + nb]
+                    off += nb
+                    if not leaf_dirty.any():
+                        continue
+                    # ONE transfer for a leaf with anything dirty, runs
+                    # cut on the host: a device slice per run is a program
+                    # per run (its bounds are static), which on the chip
+                    # cost q8 18 s a barrier in compiles and round trips
+                    flat = np.asarray(x).reshape(-1)
+                    n = flat.shape[0]
+                    rows, m = ln if ln else (1, n)
+                    nb_row = nb // rows
+                    for r in range(rows):
+                        row_dirty = leaf_dirty[r * nb_row:(r + 1) * nb_row]
+                        base_el = r * m
+                        # coalesce adjacent dirty blocks into runs
+                        edges = np.flatnonzero(np.diff(
+                            np.r_[False, row_dirty, False]))
+                        for b, e in zip(edges[::2], edges[1::2]):
+                            s_el = base_el + int(b) * block
+                            e_el = base_el + min(int(e) * block, m)
+                            payload[f"r_{i}_{s_el}"] = flat[s_el:e_el].copy()
         return {
             "job": job_name, "epoch": epoch, "kind": kind,
             "payload": payload, "treedef": treedef,
@@ -285,59 +290,70 @@ class CheckpointStore:
         cache — the durable commit point the uploader acks."""
         job_name, epoch, kind = prep["job"], prep["epoch"], prep["kind"]
         key = f"{job_name}/epoch_{epoch}"
-        buf = io.BytesIO()
-        np.savez(buf, **prep["payload"])
-        npz_bytes = buf.getvalue()
-        meta_bytes = pickle.dumps({
-            "treedef": prep["treedef"],
-            "source_state": prep["source_state"],
-            "epoch": epoch, "kind": kind,
-        })
-        with self._manifest_txn():
-            self.store.put(key + ".npz", npz_bytes)
-            self.store.put(key + ".meta", meta_bytes)
-            m = self._load_manifest()
-            job = m["jobs"].setdefault(job_name, {"epochs": []})
+        with GLOBAL_TRACE.span("ckpt_commit.encode", job=job_name):
+            buf = io.BytesIO()
+            np.savez(buf, **prep["payload"])
+            npz_bytes = buf.getvalue()
+            meta_bytes = pickle.dumps({
+                "treedef": prep["treedef"],
+                "source_state": prep["source_state"],
+                "epoch": epoch, "kind": kind,
+            })
             # crc32c trailer per epoch object, recorded in the
             # manifest (computed over the bytes BEFORE the put, so a
             # put corrupted in flight — or on disk later — mismatches
             # on read and the typed CheckpointCorruption fires)
-            job.setdefault("crc", {})[str(epoch)] = {
-                "npz": crc32c(npz_bytes), "meta": crc32c(meta_bytes),
-            }
-            # idempotent per epoch: a re-save of an already-committed
-            # epoch (e.g. ALTER PARALLELISM re-basing state at the
-            # current epoch) REPLACES the entry — appending would leave
-            # duplicate epochs in GC/load bookkeeping (advisor r4)
-            if epoch not in job["epochs"]:
-                job["epochs"].append(epoch)
-            job.setdefault("kind", {})[str(epoch)] = kind
-            job["committed"] = epoch
-            # GC beyond keep_epochs — but never break a delta chain:
-            # keep everything back to the BASE FULL of the oldest epoch
-            # that must stay readable (ref: hummock version GC keeps
-            # deltas reachable from a checkpointed version)
-            kinds = job["kind"]
-            epochs_l = job["epochs"]
-            if len(epochs_l) > self.keep_epochs:
-                idx = len(epochs_l) - self.keep_epochs
-                while idx > 0 and \
-                        kinds.get(str(epochs_l[idx]), "full") != "full":
-                    idx -= 1
-                for old in epochs_l[:idx]:
-                    kinds.pop(str(old), None)
-                    job.get("crc", {}).pop(str(old), None)
-                    for suffix in (".npz", ".meta"):
-                        self.store.delete(
-                            f"{job_name}/epoch_{old}{suffix}"
-                        )
-                job["epochs"] = epochs_l[idx:]
-            self._store_manifest(m)
-            # only after the manifest commit: a save that dies earlier
-            # must not leave the digest cache pointing at an orphan file
-            self._last_digests[job_name] = (epoch, prep["digests"])
-            self._since_full[job_name] = 0 if kind == "full" \
-                else self._since_full.get(job_name, 0) + 1
+            crc = {"npz": crc32c(npz_bytes), "meta": crc32c(meta_bytes)}
+        with self._manifest_txn():
+            with GLOBAL_TRACE.span("ckpt_commit.put", job=job_name,
+                                   bytes=len(npz_bytes) + len(meta_bytes)):
+                self.store.put(key + ".npz", npz_bytes)
+                self.store.put(key + ".meta", meta_bytes)
+            with GLOBAL_TRACE.span("ckpt_commit.manifest", job=job_name):
+                self._commit_manifest(job_name, epoch, kind, crc,
+                                      prep["digests"])
+
+    def _commit_manifest(self, job_name: str, epoch: int, kind: str,
+                         crc: dict, digests) -> None:
+        """The manifest read-modify-write of one commit (inside the
+        manifest transaction): record the epoch, GC beyond
+        ``keep_epochs``, store, advance the digest cache."""
+        m = self._load_manifest()
+        job = m["jobs"].setdefault(job_name, {"epochs": []})
+        job.setdefault("crc", {})[str(epoch)] = crc
+        # idempotent per epoch: a re-save of an already-committed
+        # epoch (e.g. ALTER PARALLELISM re-basing state at the
+        # current epoch) REPLACES the entry — appending would leave
+        # duplicate epochs in GC/load bookkeeping (advisor r4)
+        if epoch not in job["epochs"]:
+            job["epochs"].append(epoch)
+        job.setdefault("kind", {})[str(epoch)] = kind
+        job["committed"] = epoch
+        # GC beyond keep_epochs — but never break a delta chain:
+        # keep everything back to the BASE FULL of the oldest epoch
+        # that must stay readable (ref: hummock version GC keeps
+        # deltas reachable from a checkpointed version)
+        kinds = job["kind"]
+        epochs_l = job["epochs"]
+        if len(epochs_l) > self.keep_epochs:
+            idx = len(epochs_l) - self.keep_epochs
+            while idx > 0 and \
+                    kinds.get(str(epochs_l[idx]), "full") != "full":
+                idx -= 1
+            for old in epochs_l[:idx]:
+                kinds.pop(str(old), None)
+                job.get("crc", {}).pop(str(old), None)
+                for suffix in (".npz", ".meta"):
+                    self.store.delete(
+                        f"{job_name}/epoch_{old}{suffix}"
+                    )
+            job["epochs"] = epochs_l[idx:]
+        self._store_manifest(m)
+        # only after the manifest commit: a save that dies earlier
+        # must not leave the digest cache pointing at an orphan file
+        self._last_digests[job_name] = (epoch, digests)
+        self._since_full[job_name] = 0 if kind == "full" \
+            else self._since_full.get(job_name, 0) + 1
 
     def save(self, job_name: str, epoch: int, states: Any,
              source_state: dict, digests=None, lanes=None) -> None:

@@ -73,7 +73,10 @@ class CompactorService:
         t0 = time.perf_counter()
         with GLOBAL_TRACE.sampled_span("compact_cycle") as tsp:
             did = self.storage.compact_once()
-            tsp.set(did=bool(did))
+            if not did:
+                # an idle poll (every ``poll_interval_s``) would push
+                # the barriers' and reads' trees out of the ring
+                tsp.drop()
         if did:
             self.tasks_run += 1
             if self.metrics is not None:

@@ -248,12 +248,17 @@ class CheckpointUploader:
                             k, task.epoch, hs, {}),
                         retry_on=(OSError,), label="spill_save",
                     )
-                digests = np.asarray(task.digests) \
-                    if task.digests is not None else None
                 with GLOBAL_TRACE.span("ckpt_prepare",
                                        ctx=task.trace_ctx,
+                                       metrics=self.metrics,
                                        job=self.job_name,
                                        epoch=task.epoch):
+                    # waits for the shadow update program, then reads
+                    # its digest vector
+                    with GLOBAL_TRACE.span("ckpt_prepare.digests",
+                                           job=self.job_name):
+                        digests = np.asarray(task.digests) \
+                            if task.digests is not None else None
                     prep = self.store.prepare(
                         self.job_name, task.epoch, task.leaves,
                         task.shapes, task.treedef, task.source_state,
@@ -263,6 +268,7 @@ class CheckpointUploader:
                 task.fetched.set()
                 with GLOBAL_TRACE.span("ckpt_commit",
                                        ctx=task.trace_ctx,
+                                       metrics=self.metrics,
                                        job=self.job_name,
                                        epoch=task.epoch):
                     self.retry.run(lambda: self.store.commit(prep),

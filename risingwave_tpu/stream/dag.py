@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from risingwave_tpu.common.epoch import EpochPair
+from risingwave_tpu.common.trace import GLOBAL_TRACE
 from risingwave_tpu.stream.fragment import (
     COUNTER_ATTRS,
     Fragment,
@@ -1126,14 +1127,15 @@ class DagJob(CheckpointPipelineMixin):
     def inject_barrier(self) -> None:
         self.barriers_seen += 1
         sealed = self.epoch.curr.value
-        if self.staged:
-            self._counters = self._staged_barrier(sealed)
-        else:
-            if self._barrier_prog is None:
-                self._barrier_prog = self._make_barrier_prog()
-            self.states, self._counters = self._barrier_prog(
-                self.states, self._barrier_epoch_arg(sealed)
-            )
+        with GLOBAL_TRACE.span("inject_barrier.dispatch", job=self.name):
+            if self.staged:
+                self._counters = self._staged_barrier(sealed)
+            else:
+                if self._barrier_prog is None:
+                    self._barrier_prog = self._make_barrier_prog()
+                self.states, self._counters = self._barrier_prog(
+                    self.states, self._barrier_epoch_arg(sealed)
+                )
 
         if self.barriers_seen % self.checkpoint_frequency == 0:
             self._ckpts_since_maintain += 1
@@ -1179,26 +1181,30 @@ class DagJob(CheckpointPipelineMixin):
     def _maintain(self, sealed) -> None:
         if self._maintain_prog is None:
             self._maintain_prog = self._make_maintain_prog()
-        self.states = self._maintain_prog(self.states)
-        if self._counters is None:
-            return
-        values = np.asarray(self._counters)  # THE one device sync
-        residual = check_counter_values(
-            self.name, self.counter_labels, values, self.metrics
-        )
-        for _ in range(64):
-            if not residual:
-                break
-            if self.staged:
-                self._counters = self._staged_barrier(sealed)
-            else:
-                self.states, self._counters = self._barrier_prog(
-                    self.states, self._barrier_epoch_arg(sealed)
-                )
+        with GLOBAL_TRACE.span("_maintain", job=self.name):
+            self.states = self._maintain_prog(self.states)
+            if self._counters is None:
+                return
+            # THE one device sync
+            with GLOBAL_TRACE.span("_maintain.device_wait",
+                                   job=self.name):
+                values = np.asarray(self._counters)
             residual = check_counter_values(
-                self.name, self.counter_labels,
-                np.asarray(self._counters), self.metrics,
+                self.name, self.counter_labels, values, self.metrics
             )
+            for _ in range(64):
+                if not residual:
+                    break
+                if self.staged:
+                    self._counters = self._staged_barrier(sealed)
+                else:
+                    self.states, self._counters = self._barrier_prog(
+                        self.states, self._barrier_epoch_arg(sealed)
+                    )
+                residual = check_counter_values(
+                    self.name, self.counter_labels,
+                    np.asarray(self._counters), self.metrics,
+                )
 
     # -- checkpoint / recovery ------------------------------------------
     def _deliver_all_sinks(self, epoch_val) -> None:
@@ -1213,15 +1219,19 @@ class DagJob(CheckpointPipelineMixin):
     def _commit_checkpoint(self, sealed) -> None:
         # spill tiers drain under the mesh too (per-shard tiers); only
         # sink delivery stays meshless (sharded plans exclude sinks)
-        self._drain_spill_tiers(sealed)
-        if self.mesh is None:
-            up = self._ensure_uploader()
-            if up is None or up.pending() == 0:
-                self._deliver_all_sinks(sealed)
-            else:
-                # uploader behind: delivery advances on ack only
-                self._sinks_due = True
-        self._snapshot_and_save(sealed)
+        with GLOBAL_TRACE.span("_commit_checkpoint", job=self.name,
+                               epoch=sealed):
+            self._drain_spill_tiers(sealed)
+            if self.mesh is None:
+                up = self._ensure_uploader()
+                if up is None or up.pending() == 0:
+                    with GLOBAL_TRACE.span("_commit_checkpoint.sinks",
+                                           job=self.name):
+                        self._deliver_all_sinks(sealed)
+                else:
+                    # uploader behind: delivery advances on ack only
+                    self._sinks_due = True
+            self._snapshot_and_save(sealed)
 
     # -- spill-to-host (stream/spill.py) --------------------------------
     def _restore_spill_tiers(self, epoch: int) -> None:

@@ -34,6 +34,15 @@ WM_SAFE_FLOOR = -(1 << 62)
 COUNTER_ATTRS = ("inconsistency", "overflow", "emit_overflow")
 
 
+def executor_scope(i: int, ex, phase: str):
+    """``jax.named_scope("<ExecutorClass>.<index>/<phase>")`` —
+    ``HashAgg.1/apply``, ``Materialize.2/flush``: the name a device
+    profile puts an executor's operations under.  Metadata only: the
+    compiled code is the same with or without it."""
+    cls = type(ex).__name__.removesuffix("Executor")
+    return jax.named_scope(f"{cls}.{i}/{phase}")
+
+
 def collect_counters(executors, states):
     """Gather every executor's error counters + residual pending-flush
     into ONE device vector (labels, int64 [n]).
@@ -44,13 +53,14 @@ def collect_counters(executors, states):
     vals: list[jnp.ndarray] = []
     for i, ex in enumerate(executors):
         st = states[i]
-        for attr in COUNTER_ATTRS:
-            if hasattr(st, attr):
-                labels.append(f"{ex}.{attr}")
-                vals.append(getattr(st, attr).astype(jnp.int64))
-        if hasattr(ex, "pending_flush"):
-            labels.append(f"{ex}.pending")
-            vals.append(ex.pending_flush(st).astype(jnp.int64))
+        with executor_scope(i, ex, "counters"):
+            for attr in COUNTER_ATTRS:
+                if hasattr(st, attr):
+                    labels.append(f"{ex}.{attr}")
+                    vals.append(getattr(st, attr).astype(jnp.int64))
+            if hasattr(ex, "pending_flush"):
+                labels.append(f"{ex}.pending")
+                vals.append(ex.pending_flush(st).astype(jnp.int64))
     vec = jnp.stack(vals) if vals else jnp.zeros((0,), jnp.int64)
     return labels, vec
 
@@ -98,7 +108,8 @@ class Fragment:
         for i, ex in enumerate(self.executors):
             if cur is None:
                 break
-            new_states[i], cur = ex.apply(states[i], cur)
+            with executor_scope(i, ex, "apply"):
+                new_states[i], cur = ex.apply(states[i], cur)
         return tuple(new_states), cur
 
     def step(self, states: tuple, chunk: Chunk):
@@ -110,18 +121,18 @@ class Fragment:
         new_states = list(states)
         outs: list[Chunk] = []
         for i, ex in enumerate(self.executors):
-            if not ex.emits_on_flush:
-                new_states[i], _ = ex.flush(new_states[i], epoch)
-                continue
-            new_states[i], emitted = ex.flush(new_states[i], epoch)
-            if emitted is None:
+            with executor_scope(i, ex, "flush"):
+                new_states[i], emitted = ex.flush(new_states[i], epoch)
+            if not ex.emits_on_flush or emitted is None:
                 continue
             # emitted changelog flows through the rest of the chain
             cur = emitted
             for j in range(i + 1, len(self.executors)):
                 if cur is None:
                     break
-                new_states[j], cur = self.executors[j].apply(new_states[j], cur)
+                ex2 = self.executors[j]
+                with executor_scope(j, ex2, "apply"):
+                    new_states[j], cur = ex2.apply(new_states[j], cur)
             if cur is not None:
                 outs.append(cur)
         return tuple(new_states), outs
@@ -199,7 +210,8 @@ class Fragment:
             )
             wm = Watermark(ex.ts_col, val)
             for j, ex2 in enumerate(self.executors):
-                new_states[j] = ex2.on_watermark(new_states[j], wm)
+                with executor_scope(j, ex2, "watermark"):
+                    new_states[j] = ex2.on_watermark(new_states[j], wm)
         return tuple(new_states)
 
     def _barrier_impl(self, states, epoch):
@@ -229,7 +241,8 @@ class Fragment:
         new_states = list(states)
         for i, ex in enumerate(self.executors):
             if hasattr(ex, "maybe_rehash"):
-                new_states[i] = ex.maybe_rehash(new_states[i])
+                with executor_scope(i, ex, "rehash"):
+                    new_states[i] = ex.maybe_rehash(new_states[i])
         return tuple(new_states)
 
     def maintain(self, states):

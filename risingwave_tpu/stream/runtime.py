@@ -143,8 +143,10 @@ class CheckpointPipelineMixin:
         boundaries, orderly stop, recovery).  Within a batch the
         uploads pipeline; the batch boundary is the freshness point."""
         if self._uploader is not None:
-            self._uploader.drain(raise_error=raise_error)
-            self._process_upload_acks()
+            # the barrier loop standing still for its own upload
+            with GLOBAL_TRACE.span("drain_uploads", job=self.name):
+                self._uploader.drain(raise_error=raise_error)
+                self._process_upload_acks()
 
     def _deliver_all_sinks(self, epoch_val) -> None:
         """Subclass hook: drain sink ring buffers at ``epoch_val``."""
@@ -167,7 +169,9 @@ class CheckpointPipelineMixin:
         up = self._ensure_uploader()
         if up is not None:
             # bounded in-flight window (mirrors the L0-depth stall)
-            self.stall_seconds += up.wait_window(self.upload_window)
+            with GLOBAL_TRACE.span("_commit_checkpoint.wait_window",
+                                   job=self.name):
+                self.stall_seconds += up.wait_window(self.upload_window)
             self._process_upload_acks()
         if self._shadow is not None and (
                 not self._shadow.matches(self.states)
@@ -182,8 +186,14 @@ class CheckpointPipelineMixin:
             if store is not None:
                 store.invalidate(self.ckpt_key)
             self._shadow = None
-        with GLOBAL_TRACE.span("snapshot", job=getattr(
-                self, "name", "?"), epoch=epoch_val):
+        if self._shadow is not None and up is not None:
+            # the update donates the shadow buffers in-flight fetches
+            # still read — wait for the fetch point only
+            with GLOBAL_TRACE.span("_commit_checkpoint.wait_fetched",
+                                   job=self.name):
+                up.wait_fetched()
+        with GLOBAL_TRACE.span("snapshot", job=self.name,
+                               epoch=epoch_val):
             if self._shadow is None:
                 self._shadow = ShadowSnapshot(
                     self.states,
@@ -194,10 +204,6 @@ class CheckpointPipelineMixin:
                 )
                 digests = self._shadow.digests
             else:
-                if up is not None:
-                    # the update donates the shadow buffers in-flight
-                    # fetches still read — wait for the fetch point only
-                    up.wait_fetched()
                 digests = self._shadow.update(self.states, epoch_val)
         self.sealed_epoch = epoch_val
         self.checkpoints = [CheckpointSnapshot(
@@ -397,9 +403,9 @@ class StreamingJob(CheckpointPipelineMixin):
         if hasattr(source, "impl") and hasattr(source, "next_base"):
 
             def _fused(states, k0):
-                return fragment._step_impl(
-                    states, source.impl(k0, source.cap)
-                )
+                with jax.named_scope("gen"):
+                    chunk = source.impl(k0, source.cap)
+                return fragment._step_impl(states, chunk)
 
             self._fused = jax.jit(_fused, donate_argnums=(0,))
 
@@ -451,9 +457,9 @@ class StreamingJob(CheckpointPipelineMixin):
 
             def _multi(states, k0):
                 def body(i, st):
-                    st2, _ = self.fragment._step_impl(
-                        st, self.source.impl(k0 + i * stride, cap)
-                    )
+                    with jax.named_scope("gen"):
+                        chunk = self.source.impl(k0 + i * stride, cap)
+                    st2, _ = self.fragment._step_impl(st, chunk)
                     return st2
 
                 return jax.lax.fori_loop(0, n, body, states)
@@ -497,9 +503,10 @@ class StreamingJob(CheckpointPipelineMixin):
             self.stall_seconds += self.write_stall_hook()
 
         epoch_val = barrier.epoch.prev.value
-        self.states, outs, self._counters = self.fragment.barrier(
-            self.states, epoch_val
-        )
+        with GLOBAL_TRACE.span("inject_barrier.dispatch", job=self.name):
+            self.states, outs, self._counters = self.fragment.barrier(
+                self.states, epoch_val
+            )
         if barrier.is_checkpoint:
             self._ckpts_since_maintain += 1
             if self._ckpts_since_maintain >= self.maintenance_interval:
@@ -514,25 +521,31 @@ class StreamingJob(CheckpointPipelineMixin):
 
     def _maintain(self, epoch_val) -> None:
         """Rehash (on device) + the single counters readback."""
-        self.states = self.fragment.maintain(self.states)
-        if self._counters is None:
-            return
-        values = np.asarray(self._counters)  # THE one device sync
-        residual = check_counter_values(
-            self.name, self.fragment.counter_labels, values, self.metrics
-        )
-        # residual pending beyond MAX_DRAIN_ROUNDS×emit_capacity per
-        # barrier: pathological; finish draining with host loops
-        for _ in range(64):
-            if not residual:
-                break
-            self.states, _, self._counters = self.fragment.barrier(
-                self.states, epoch_val
-            )
+        with GLOBAL_TRACE.span("_maintain", job=self.name):
+            self.states = self.fragment.maintain(self.states)
+            if self._counters is None:
+                return
+            # THE one device sync: the host blocked on the chip until
+            # the window, barrier and maintain programs have run
+            with GLOBAL_TRACE.span("_maintain.device_wait",
+                                   job=self.name):
+                values = np.asarray(self._counters)
             residual = check_counter_values(
-                self.name, self.fragment.counter_labels,
-                np.asarray(self._counters), self.metrics,
+                self.name, self.fragment.counter_labels, values,
+                self.metrics,
             )
+            # residual pending beyond MAX_DRAIN_ROUNDS×emit_capacity
+            # per barrier: pathological; finish draining with host loops
+            for _ in range(64):
+                if not residual:
+                    break
+                self.states, _, self._counters = self.fragment.barrier(
+                    self.states, epoch_val
+                )
+                residual = check_counter_values(
+                    self.name, self.fragment.counter_labels,
+                    np.asarray(self._counters), self.metrics,
+                )
 
     def _drain_impl(self, states, i, ex):
         new_states = list(states)
@@ -581,28 +594,34 @@ class StreamingJob(CheckpointPipelineMixin):
         if self._ckpts_since_snapshot < self.snapshot_interval:
             return
         self._ckpts_since_snapshot = 0
-        self._drain_spill_tiers(epoch_val)
-        up = self._ensure_uploader()
-        if up is None or up.pending() == 0:
-            # at-least-once delivery, same window as the synchronous
-            # path (rows delivered before their epoch is durable ride
-            # THIS epoch's snapshot via the advanced read_cursor)
-            self.states = deliver_sinks(
-                self.fragment, self.states, epoch_val
-            )
-        else:
-            # uploader behind: defer delivery to the ack poll
-            self._sinks_due = True
-        src_state = self.source.state() if hasattr(self.source, "state") \
-            else {}
-        # ONE host materialization per tier, shared by the in-memory
-        # snapshot and the durable save
-        spill_host = {i: tier.snapshot() for i, _, _, tier in self._spill
-                      if tier.rows_absorbed}
-        spill_items = [(f"{self.ckpt_key}@spill{i}", spill_host[i])
-                       for i in spill_host]
-        self._snapshot_commit(epoch_val, src_state, spill_host,
-                              spill_items)
+        with GLOBAL_TRACE.span("_commit_checkpoint", job=self.name,
+                               epoch=epoch_val):
+            self._drain_spill_tiers(epoch_val)
+            up = self._ensure_uploader()
+            if up is None or up.pending() == 0:
+                # at-least-once delivery, same window as the
+                # synchronous path (rows delivered before their epoch
+                # is durable ride THIS epoch's snapshot via the
+                # advanced read_cursor)
+                with GLOBAL_TRACE.span("_commit_checkpoint.sinks",
+                                       job=self.name):
+                    self.states = deliver_sinks(
+                        self.fragment, self.states, epoch_val
+                    )
+            else:
+                # uploader behind: defer delivery to the ack poll
+                self._sinks_due = True
+            src_state = self.source.state() \
+                if hasattr(self.source, "state") else {}
+            # ONE host materialization per tier, shared by the
+            # in-memory snapshot and the durable save
+            spill_host = {i: tier.snapshot()
+                          for i, _, _, tier in self._spill
+                          if tier.rows_absorbed}
+            spill_items = [(f"{self.ckpt_key}@spill{i}", spill_host[i])
+                           for i in spill_host]
+            self._snapshot_commit(epoch_val, src_state, spill_host,
+                                  spill_items)
 
     def _apply_mutation(self, mutation) -> None:
         if mutation.kind == "pause":

@@ -212,6 +212,10 @@ def _build_programs(sig, block: int, digest: bool, shard_rows):
         return flat, d
 
     def update(live_leaves, shadow_leaves, old_digests):
+        with jax.named_scope("ShadowSnapshot/update"):
+            return _update(live_leaves, shadow_leaves, old_digests)
+
+    def _update(live_leaves, shadow_leaves, old_digests):
         if not digest:
             # store-less mode: no durable delta wants the digest, so
             # the cheapest correct snapshot is a straight copy INTO

@@ -6,8 +6,8 @@ Two gates for the observability plane (ISSUE 14):
    2 compute + 1 serving, real processes) runs N driver-paced rounds;
    for EVERY committed round ``ctl cluster trace`` must assemble one
    complete cross-role span tree: the meta round span parenting the
-   worker barrier-phase spans (dispatch / seal / mv_export), the
-   uploader's prepare/commit spans, a meta commit span that covers
+   worker barrier-phase spans (run_chunks / inject_barrier /
+   mv_export), the uploader's prepare/commit spans, a meta commit span that covers
    every worker seal span, and (for rounds after the first serving
    read) at least one sampled serving read span.  The ``--chrome``
    export must be loadable ``trace_event`` JSON, and the meta's
@@ -61,7 +61,7 @@ READ = "SELECT a, n, vol FROM qcnt"
 #: span names the meta records on the barrier path of every round
 META_SPANS = {"round", "barrier", "await_durable", "commit"}
 #: span names the owning worker records inside its barrier handling
-WORKER_SPANS = {"dispatch", "seal", "mv_export"}
+WORKER_SPANS = {"run_chunks", "inject_barrier", "mv_export"}
 
 
 def _free_port() -> int:
@@ -205,7 +205,7 @@ def run_cluster(rounds: int = 6, workers: int = 2,
                     f"round {rn}: missing spans {sorted(missing)}")
             # the meta round span must COVER every worker seal span
             root = _span_window(tr["spans"], "round")
-            seal = _span_window(tr["spans"], "seal")
+            seal = _span_window(tr["spans"], "inject_barrier")
             if root and seal:
                 slack = 0.25
                 if seal[0] < root[0] - slack or seal[1] > root[1] + slack:

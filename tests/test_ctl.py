@@ -156,7 +156,7 @@ def test_ctl_cluster_metrics_and_trace(tmp_path):
         tr = cluster_trace(addr, round=1, chrome=str(chrome))
         assert tr["round"] == 1 and tr["check"]["complete"]
         names = set(tr["check"]["names"])
-        assert {"round", "barrier", "commit", "seal"} <= names
+        assert {"round", "barrier", "commit", "inject_barrier"} <= names
         ct = json.loads(chrome.read_text())
         assert any(e.get("ph") == "X" for e in ct["traceEvents"])
     finally:

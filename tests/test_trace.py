@@ -3,7 +3,12 @@ assembly, propagation under injected faults, metrics-plane merging,
 and DROP-time series retirement."""
 
 import json
+import os
+import re
+import subprocess
+import sys
 import threading
+import urllib.request
 
 import pytest
 
@@ -30,12 +35,14 @@ def _clean_globals():
     are restored so unrelated suites never see leaked state."""
     role, n, cap = (GLOBAL_TRACE.role, GLOBAL_TRACE.sample_n,
                     GLOBAL_TRACE.capacity)
+    sink, annotate = GLOBAL_TRACE._metrics, GLOBAL_TRACE._annotate
     GLOBAL_TRACE.configure(role="proc", sample_n=1)
     GLOBAL_TRACE.clear()
     faults_mod.install(None)
     yield
     faults_mod.install(None)
     GLOBAL_TRACE.configure(role=role, sample_n=n, capacity=cap)
+    GLOBAL_TRACE._metrics, GLOBAL_TRACE._annotate = sink, annotate
     GLOBAL_TRACE.clear()
 
 
@@ -281,9 +288,10 @@ def test_retried_barrier_yields_exactly_one_span_tree(tmp_path):
         assert chk["complete"], chk
         names = [s["name"] for s in tr["spans"]]
         assert names.count("round") == 1  # exactly one root
-        assert names.count("seal") == 1  # chunks ran exactly once
+        # chunks ran exactly once
+        assert names.count("inject_barrier") == 1
         assert names.count("barrier") == 1  # one meta-side RPC span
-        assert "commit" in names and "dispatch" in names
+        assert "commit" in names and "run_chunks" in names
     finally:
         faults_mod.install(None)
         w.stop()
@@ -318,7 +326,7 @@ def test_failed_tick_reuses_round_root_no_duplicate_trees(tmp_path):
         names = [s["name"] for s in tr["spans"]]
         assert names.count("round") == 1
         assert "attempt" in names  # the retry rode the cached root
-        assert names.count("seal") == 1
+        assert names.count("inject_barrier") == 1
     finally:
         faults_mod.install(None)
         w.stop()
@@ -353,3 +361,328 @@ def test_drop_mv_and_index_retire_job_labeled_series():
     eng.execute("DROP MATERIALIZED VIEW m1")
     text = eng.metrics.render_prometheus()
     assert 'job="m1"' not in text  # ...and the MV's whole footprint
+
+
+# -- the served single node traces itself (ISSUE 26) ----------------------
+_Q7 = (
+    "CREATE SOURCE bid (auction BIGINT, bidder BIGINT, price BIGINT, "
+    "channel VARCHAR, url VARCHAR, date_time TIMESTAMP, WATERMARK FOR "
+    "date_time AS date_time - INTERVAL '4' SECOND) WITH (connector = "
+    "'nexmark', nexmark.table = 'bid', nexmark.event.rate = '1000000')",
+    "CREATE MATERIALIZED VIEW q7 AS SELECT window_start, max(price) AS "
+    "max_price, count(*) AS bids FROM TUMBLE(bid, date_time, INTERVAL "
+    "'10' SECOND) GROUP BY window_start",
+)
+
+#: every span of one barrier of a durable job, uploader thread included
+_TICK_SPANS = {
+    "tick", "run_chunks", "inject_barrier", "inject_barrier.dispatch",
+    "_maintain", "_maintain.device_wait", "_commit_checkpoint",
+    "_commit_checkpoint.sinks", "_commit_checkpoint.wait_window",
+    "snapshot", "drain_uploads", "ckpt_prepare", "ckpt_prepare.digests",
+    "ckpt_prepare.fetch", "ckpt_commit", "ckpt_commit.encode",
+    "ckpt_commit.put", "ckpt_commit.manifest",
+}
+
+
+def _trees(prefix: str) -> dict[str, list[dict]]:
+    out: dict[str, list[dict]] = {}
+    for s in GLOBAL_TRACE.dump():
+        if s["trace_id"].startswith(prefix):
+            out.setdefault(s["trace_id"], []).append(s)
+    return out
+
+
+def _span_series(text: str, family: str) -> dict[tuple, float]:
+    """{(span, job or None): value} of one ``trace_span_*`` family."""
+    out = {}
+    for line in text.splitlines():
+        m = re.match(family + r"\{(.*)\} (\S+)$", line)
+        if m:
+            lb = dict(kv.split("=") for kv in m.group(1).split(","))
+            out[(lb["span"].strip('"'),
+                 lb.get("job", "").strip('"') or None)] = float(m.group(2))
+    return out
+
+
+def test_engine_three_barriers_yield_three_tick_trees(tmp_path):
+    """An in-process engine opens the ``tick`` root itself: one tree a
+    call, the uploader thread's spans parented across the thread."""
+    from risingwave_tpu.sql.engine import Engine
+
+    eng = Engine(_cluster_cfg(), data_dir=str(tmp_path))
+    for stmt in _Q7:
+        eng.execute(stmt)
+    for _ in range(3):
+        eng.tick(barriers=1, chunks_per_barrier=2)
+    trees = _trees("tick-")
+    assert len(trees) == 3
+    for spans in trees.values():
+        chk = tree_check(spans)
+        assert chk["complete"] and chk["root_covers"], chk
+        assert _TICK_SPANS <= set(chk["names"]), \
+            _TICK_SPANS - set(chk["names"])
+        by_id = {s["span_id"]: s for s in spans}
+        by_name = {s["name"]: s for s in spans}
+        # one root, and one span of each name: a barrier, not a chunk
+        assert len(by_name) == len(spans)
+        up = by_name["ckpt_commit.encode"]
+        assert up["thread"].startswith("ckpt-upload-")
+        assert by_id[up["parent_id"]]["name"] == "ckpt_commit"
+        assert by_id[by_name["ckpt_prepare"]["parent_id"]]["name"] \
+            == "_commit_checkpoint"
+        assert by_id[by_name["_maintain.device_wait"]["parent_id"]][
+            "name"] == "_maintain"
+        assert all(s["t_mono"] > 0 for s in spans)
+        root = by_name["tick"]
+        assert root["attrs"]["rows"] == 2 * 128 and root["attrs"]["epoch"]
+    # every span is a counter too, in the engine's own registry
+    text = eng.metrics.render_prometheus()
+    counts = _span_series(text, "trace_span_total")
+    secs = _span_series(text, "trace_span_seconds_total")
+    assert counts[("tick", None)] == 3
+    for name in _TICK_SPANS - {"tick"}:
+        assert counts[(name, "q7")] == 3, name
+        assert secs[(name, "q7")] > 0, name
+    # the cadence counters: one maintenance and one snapshot a barrier
+    assert counts[("_maintain", "q7")] == counts[("snapshot", "q7")] \
+        == counts[("inject_barrier", "q7")]
+
+
+def test_spans_count_into_their_own_engines_registry():
+    """GLOBAL_TRACE is one a process, engines are many: an engine's
+    spans never land in whichever registry was configured last."""
+    from risingwave_tpu.sql.engine import Engine
+
+    a, b = Engine(_cluster_cfg()), Engine(_cluster_cfg())
+    other = MetricsRegistry()
+    GLOBAL_TRACE.configure(metrics=other)  # the fixture restores it
+    for eng in (a, b):
+        for stmt in _Q7:
+            eng.execute(stmt)
+    a.tick(barriers=1, chunks_per_barrier=1)
+    b.tick(barriers=2, chunks_per_barrier=1)
+    with GLOBAL_TRACE.span("loose", trace_id="x-1"):
+        pass
+    ca = _span_series(a.metrics.render_prometheus(), "trace_span_total")
+    cb = _span_series(b.metrics.render_prometheus(), "trace_span_total")
+    assert ca[("inject_barrier", "q7")] == 1 and ca[("tick", None)] == 1
+    assert cb[("inject_barrier", "q7")] == 2 and cb[("tick", None)] == 1
+    # a span with no owner falls to the configured default, alone
+    assert _span_series(other.render_prometheus(),
+                        "trace_span_total") == {("loose", None): 1}
+
+
+def test_single_node_read_tree_counters_and_drop(tmp_path):
+    """One pgwire statement is one ``read-<n>`` tree; the served
+    node's tick holds the wait for the engine lock and no second
+    ``tick`` span; /metrics counts both and DROP retires the job's."""
+    from risingwave_tpu.pgwire import SimpleClient
+    from risingwave_tpu.server import SingleNode
+
+    node = SingleNode(_cluster_cfg(), data_dir=str(tmp_path))
+    server = node.start(port=0, ticker=False)
+    try:
+        c = SimpleClient("127.0.0.1", server.server_address[1])
+        for stmt in _Q7:
+            c.query(stmt)
+        for _ in range(2):
+            node._tick_once()
+        GLOBAL_TRACE.clear()
+        _, rows = c.query("SELECT window_start, max_price, bids FROM q7")
+        (spans,) = _trees("read-").values()
+        chk = tree_check(spans)
+        assert chk["complete"] and chk["root_covers"], chk
+        assert set(chk["names"]) == {
+            "read", "read.lock_wait", "read.execute", "read.send",
+            "_mv_rows", "_mv_rows.to_host"}
+        by_id = {s["span_id"]: s for s in spans}
+        by_name = {s["name"]: s for s in spans}
+        assert by_name["read"]["attrs"] == {"kind": "SELECT",
+                                            "rows": len(rows)}
+        assert by_id[by_name["_mv_rows"]["parent_id"]]["name"] \
+            == "read.execute"
+        assert by_name["read.send"]["parent_id"] \
+            == by_name["read"]["span_id"]
+
+        node._tick_once()
+        (tick,) = _trees("tick-").values()
+        names = [s["name"] for s in tick]
+        assert names.count("tick") == 1 and "tick.lock_wait" in names
+        assert tree_check(tick)["complete"]
+
+        text = node.render_metrics()
+        counts = _span_series(text, "trace_span_total")
+        assert counts[("read", None)] == 3  # two DDL, one SELECT
+        assert counts[("read.execute", None)] == 3
+        assert counts[("_mv_rows", None)] == 1
+        assert counts[("tick", None)] == counts[("tick.lock_wait", None)] \
+            == counts[("inject_barrier", "q7")] == 3
+        (scrape,) = _trees("scrape-").values()
+        assert {s["name"] for s in scrape} == {
+            "render_metrics", "render_metrics.lock_wait",
+            "render_metrics.collect"}
+
+        c.query("DROP MATERIALIZED VIEW q7")
+        text = node.render_metrics()
+        assert 'job="q7"' not in text
+        assert 'trace_span_total{span="read"} 4' in text
+        c.close()
+    finally:
+        node.stop()
+        server.shutdown()
+        server.server_close()
+
+
+def test_sample_n_zero_records_nothing_and_exports_no_series():
+    """The overhead contract on the single-node path: off means the
+    null singleton at every call site, an empty ring, no series."""
+    from risingwave_tpu.sql.engine import Engine
+
+    GLOBAL_TRACE.configure(sample_n=0)
+    lock = threading.Lock()
+    assert GLOBAL_TRACE.root("tick", "tick") is NULL_SPAN
+    with GLOBAL_TRACE.held(lock, "tick.lock_wait"):
+        assert lock.locked()
+    assert not lock.locked()
+    eng = Engine(_cluster_cfg())
+    for stmt in _Q7:
+        eng.execute(stmt)
+    eng.tick(barriers=2, chunks_per_barrier=1)
+    assert eng.query("SELECT * FROM q7")[0]
+    assert GLOBAL_TRACE.dump() == []
+    assert "trace_span" not in eng.metrics.render_prometheus()
+
+
+def test_annotation_factory_wraps_every_span():
+    """``configure(annotate=...)`` is how the roles that hold a chip
+    put spans on the profiler's clock; the module never imports jax."""
+    seen = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("in", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("out", self.name))
+
+    rec = SpanRecorder(role="single", sample_n=1).configure(annotate=Ann)
+    with rec.root("tick", "tick"):
+        with rec.span("run_chunks"):
+            pass
+    assert seen == [("in", "tick"), ("in", "run_chunks"),
+                    ("out", "run_chunks"), ("out", "tick")]
+    assert [s["name"] for s in rec.dump()] == ["run_chunks", "tick"]
+
+
+def test_trace_module_and_server_import_no_jax():
+    """``--role serving`` is engine-free: the recorder, its counters
+    and the server's entry module load and record without jax."""
+    code = (
+        "import sys\n"
+        "from risingwave_tpu import server\n"
+        "from risingwave_tpu.common.metrics import MetricsRegistry\n"
+        "from risingwave_tpu.common.trace import GLOBAL_TRACE\n"
+        "m = MetricsRegistry()\n"
+        "GLOBAL_TRACE.configure(role='serving', sample_n=1, metrics=m)\n"
+        "with GLOBAL_TRACE.root('read', 'read'):\n"
+        "    pass\n"
+        "assert m.get('trace_span_total', span='read') == 1\n"
+        "assert server._render_trace('format=chrome')\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n"
+    )
+    env = dict(os.environ, RWT_NO_JAX="1")
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+
+
+def test_trace_endpoint_beside_metrics():
+    """``GET /trace`` on the metrics port: the ring as JSON, or Chrome
+    ``trace_event`` JSON; ``/metrics`` as before."""
+    from risingwave_tpu.server import _start_metrics_http
+
+    with GLOBAL_TRACE.root("tick", "tick", rows=7):
+        with GLOBAL_TRACE.span("run_chunks"):
+            pass
+    httpd = _start_metrics_http(lambda: "up 1\n", "127.0.0.1", 0)
+    base = f"http://127.0.0.1:{httpd.server_address[1]}"
+    try:
+        def get(path):
+            with urllib.request.urlopen(base + path, timeout=30) as r:
+                return r.read().decode()
+
+        out = json.loads(get("/trace"))
+        assert out["role"] == "proc"
+        assert [s["name"] for s in out["spans"]] == ["run_chunks", "tick"]
+        assert out["spans"][1]["attrs"] == {"rows": 7}
+        tid = out["spans"][0]["trace_id"]
+        assert len(json.loads(get(f"/trace?trace_id={tid}"))["spans"]) == 2
+        assert json.loads(get("/trace?trace_id=none"))["spans"] == []
+        chrome = json.loads(get("/trace?format=chrome"))
+        assert {e["name"] for e in chrome["traceEvents"]
+                if e["ph"] == "X"} == {"tick", "run_chunks"}
+        assert get("/metrics") == "up 1\n"
+    finally:
+        httpd.shutdown()
+        httpd.server_close()
+
+
+def test_window_program_names_generator_and_every_executor():
+    """``jax.named_scope`` on the device side: the q7-shaped window
+    program's lowered text holds ``gen`` and ``<Class>.<i>/apply`` for
+    every executor; the barrier and maintain programs their phases."""
+    import jax.numpy as jnp
+
+    from risingwave_tpu.sql.engine import Engine
+
+    eng = Engine(_cluster_cfg())
+    for stmt in _Q7:
+        eng.execute(stmt)
+    job = eng.jobs[0]
+
+    def locs(lowered) -> set[str]:
+        return set(re.findall(r'loc\("([^"]*)"',
+                              lowered.as_text(debug_info=True)))
+
+    window = locs(job._multi_prog(4).lower(job.states, jnp.int64(0)))
+    assert any(name.startswith("gen/") for name in window)
+    barrier = locs(job.fragment._barrier.lower(job.states, 5))
+    execs = job.fragment.executors
+    names = [type(ex).__name__.removesuffix("Executor") for ex in execs]
+    assert names == ["WatermarkFilter", "HopWindow", "HashAgg", "Project",
+                     "Materialize"]
+
+    def applied(prog: set[str]) -> set[int]:
+        return {i for i, n in enumerate(names)
+                if any(f"{n}.{i}/apply" in loc for loc in prog)}
+
+    # the window program runs the chain up to the aggregate (it emits
+    # on flush); the barrier program runs the aggregate's changelog
+    # through the rest: between them, every executor that leaves an
+    # operation to name (the projection only picks columns)
+    assert applied(window) == {0, 1, 2}
+    assert applied(barrier) - {3} == {4}
+    barrier = " ".join(barrier)
+    for phase in ("flush", "watermark", "counters"):
+        assert f"HashAgg.2/{phase}" in barrier
+    maintain = " ".join(locs(job.fragment._maintain.lower(job.states)))
+    assert "HashAgg.2/rehash" in maintain
+
+
+def test_dropped_span_leaves_no_record():
+    """An idle poll is not worth a ring entry (the compactor polls a
+    hundred times a second): ``drop()`` unwinds, records nothing."""
+    m = MetricsRegistry()
+    rec = SpanRecorder(role="single", sample_n=1).configure(metrics=m)
+    with rec.sampled_span("compact_cycle") as idle:
+        idle.drop()
+    with rec.sampled_span("compact_cycle"):
+        pass
+    NULL_SPAN.drop()
+    assert rec.current() is None
+    assert len(rec.dump()) == 1
+    assert m.get("trace_span_total", span="compact_cycle") == 1
