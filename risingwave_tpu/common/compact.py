@@ -1,17 +1,20 @@
 """Mask compaction primitives, backend-adaptive.
 
-The same logical op has opposite cost profiles per backend (all
-measured, see ARCHITECTURE.md perf notes):
+The same logical op has opposite cost profiles per backend:
 
-- ``jnp.nonzero(mask, size=k)`` lowers to a cumsum + full-size
-  scatter: ~18.6ms on TPU for a 2^18 mask (the single hottest op in
-  barrier flush) but only ~2.7ms on CPU.
-- ``lax.top_k`` is a tuned TPU primitive (~0.02ms for the same shape)
-  but on CPU costs ~34ms (it lowers to a full variadic sort per call).
+- ``jnp.nonzero(mask, size=k)`` lowers to a cumsum + a scatter as wide
+  as the mask.  The chip pays for a scatter by the index (a fixed time
+  for each, live or dropped: 0.54 ms for 8,192 into a 2^20-slot array,
+  PERF.md §5), so this is the shape to keep off it; the CPU does it
+  cheaply.
+- ``lax.top_k`` is a sort-class primitive, and the sorts of a chunk
+  cost the chip less than a tenth of one such scatter (PERF.md §5); on
+  the CPU it lowers to a full variadic sort per call and is the slow
+  one.
 
 Round 2 switched everything to top_k and silently made the CPU path
-~6x slower (the round-2 q7 "4x regression"); the strategy is now
-selected once per process from ``jax.default_backend()`` — a
+several times slower (the round-2 q7 "4x regression"); the strategy is
+now selected once per process from ``jax.default_backend()`` — a
 trace-time Python branch, so each backend compiles only its fast op.
 """
 
@@ -35,7 +38,7 @@ def mask_indices(mask: jnp.ndarray, k: int, fill) -> jnp.ndarray:
     for the rest.
 
     TPU: ``lax.top_k`` (tie-break = ascending index, a drop-in for
-    nonzero's order).  CPU: ``jnp.nonzero`` (top_k is ~13x slower
+    nonzero's order).  CPU: ``jnp.nonzero`` (top_k is the slow one
     there)."""
     if accel_tuned():
         vals, idx = jax.lax.top_k(mask.astype(jnp.int32), k)

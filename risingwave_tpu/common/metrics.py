@@ -75,6 +75,14 @@ class MetricsRegistry:
         with self._lock:
             self._counters[key].value += amount
 
+    def set_counter(self, name: str, value: float, **labels) -> None:
+        """Mirror a running total that is kept elsewhere (on the
+        device) and read whole, where ``inc`` would need the last
+        reading."""
+        key = (name, tuple(sorted(labels.items())))
+        with self._lock:
+            self._counters[key].value = value
+
     def set_gauge(self, name: str, value: float, **labels) -> None:
         key = (name, tuple(sorted(labels.items())))
         with self._lock:

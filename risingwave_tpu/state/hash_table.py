@@ -312,9 +312,9 @@ class HashTable:
             jnp.int32(0),
         )
         # the first probe round is unrolled into the enclosing program:
-        # at sane load factors most rows resolve immediately, and a
-        # while_loop iteration carries fixed launch overhead (~0.5ms on
-        # the dev chip) that the common case should not pay
+        # at sane load factors most rows resolve immediately, and the
+        # common case should not pay a loop iteration's fixed overhead
+        # (a few µs on the v5e: PERF.md §6, PR 27)
         carry = body(init)
         occupied, key_store, slots, done, inserted, _, _ = jax.lax.while_loop(
             cond, body, carry

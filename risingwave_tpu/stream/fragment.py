@@ -32,6 +32,10 @@ WM_SAFE_FLOOR = -(1 << 62)
 
 #: per-executor-state scalar counters surfaced to maintenance checks
 COUNTER_ATTRS = ("inconsistency", "overflow", "emit_overflow")
+#: running tallies that ride the same vector (``AggState``): how often a
+#: mechanism engaged, not rows lost — maintenance exports them as
+#: ``hash_agg_<attr>_total`` and neither sums nor raises on them
+TALLY_ATTRS = ("apply_chunks", "rep_rows", "rep_tiles")
 
 
 def executor_scope(i: int, ex, phase: str):
@@ -54,7 +58,7 @@ def collect_counters(executors, states):
     for i, ex in enumerate(executors):
         st = states[i]
         with executor_scope(i, ex, "counters"):
-            for attr in COUNTER_ATTRS:
+            for attr in COUNTER_ATTRS + TALLY_ATTRS:
                 if hasattr(st, attr):
                     labels.append(f"{ex}.{attr}")
                     vals.append(getattr(st, attr).astype(jnp.int64))

@@ -72,6 +72,17 @@ from risingwave_tpu.state.hash_table import HashTable, gather_key, keys_equal
 from risingwave_tpu.stream.executor import Executor
 
 
+#: representatives the chip branch of ``HashAggExecutor.apply`` hands to
+#: the table at a time.  Every index of a scatter or gather against a
+#: table-sized array costs the v5e a fixed time, live or dropped, so a
+#: chunk of one group wants the tile narrow; a tile's own fixed cost is
+#: a few µs, so a chunk of thousands loses little to it.  ``apply``
+#: alone, ms a chunk at 64 / 128 / 256 / 512: one group in 8,192 rows
+#: 0.85 / 0.90 / 1.04 / 1.18; 8,192 groups in 40,960 rows 18.0 / 17.8 /
+#: 15.6 / 15.2 (PERF.md §6, PR 27).
+REP_TILE = 128
+
+
 class AggState(NamedTuple):
     table: HashTable
     #: flattened per-primitive state arrays, each [size]
@@ -106,6 +117,13 @@ class AggState(NamedTuple):
     spill_rows: tuple = ()
     spill_ops: jnp.ndarray = ()
     spill_count: jnp.ndarray = ()
+    #: tallies of ``apply`` (int64 scalars; ``fragment.TALLY_ATTRS``
+    #: carries them to ``/metrics`` with the barrier's counters vector):
+    #: chunks applied, and on the chip branch the group representatives
+    #: that probed the table and the ``REP_TILE``-wide tiles they took
+    apply_chunks: jnp.ndarray = ()
+    rep_rows: jnp.ndarray = ()
+    rep_tiles: jnp.ndarray = ()
 
 
 def _empty_input_col(f: Field, n: int):
@@ -362,24 +380,28 @@ class HashAggExecutor(Executor):
             if self.spill_ring else (),
             spill_count=jnp.zeros((), jnp.int32)
             if self.spill_ring else (),
+            apply_chunks=jnp.zeros((), jnp.int64),
+            rep_rows=jnp.zeros((), jnp.int64),
+            rep_tiles=jnp.zeros((), jnp.int64),
         )
 
     # ------------------------------------------------------------------
     def apply(self, state: AggState, chunk: Chunk):
         """Apply one chunk of updates; backend-adaptive strategy.
 
-        TPU: chunk-local pre-aggregation, then one sparse scatter per
-        prim.  TPU scatters serialize over LIVE updates (~0.25µs/row),
-        so a full-chunk scatter costs milliseconds while sort +
-        segmented scan cost ~20µs.  The chunk is sorted by key hash,
-        adjacent equal keys form segments, each primitive contribution
-        is segment-reduced, and only each segment's END row (its
-        "representative") probes the table and scatters — O(distinct
-        keys) serialized work instead of O(chunk).
+        TPU: chunk-local pre-aggregation.  A scatter or gather against a
+        table-sized array costs the chip a fixed time for every index it
+        is handed, dropped or not (PERF.md §5), while a sort or a
+        segmented scan of the whole chunk costs less than a tenth of one
+        such scatter.  So the chunk is sorted by key hash, adjacent
+        equal keys form segments, each primitive contribution is
+        segment-reduced, and only each segment's END row (its
+        "representative") probes the table and scatters — compacted to
+        the front and taken ``REP_TILE`` at a time, so the table-sized
+        arrays see O(distinct keys) indices instead of O(chunk).
 
-        CPU: scatters are cheap (~0.3ms for a full chunk into 2^18)
-        while each 8k-row sort costs ~1.6ms, so the chunk probes and
-        scatters per-row with no sort at all (the round-1 shape; the
+        CPU: scatters are cheap and sorts are not, so the chunk probes
+        and scatters per-row with no sort at all (the round-1 shape; the
         round-2 always-sort version was the "4x q7 regression")."""
         signs = chunk.signs()
         valid = chunk.valid
@@ -395,21 +417,23 @@ class HashAggExecutor(Executor):
         if preagg:
             # invalid rows sort to the very end under the all-ones
             # sentinel (hash64_columns never returns ~0, so no valid
-            # row lands there)
+            # row lands there, and the sorted key says which rows are)
             sort_key = jnp.where(valid, h, ~jnp.uint64(0))
             s_h, perm = jax.lax.sort_key_val(
                 sort_key, jnp.arange(cap, dtype=jnp.int32)
             )
-            s_valid = valid[perm]
+            s_valid = s_h != ~jnp.uint64(0)
             s_signs = signs[perm]
             s_keys = [gather_key(c, perm) for c in key_cols]
             # segment boundary: hash differs OR any key column differs
             # (hash collisions between distinct keys stay distinct)
             neq = s_h[1:] != s_h[:-1]
             for c in s_keys:
+                # every leaf of a key column has the rows first: slices,
+                # where a gather by ``arange`` stays a chunk-wide gather
                 neq = neq | ~keys_equal(
-                    gather_key(c, jnp.arange(1, cap)),
-                    gather_key(c, jnp.arange(0, cap - 1)))
+                    jax.tree.map(lambda x: x[1:], c),
+                    jax.tree.map(lambda x: x[:-1], c))
             starts = segment_starts(neq)
             ends = jnp.concatenate([neq, jnp.ones((1,), jnp.bool_)])
             rep = ends & s_valid
@@ -418,24 +442,74 @@ class HashAggExecutor(Executor):
             # segments of equal s_h must not merge in the min/max
             # secondary sort)
             seg_id = jnp.cumsum(starts.astype(jnp.int32))
-            seg_rows = segmented_sum(s_valid.astype(jnp.int64), start_pos)
 
-            table, slots, inserted, overflow = state.table.lookup_or_insert(
-                s_keys, rep, hashes=s_h
-            )
-            # overflowed representatives drop their whole segment —
-            # count rows (or divert them to the spill ring)
-            n_over = jnp.sum(jnp.where(rep & overflow, seg_rows, 0))
-            if self.spill_ring:
-                seg_over = jnp.zeros((cap + 1,), jnp.bool_).at[
-                    jnp.where(rep, seg_id, 0)
-                ].set(rep & overflow, mode="drop")
-                sorted_spill = s_valid & seg_over[seg_id]
-                spill_mask = jnp.zeros((cap,), jnp.bool_).at[perm].set(
-                    sorted_spill
+            # representatives' sorted positions compacted to the front
+            # (ascending), padded to whole tiles; a tile is REP_TILE of
+            # them, and how many tiles run follows the chunk
+            K = min(REP_TILE, cap)
+            n_rep = jnp.sum(rep.astype(jnp.int32))
+            n_tiles = (n_rep + (K - 1)) // K
+            row = jnp.arange(cap, dtype=jnp.int32)
+            rep_pos = jnp.concatenate([
+                jax.lax.sort(jnp.where(rep, row, cap)),
+                jnp.full((-cap % K,), cap, jnp.int32),
+            ])
+            padded = rep_pos.shape[0]
+
+            def tile(t):
+                """(sorted positions, liveness) of tile ``t``'s reps."""
+                pos = jax.lax.dynamic_slice(rep_pos, (t * K,), (K,))
+                return jnp.minimum(pos, cap - 1), pos < cap
+
+            def probe_tile(t, carry):
+                table, rep_slot, rep_ins, rep_over, n_over = carry
+                pos, live = tile(t)
+                table, slots, ins, over = table.lookup_or_insert(
+                    [gather_key(c, pos) for c in s_keys], live,
+                    hashes=s_h[pos],
                 )
+                # an overflowed representative drops its whole segment
+                # (all of it valid, and ending at ``pos``)
+                n_over = n_over + jnp.sum(jnp.where(
+                    over, pos - start_pos[pos] + 1, 0
+                ).astype(jnp.int64))
+                at = (t * K,)
+                return (
+                    table,
+                    jax.lax.dynamic_update_slice(rep_slot, slots, at),
+                    jax.lax.dynamic_update_slice(rep_ins, ins, at),
+                    jax.lax.dynamic_update_slice(rep_over, over, at),
+                    n_over,
+                )
+
+            # tiles run in order, so a later tile finds the keys an
+            # earlier one inserted, as the losers of a claim race find
+            # the winner's within one wide probe
+            table, rep_slot, rep_ins, rep_over, n_over = jax.lax.fori_loop(
+                0, n_tiles, probe_tile, (
+                    state.table,
+                    jnp.full((padded,), self.table_size, jnp.int32),
+                    jnp.zeros((padded,), jnp.bool_),
+                    jnp.zeros((padded,), jnp.bool_),
+                    jnp.zeros((), jnp.int64),
+                ),
+            )
+            if self.spill_ring or self._minput_aggs:
+                # the paths that need a slot for every ROW: a row's
+                # representative is the first at or after it, so as many
+                # lie before the row as before its representative
+                row_rep = jnp.cumsum(rep.astype(jnp.int32)) \
+                    - rep.astype(jnp.int32)
+            if self.spill_ring:
+                # back to the chunk's order: sorting by ``perm`` is the
+                # inverse permutation without a chunk-wide scatter
+                _, spilled = jax.lax.sort_key_val(
+                    perm, (s_valid & rep_over[row_rep]).astype(jnp.int32)
+                )
+                spill_mask = spilled > 0
         else:
             perm = None
+            n_rep = n_tiles = 0
             s_signs = signs
             table, slots, inserted, overflow = state.table.lookup_or_insert(
                 key_cols, valid, hashes=h
@@ -480,11 +554,9 @@ class HashAggExecutor(Executor):
                 jnp.any(spill_mask), capture, skip,
                 (spill_rows, spill_ops, spill_count),
             )
-        # freshly claimed slots may be reclaimed after state cleaning —
-        # reset their (stale) primitive state before applying updates
-        ins_pos = jnp.where(inserted, slots, jnp.int32(self.table_size))
-
-        prims = list(state.prims)
+        #: prim index -> the update each row (CPU) or each segment's END
+        #: row (chip) brings to its group
+        segs: dict[int, jnp.ndarray] = {}
         arg_cache: dict[int, jnp.ndarray] = {}
         filt_cache: dict[int, jnp.ndarray] = {}
 
@@ -553,11 +625,11 @@ class HashAggExecutor(Executor):
                 n_bad_d = n_bad_d + jnp.sum(
                     (eligible & (n1 < 0)).astype(jnp.int64)
                 )
-                rep = eligible & (
+                first = eligible & (
                     _rank_by(dslots.astype(jnp.uint64), eligible) == 0
                 )
                 d_signs[agg_idx] = jnp.where(
-                    rep,
+                    first,
                     (n1 > 0).astype(jnp.int64)
                     - (n0 > 0).astype(jnp.int64),
                     0,
@@ -570,7 +642,7 @@ class HashAggExecutor(Executor):
                 # not accumulate dead keys (ref distinct.rs deletes
                 # count-0 dedup rows)
                 died = jnp.zeros((size_d,), jnp.bool_).at[
-                    jnp.where(rep & (n1 <= 0) & (n0 > 0), safe_d,
+                    jnp.where(first & (n1 <= 0) & (n0 > 0), safe_d,
                               jnp.int32(size_d))
                 ].set(True, mode="drop")
                 d_tables[di] = d_tables[di].clear_where(died)
@@ -584,10 +656,6 @@ class HashAggExecutor(Executor):
                 if agg_idx not in arg_cache:
                     arg_cache[agg_idx] = a.arg.eval(chunk)
                 col = arg_cache[agg_idx]
-            st_dt = prims[pi].dtype
-            prims[pi] = prims[pi].at[ins_pos].set(
-                ps.init(st_dt), mode="drop"
-            )
             # NULL arguments contribute nothing (SQL: aggregates skip
             # NULLs): zero the sign, which every lift mode maps to its
             # identity element.  The payload is zeroed too — a NULL
@@ -628,27 +696,40 @@ class HashAggExecutor(Executor):
                     seg = segmented_minmax_at_ends(
                         seg_id, contrib, start_pos, ps.mode
                     )
-            # non-representative rows carry sentinel slots (dropped)
-            if ps.mode == "add":
-                prims[pi] = prims[pi].at[slots].add(seg, mode="drop")
-            elif ps.mode == "min":
-                prims[pi] = prims[pi].at[slots].min(seg, mode="drop")
-            else:
-                prims[pi] = prims[pi].at[slots].max(seg, mode="drop")
+            segs[pi] = seg
         if perm is None:
-            seg_signs = signs.astype(jnp.int64)
+            prims, row_count, dirty, minput_occ = self._scatter_groups(
+                state.prims, state.row_count, state.dirty,
+                state.minput_occ, slots, inserted, segs,
+                signs.astype(jnp.int64),
+            )
         else:
             seg_signs = segmented_sum(s_signs.astype(jnp.int64), start_pos)
-        row_count = state.row_count.at[ins_pos].set(0, mode="drop")
-        row_count = row_count.at[slots].add(seg_signs, mode="drop")
-        dirty = state.dirty.at[slots].set(True, mode="drop")
+
+            def scatter_tile(t, carry):
+                pos, _ = tile(t)
+                at = (t * K,)
+                return self._scatter_groups(
+                    *carry,
+                    jax.lax.dynamic_slice(rep_slot, at, (K,)),
+                    jax.lax.dynamic_slice(rep_ins, at, (K,)),
+                    {pi: seg[pos] for pi, seg in segs.items()},
+                    seg_signs[pos],
+                )
+
+            prims, row_count, dirty, minput_occ = jax.lax.fori_loop(
+                0, n_tiles, scatter_tile, (
+                    state.prims, state.row_count, state.dirty,
+                    state.minput_occ,
+                ),
+            )
 
         # materialized-input updates (retractable min/max): every row
         # lands in its group's value bucket — per-row slots come from
-        # the per-row probe (CPU) or from scattering each segment
-        # representative's slot over its segment id (TPU)
+        # the per-row probe (CPU) or from each row's representative
+        # (TPU)
         minput_vals = list(state.minput_vals)
-        minput_occ = list(state.minput_occ)
+        minput_occ = list(minput_occ)
         n_over_mi = jnp.zeros((), jnp.int64)
         n_miss_mi = jnp.zeros((), jnp.int64)
         if self._minput_aggs:
@@ -656,16 +737,13 @@ class HashAggExecutor(Executor):
                 row_slots = slots
                 row_ok = valid & (row_slots < self.table_size)
             else:
-                # seg ids start at 1, so index 0 is a safe dump for
-                # non-rep rows; segments whose representative
-                # overflowed keep the `size` sentinel and their rows
-                # are skipped (already counted in n_over)
-                seg_slot = jnp.full((cap + 1,), self.table_size, jnp.int32)
-                seg_slot = seg_slot.at[jnp.where(rep, seg_id, 0)].set(
-                    jnp.where(rep, slots, self.table_size), mode="drop"
+                # segments whose representative overflowed keep the
+                # `size` sentinel and their rows are skipped (already
+                # counted in n_over)
+                row_slots = jnp.where(
+                    s_valid, rep_slot[row_rep], self.table_size
                 )
-                row_slots = seg_slot[seg_id]
-                row_ok = s_valid & (row_slots < self.table_size)
+                row_ok = row_slots < self.table_size
             for mi, agg_idx in enumerate(self._minput_aggs):
                 a = self.aggs[agg_idx]
                 if agg_idx not in arg_cache:
@@ -682,7 +760,7 @@ class HashAggExecutor(Executor):
                     active = active & (fm if perm is None else fm[perm])
                 vals, occ, over, miss = self._minput_update(
                     minput_vals[mi], minput_occ[mi], row_slots,
-                    v_sorted, s_signs, active, ins_pos,
+                    v_sorted, s_signs, active,
                 )
                 minput_vals[mi] = vals
                 minput_occ[mi] = occ
@@ -695,7 +773,7 @@ class HashAggExecutor(Executor):
             n_bad = jnp.sum((valid & (signs < 0)).astype(jnp.int64))
         return AggState(
             table=table,
-            prims=tuple(prims),
+            prims=prims,
             row_count=row_count,
             dirty=dirty,
             prev_prims=state.prev_prims,
@@ -712,7 +790,39 @@ class HashAggExecutor(Executor):
             spill_rows=spill_rows,
             spill_ops=spill_ops,
             spill_count=spill_count,
+            apply_chunks=state.apply_chunks + 1,
+            rep_rows=state.rep_rows + n_rep,
+            rep_tiles=state.rep_tiles + n_tiles,
         ), None
+
+    def _scatter_groups(self, prims, row_count, dirty, minput_occ, slots,
+                        inserted, segs, seg_signs):
+        """Fold one update per entry of ``slots`` into the per-slot
+        state arrays (``size`` = dropped), at whatever width ``slots``
+        has: the chunk's on the CPU, one tile's on the chip."""
+        # freshly claimed slots may be reclaimed after state cleaning —
+        # reset their (stale) state before applying updates
+        ins_pos = jnp.where(inserted, slots, jnp.int32(self.table_size))
+        prims = list(prims)
+        for pi, seg in segs.items():
+            ps = self._prim_specs[pi][1]
+            p = prims[pi]
+            p = p.at[ins_pos].set(ps.init(p.dtype), mode="drop")
+            if ps.mode == "add":
+                p = p.at[slots].add(seg, mode="drop")
+            elif ps.mode == "min":
+                p = p.at[slots].min(seg, mode="drop")
+            else:
+                p = p.at[slots].max(seg, mode="drop")
+            prims[pi] = p
+        row_count = row_count.at[ins_pos].set(0, mode="drop")
+        row_count = row_count.at[slots].add(seg_signs, mode="drop")
+        dirty = dirty.at[slots].set(True, mode="drop")
+        # a reclaimed slot starts with empty materialized-input buckets
+        minput_occ = tuple(
+            occ.at[ins_pos].set(False, mode="drop") for occ in minput_occ
+        )
+        return tuple(prims), row_count, dirty, minput_occ
 
     def reconstructible_from_rows(self) -> bool:
         """True when the agg's full state round-trips through its own
@@ -788,7 +898,7 @@ class HashAggExecutor(Executor):
         )
 
     def _minput_update(self, vals, occ, row_slots, v_sorted, s_signs,
-                       active, ins_pos):
+                       active):
         """Apply one chunk's (sorted) rows to a value bucket multi-map.
 
         Same rank-claim/rank-clear mechanics as the join's bucketed
@@ -801,8 +911,6 @@ class HashAggExecutor(Executor):
 
         B = occ.shape[1]
         size = self.table_size
-        # reclaimed slots start with an empty bucket
-        occ = occ.at[ins_pos].set(False, mode="drop")
         is_ins = active & (s_signs > 0)
         is_del = active & (s_signs < 0)
         # in-chunk annihilation on (slot, value): a +v/-v pair inside

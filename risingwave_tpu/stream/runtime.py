@@ -34,6 +34,7 @@ from risingwave_tpu.common.trace import GLOBAL_TRACE
 from risingwave_tpu.stream.fragment import (
     COUNTER_ATTRS,
     Fragment,
+    TALLY_ATTRS,
     WM_NONE,
     WM_SAFE_FLOOR,
     collect_counters,
@@ -238,24 +239,33 @@ def check_counter_values(name: str, labels: list[str],
     ``values`` is the host copy of a barrier program's counters vector;
     with a registry, its per-kind sums are left behind as
     ``maintenance_counter_rows{job,kind}`` gauges (what the LAST
-    maintenance barrier read) before anything raises.
+    maintenance barrier read) before anything raises.  The tallies of
+    ``fragment.TALLY_ATTRS`` count engagement, not lost rows: they go
+    out as ``hash_agg_<kind>_total{job}`` and are otherwise skipped, as
+    ``.pending`` is.
     """
+    kinds = [label.rsplit(".", 1)[-1] for label in labels]
     if metrics is not None:
         sums: dict[str, int] = {}
-        for label, v in zip(labels, values):
-            kind = label.rsplit(".", 1)[-1]
-            if kind != "pending":
+        tallies: dict[str, int] = {}
+        for kind, v in zip(kinds, values):
+            if kind in TALLY_ATTRS:
+                tallies[kind] = tallies.get(kind, 0) + int(v)
+            elif kind != "pending":
                 sums[kind] = sums.get(kind, 0) + int(v)
         for kind, v in sums.items():
             metrics.set_gauge("maintenance_counter_rows", v,
                               job=name, kind=kind)
+        for kind, v in tallies.items():
+            metrics.set_counter(f"hash_agg_{kind}_total", v, job=name)
     residual = []
-    for label, v in zip(labels, values):
-        if label.endswith(".pending"):
+    for label, kind, v in zip(labels, kinds, values):
+        if kind in TALLY_ATTRS:
+            continue
+        if kind == "pending":
             if v > 0:
                 residual.append(label)
         elif v > 0:
-            kind = label.rsplit(".", 1)[-1]
             if kind == "inconsistency":
                 raise RuntimeError(
                     f"{name}/{label}: {v} inconsistent changelog rows "

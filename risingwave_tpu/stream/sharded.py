@@ -303,13 +303,16 @@ class ShardedStreamingJob:
     def _gather_counters(self, states):
         """All shard-summed error counters + residual pending as ONE
         device vector (read back once per maintenance interval)."""
-        from risingwave_tpu.stream.fragment import COUNTER_ATTRS
+        from risingwave_tpu.stream.fragment import (
+            COUNTER_ATTRS,
+            TALLY_ATTRS,
+        )
 
         labels: list[str] = []
         vals: list[jnp.ndarray] = []
         for i, ex in enumerate(self.sharded.executors):
             st = states[i]
-            for counter in COUNTER_ATTRS:
+            for counter in COUNTER_ATTRS + TALLY_ATTRS:
                 if hasattr(st, counter):
                     labels.append(f"{ex}.{counter}")
                     vals.append(

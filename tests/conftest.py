@@ -33,17 +33,27 @@ import pytest  # noqa: E402
 jax.config.update("jax_enable_x64", True)
 
 
-@pytest.fixture(params=[False, True], ids=["cpu_branch", "chip_branch"])
-def accel_tuned(request, monkeypatch):
-    """Both trace-time branches behind ``compact.accel_tuned()``: the
-    CPU's (``jnp.nonzero``, per-row probes) and the chip's
-    (``lax.top_k``, sort-based pre-aggregation), same expected rows.
-    ``hash_agg`` imports the name, so it is patched there too."""
+@pytest.fixture
+def accel_branch(monkeypatch):
+    """A setter for the trace-time branch behind
+    ``compact.accel_tuned()``: ``accel_branch(True)`` is the chip's
+    (``lax.top_k``, sort-based pre-aggregation), ``False`` the CPU's
+    (``jnp.nonzero``, per-row probes).  ``hash_agg`` imports the name,
+    so it is patched there too."""
     from risingwave_tpu.common import compact
     from risingwave_tpu.stream import hash_agg
 
-    monkeypatch.setattr(compact, "accel_tuned", lambda: request.param)
-    monkeypatch.setattr(hash_agg, "accel_tuned", lambda: request.param)
+    def choose(chip: bool) -> None:
+        monkeypatch.setattr(compact, "accel_tuned", lambda: chip)
+        monkeypatch.setattr(hash_agg, "accel_tuned", lambda: chip)
+
+    return choose
+
+
+@pytest.fixture(params=[False, True], ids=["cpu_branch", "chip_branch"])
+def accel_tuned(request, accel_branch):
+    """Both branches of ``accel_branch``, same expected rows."""
+    accel_branch(request.param)
     return request.param
 
 

@@ -352,3 +352,198 @@ def test_cumsum_int64_from_limbs_matches_numpy(n):
     y = rng.integers(0, 2**64 - 1, n, dtype=np.uint64)
     np.testing.assert_array_equal(
         np.asarray(_cumsum_int64(jnp.asarray(y))), np.cumsum(y))
+
+
+# -- the chip branch of HashAggExecutor.apply: representatives in tiles --
+
+_GV = Schema.of(("g", DataType.INT64), ("v", DataType.INT64))
+
+
+def _apply_on(accel_branch, chip, make_agg, chunks):
+    """The state after ``chunks`` through the CPU branch (per-row
+    probes) or the chip branch (sort, then representatives a tile at a
+    time) of a fresh aggregate."""
+    accel_branch(chip)
+    frag = Fragment([make_agg()])
+    states = frag.init_states()
+    for c in chunks:
+        states, _ = frag.step(states, c)
+    return states[0]
+
+
+def _groups(st):
+    """State by group key: slots may differ between the branches."""
+    occ = np.asarray(st.table.occupied)
+    keys = np.asarray(st.table.key_cols[0])[occ]
+    cols = [np.asarray(p)[occ] for p in st.prims]
+    cols += [np.asarray(st.row_count)[occ], np.asarray(st.dirty)[occ]]
+    buckets = [
+        [tuple(sorted(v[o])) for v, o in zip(np.asarray(vals)[occ],
+                                             np.asarray(mocc)[occ])]
+        for vals, mocc in zip(st.minput_vals, st.minput_occ)
+    ]
+    out = {}
+    for i, k in enumerate(keys):
+        out[int(k)] = tuple(c[i].item() for c in cols) \
+            + tuple(b[i] for b in buckets)
+    assert len(out) == len(keys)
+    return out
+
+
+def _gv_chunk(g, v, cap, ops=None, valid=None):
+    c = Chunk.from_numpy(_GV, [np.asarray(g, np.int64),
+                               np.asarray(v, np.int64)],
+                         ops=ops, capacity=cap)
+    if valid is not None:
+        full = np.zeros(cap, np.bool_)
+        full[:len(valid)] = valid
+        c = Chunk(c.columns, c.ops, jnp.asarray(full), c.schema)
+    return c
+
+
+def _four_aggs(table_size=4096, **kw):
+    return lambda: HashAggExecutor(
+        _GV, group_by=[("g", col("g"))],
+        aggs=[count_star(), AggCall("sum", col("v"), "s"),
+              AggCall("min", col("v"), "lo"), AggCall("max", col("v"), "hi")],
+        table_size=table_size, emit_capacity=8, **kw)
+
+
+def _tile_cases():
+    from risingwave_tpu.stream.hash_agg import REP_TILE as K
+    return K, 4 * K
+
+
+@pytest.mark.parametrize("case", ["one", "k_minus_1", "k", "k_plus_1",
+                                  "three_k_plus_7", "full_chunk"])
+def test_hash_agg_rep_tiles_match_cpu_branch(accel_branch, case):
+    """Same chunks, both branches, same state by group key; the chip
+    branch's tallies say how many representatives and tiles it took."""
+    K, cap = _tile_cases()
+    n = {"one": 1, "k_minus_1": K - 1, "k": K, "k_plus_1": K + 1,
+         "three_k_plus_7": 3 * K + 7, "full_chunk": cap}[case]
+    rng = np.random.default_rng(n)
+    # every one of n keys at least once, the rest of the chunk at random
+    g1 = np.concatenate([np.arange(n), rng.integers(0, n, cap - n)])
+    rng.shuffle(g1)
+    # second chunk: old and new keys, some rows invalid
+    g2 = rng.integers(n // 2, n // 2 + n, cap)
+    valid2 = rng.random(cap) < 0.8
+    chunks = [
+        _gv_chunk(g1 * 7919, rng.integers(-50, 50, cap), cap),
+        _gv_chunk(g2 * 7919, rng.integers(-50, 50, cap), cap, valid=valid2),
+    ]
+    cpu = _apply_on(accel_branch, False, _four_aggs(), chunks)
+    chip = _apply_on(accel_branch, True, _four_aggs(), chunks)
+    assert _groups(chip) == _groups(cpu)
+    assert len(_groups(chip)) == len(set(g1) | set(g2[valid2]))
+    assert int(chip.overflow) == int(cpu.overflow) == 0
+    reps = [n, len(set(g2[valid2]))]
+    assert int(chip.apply_chunks) == int(cpu.apply_chunks) == 2
+    assert int(chip.rep_rows) == sum(reps)
+    assert int(chip.rep_tiles) == sum(-(-r // K) for r in reps)
+    assert int(cpu.rep_rows) == int(cpu.rep_tiles) == 0
+
+
+def test_hash_agg_rep_tiles_all_invalid_chunk(accel_branch):
+    """No representative, zero tiles: nothing touches the table."""
+    _, cap = _tile_cases()
+    chunk = _gv_chunk(np.arange(cap), np.ones(cap), cap,
+                      valid=np.zeros(cap, np.bool_))
+    for chip in (False, True):
+        st = _apply_on(accel_branch, chip, _four_aggs(), [chunk])
+        assert _groups(st) == {}
+        assert (int(st.apply_chunks), int(st.rep_rows),
+                int(st.rep_tiles)) == (1, 0, 0)
+
+
+@pytest.mark.parametrize("interleaved", [False, True])
+def test_hash_agg_rep_tiles_equal_hash_across_tile_edge(
+        monkeypatch, accel_branch, interleaved):
+    """Two distinct keys with one 64-bit hash, the last representative
+    of one tile and the first of the next: they stay two groups, and the
+    later tile probes past the earlier one's insert.  Interleaved rows
+    split each key into several segments, so one key has representatives
+    in both tiles."""
+    from risingwave_tpu.stream import hash_agg
+
+    K, cap = _tile_cases()
+    monkeypatch.setattr(
+        hash_agg, "hash64_columns",
+        lambda cols, seed=0: (cols[0] // 2 + 1).astype(jnp.uint64))
+    # keys 1..2K: key 1 alone, then pairs (2j, 2j+1) of equal hash at
+    # sorted representative positions 2j-1 and 2j: (K, K+1) straddles
+    keys = np.arange(1, 2 * K + 1)
+    g = np.concatenate([keys, np.tile([K, K + 1], (cap - 2 * K) // 2)]
+                       if interleaved else
+                       [keys, np.repeat([K, K + 1], (cap - 2 * K) // 2)])
+    v = np.arange(cap) % 97
+    chunks = [_gv_chunk(g, v, cap), _gv_chunk(g[::-1], v, cap)]
+    cpu = _apply_on(accel_branch, False, _four_aggs(), chunks)
+    chip = _apply_on(accel_branch, True, _four_aggs(), chunks)
+    got = _groups(chip)
+    assert got == _groups(cpu) and len(got) == 2 * K
+    assert got[K][0] == got[K + 1][0] == 2 * (1 + (cap - 2 * K) // 2)
+    assert int(chip.rep_tiles) >= 4
+
+
+@pytest.mark.parametrize("spill_ring", [0, 1024])
+def test_hash_agg_rep_tiles_later_tile_overflows(accel_branch, spill_ring):
+    """A table of 2K slots and 3K+7 one-row groups: the later tiles'
+    representatives find it full.  Which groups lose differs between
+    the branches; how many, and what becomes of their rows, does not."""
+    K, cap = _tile_cases()
+    n = 3 * K + 7
+    g = np.arange(n) * 7919
+    chunk = _gv_chunk(g, np.ones(n), cap)
+    make = _four_aggs(table_size=2 * K, spill_ring=spill_ring)
+    cpu = _apply_on(accel_branch, False, make, [chunk])
+    chip = _apply_on(accel_branch, True, make, [chunk])
+    lost = n - 2 * K
+    for st in (cpu, chip):
+        kept = set(_groups(st))
+        assert len(kept) == 2 * K
+        if spill_ring:
+            assert int(st.overflow) == 0
+            assert int(st.spill_count) == lost
+            spilled = np.asarray(st.spill_rows[0])[:lost]
+            assert kept.isdisjoint(spilled.tolist())
+            assert kept | set(spilled.tolist()) == set(g.tolist())
+        else:
+            assert int(st.overflow) == lost
+    assert int(chip.rep_tiles) == 4
+
+
+def test_hash_agg_rep_tiles_retractable_minmax_and_filter(accel_branch):
+    """Materialized-input min/max (a slot for every ROW, read from the
+    row's representative) and a FILTERed sum, over 3K+7 groups with
+    deletes in the second chunk."""
+    K, cap = _tile_cases()
+    n = 3 * K + 7
+    rng = np.random.default_rng(27)
+    g = np.concatenate([np.arange(n), rng.integers(0, n, cap - n)])
+    v = rng.integers(0, 40, cap)
+    # second chunk: retract a third of the first's rows, insert others
+    gone = rng.random(cap) < 0.33
+    g2 = np.where(gone, g, rng.integers(0, n + K, cap))
+    v2 = np.where(gone, v, rng.integers(0, 40, cap))
+    ops2 = np.where(gone, 1, 0).astype(np.int8)
+
+    def make():
+        return HashAggExecutor(
+            _GV, group_by=[("g", col("g"))],
+            aggs=[AggCall("min", col("v"), "lo"),
+                  AggCall("max", col("v"), "hi"),
+                  AggCall("sum", col("v"), "s", filter=col("v") > 20),
+                  count_star()],
+            table_size=4096, emit_capacity=8, retractable_input=True,
+            minput_bucket_cap=16)
+
+    chunks = [_gv_chunk(g * 7919, v, cap),
+              _gv_chunk(g2 * 7919, v2, cap, ops=ops2)]
+    cpu = _apply_on(accel_branch, False, make, chunks)
+    chip = _apply_on(accel_branch, True, make, chunks)
+    assert _groups(chip) == _groups(cpu)
+    assert int(chip.overflow) == int(cpu.overflow) == 0
+    assert int(chip.inconsistency) == int(cpu.inconsistency) == 0
+    assert any(len(b[-1]) > 1 for b in _groups(chip).values())

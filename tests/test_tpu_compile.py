@@ -17,6 +17,7 @@ xdist only the worker that is given this file does).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -90,22 +91,60 @@ def jobs():
     return get
 
 
-def _compile(prog, one_chip, *args):
+def _compile(prog, one_chip, *args, text=False):
     """Lower for the described chip from shapes alone; return the
-    compiled program's memory analysis."""
+    compiled program's memory analysis (and its text, if asked)."""
     def sds(tree):
         return jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(
                 x.shape, x.dtype, sharding=one_chip),
             tree)
 
-    mem = prog.lower(*(sds(a) for a in args)).compile().memory_analysis()
+    compiled = prog.lower(*(sds(a) for a in args)).compile()
+    mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     print(f"args={mem.argument_size_in_bytes >> 20}MiB "
           f"temp={mem.temp_size_in_bytes >> 20}MiB total={total >> 20}MiB")
     assert total < HBM_BYTES
-    return mem
+    return (mem, compiled.as_text()) if text else mem
+
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?(%[\w.\-]+) = (\([^=]*?\)|\S+) ([\w\-]+)\((.*)$")
+
+
+def _apply_scatter_widths(hlo: str) -> dict[str, int]:
+    """Indices handed to every ``scatter`` of the compiled program whose
+    ``op_name`` lies under a ``HashAgg.<i>/apply`` scope, by
+    instruction.  A scatter's operands are N arrays, the indices, N
+    updates."""
+    shape_of, scatters = {}, []
+    for line in hlo.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, shape, op, rest = m.groups()
+        shape_of[name] = shape
+        if op == "scatter" and re.search(
+                r'op_name="[^"]*/HashAgg\.\d+/apply/', rest):
+            scatters.append((name, rest))
+    out = {}
+    for name, rest in scatters:
+        operands = re.findall(r"%[\w.\-]+", rest.split(")", 1)[0])
+        idx = shape_of[operands[(len(operands) - 1) // 2]]
+        dims = re.match(r"\w+\[([\d,]*)\]", idx).group(1)
+        out[name] = int(dims.split(",")[0]) if dims else 1
+    return out
+
+
+def _assert_narrow_apply(hlo: str, chunk_rows: int):
+    """The guard that the chunk-wide scatter does not come back: under
+    ``HashAgg.<i>/apply`` every scatter takes one tile of
+    representatives, never a chunk of rows (PERF.md §6, PR 27)."""
+    widths = _apply_scatter_widths(hlo)
+    assert len(widths) >= 5, widths  # the scope is there, and read
+    assert max(widths.values()) <= hash_agg.REP_TILE < chunk_rows, widths
 
 
 K0 = np.int64(0)
@@ -114,7 +153,8 @@ EPOCH = np.int64(1)
 
 def test_q7_step(jobs, one_chip):
     job = jobs("q7")
-    _compile(job._fused, one_chip, job.states, K0)
+    _, hlo = _compile(job._fused, one_chip, job.states, K0, text=True)
+    _assert_narrow_apply(hlo, CFG["chunk"])
 
 
 def test_q7_barrier(jobs, one_chip):
@@ -125,8 +165,9 @@ def test_q7_barrier(jobs, one_chip):
 def test_q5_fused_window(jobs, one_chip):
     """The multi-chunk window program at the smoke's chunks_per_barrier."""
     job = jobs("q5")
-    _compile(job._multi_prog(CFG["chunks_per_barrier"]), one_chip,
-             job.states, K0)
+    _, hlo = _compile(job._multi_prog(CFG["chunks_per_barrier"]), one_chip,
+                      job.states, K0, text=True)
+    _assert_narrow_apply(hlo, CFG["chunk"])
 
 
 def test_q5_barrier(jobs, one_chip):
@@ -134,7 +175,9 @@ def test_q5_barrier(jobs, one_chip):
     on 40,960-row chunks: the 64-bit reduce-window the compiler refused
     there is what ``compact._cumsum_int64`` replaced."""
     job = jobs("q5")
-    _compile(job.fragment._barrier, one_chip, job.states, EPOCH)
+    _, hlo = _compile(job.fragment._barrier, one_chip, job.states, EPOCH,
+                      text=True)
+    _assert_narrow_apply(hlo, 40960)
 
 
 def test_q8_step_person(jobs, one_chip):

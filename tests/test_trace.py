@@ -686,3 +686,56 @@ def test_dropped_span_leaves_no_record():
     assert rec.current() is None
     assert len(rec.dump()) == 1
     assert m.get("trace_span_total", span="compact_cycle") == 1
+
+
+@pytest.mark.parametrize("view, tiles_per_chunk", [
+    # one 10 s window holds every bid of a 512-row chunk: one group
+    (_Q7[1], "one"),
+    # a group a price: hundreds of representatives a chunk, so several
+    # tiles of hash_agg.REP_TILE
+    ("CREATE MATERIALIZED VIEW q7 AS SELECT price, count(*) AS bids "
+     "FROM bid GROUP BY price", "several"),
+], ids=["one_group", "many_groups"])
+def test_hash_agg_tallies_reach_metrics(tmp_path, accel_branch, view,
+                                        tiles_per_chunk):
+    """The chip branch's engagement counters ride the maintenance
+    barrier's counters vector to /metrics under their own names, and
+    are not lost rows: ``maintenance_counter_rows`` stays 0."""
+    from risingwave_tpu.common.config import RwConfig
+    from risingwave_tpu.server import SingleNode
+    from risingwave_tpu.stream import hash_agg
+
+    accel_branch(True)
+    node = SingleNode(RwConfig.from_dict({
+        "streaming": {"chunk_size": 512},
+        "state": {"agg_table_size": 8192, "agg_emit_capacity": 2048,
+                  "mv_table_size": 8192, "mv_ring_size": 1024},
+    }), data_dir=str(tmp_path))
+    try:
+        node.engine.execute(_Q7[0])
+        node.engine.execute(view)
+        node.engine.tick(barriers=2, chunks_per_barrier=3)
+        text = node.render_metrics()
+    finally:
+        node.stop()
+
+    def series(name):
+        (v,) = re.findall(rf'^{name}{{job="q7"}} (\S+)$', text, re.M)
+        return float(v)
+
+    assert "# TYPE hash_agg_rep_tiles_total counter" in text
+    chunks = series("hash_agg_apply_chunks_total")
+    tiles = series("hash_agg_rep_tiles_total")
+    reps = series("hash_agg_rep_rows_total")
+    assert chunks == 6
+    if tiles_per_chunk == "one":
+        assert tiles / chunks == 1 and 1 <= reps / chunks <= 2
+    else:
+        assert tiles / chunks > 1
+        assert reps / chunks > hash_agg.REP_TILE
+    rows = re.findall(
+        r'^maintenance_counter_rows{job="q7",kind="(\w+)"} (\S+)$',
+        text, re.M)
+    assert rows and all(float(v) == 0 for _, v in rows)
+    assert not {k for k, _ in rows} & {"apply_chunks", "rep_rows",
+                                       "rep_tiles"}
