@@ -48,6 +48,12 @@ from risingwave_tpu.common.hash import (
     hash64_partial,
 )
 
+#: movers ``HashTable.reclaimed`` reinserts at a time: one probe and one
+#: scatter a leaf of this width, like ``hash_agg.REP_TILE`` (PERF.md §6)
+RECLAIM_TILE = 128
+#: movers it lists at a time (one binary search over the table each)
+RECLAIM_BATCH = 4096
+
 #: trace-time probe accounting: how many table-probe loops a compiled
 #: program contains.  Incremented while TRACING (each jitted program
 #: traces once), so wrapping a trace of an update function between
@@ -110,6 +116,21 @@ def _keys_equal(a, b) -> jnp.ndarray:
 # (chunk pre-aggregation in hash_agg, join bucket paths)
 gather_key = _gather_key
 keys_equal = _keys_equal
+
+
+def _scan_slots(op, combine, x: jnp.ndarray, identity) -> jnp.ndarray:
+    """``op`` (``lax.cummax`` / ``lax.cumsum``, with its ``combine``)
+    along a table-sized int32 vector, as a scan within rows of 512 and
+    one over the rows' totals:
+    flat, the chip's compiler takes 37 s over 2^20 elements and 6 s over
+    2^18; so, a second (deviceless compiles, PERF.md §6, PR 30)."""
+    n = x.shape[0]
+    if n <= 512:
+        return op(x, axis=0)
+    inner = op(x.reshape(n // 512, 512), axis=1)
+    carry = jnp.concatenate([
+        jnp.full((1,), identity, x.dtype), op(inner[:, -1], axis=0)[:-1]])
+    return combine(inner, carry[:, None]).reshape(n)
 
 
 def permute_dense(arr, moved: jnp.ndarray, init=None):
@@ -368,6 +389,109 @@ class HashTable:
         live = self.occupied
         fresh, new_slots, _, _ = fresh.lookup_or_insert(self.key_cols, live)
         return fresh, new_slots
+
+    def reclaimed(self, dense=(), inits=()):
+        """Give every tombstone back, at a cost that follows what was
+        retired: the live keys whose probe chain crosses a tombstone are
+        taken out and inserted again, ``RECLAIM_TILE`` at a time, and
+        nothing else moves.
+
+        Every index of a table-wide scatter or gather costs the v5e
+        ~66 ns, live or dropped (PERF.md §6), and ``rehashed`` +
+        ``permute_dense`` hand it the whole table once a state leaf.
+        Here the table-wide work is elementwise and two scans.  A live
+        key is a *mover* if it is off its home slot and a tombstone lies
+        before it in its run (the stretch of non-empty slots it sits
+        in): a superset of the keys a freed slot can bring nearer home,
+        and closed, because a key ahead of its run's first tombstone has
+        no freed slot on its chain.  All tombstones and movers become
+        empty at once; then the movers go back in, in the order of their
+        old slots counted from an empty one (so no run is cut), which
+        keeps each reinserted key at or before the last old slot of its
+        tile: the old slots' contents are read a tile ahead of anything
+        that could overwrite them, and no staging copy is needed.
+
+        ``dense`` is a tuple of pytrees of ``[size, ...]`` per-slot
+        arrays that move with their keys; ``inits`` one fill value (what
+        a never-used slot holds) per leaf of ``dense`` in
+        ``jax.tree.leaves`` order, or empty for zeros: every slot left
+        empty is reset to it.  Returns ``(table, dense', lost)``;
+        ``lost`` counts movers whose reinsertion ran into the probe
+        bound (none, below the table's load limit).
+        """
+        size = self.size
+        K = min(RECLAIM_TILE, size)
+        idx = jnp.arange(size, dtype=jnp.int32)
+        live = self.occupied
+        tomb = self.tombstone & ~live
+        empty = ~live & ~tomb
+        home = (hash64_columns(self.key_cols) % np.uint64(size)).astype(
+            jnp.int32)
+
+        def last_at_or_before(mask):
+            """Slot of the nearest ``mask`` slot at or before each slot,
+            around the table's end (then negative); none: below all."""
+            none = -2 * size - 2
+            at = _scan_slots(jax.lax.cummax, jnp.maximum,
+                             jnp.where(mask, idx, none), none)
+            return jnp.maximum(at, at[-1] - size)
+
+        mover = live & (home != idx) & (
+            last_at_or_before(tomb) > last_at_or_before(empty))
+        # slots are counted from an empty one, or, in a table without,
+        # from a tombstone (then every displaced key is a mover)
+        origin = jnp.where(jnp.any(empty), jnp.argmax(empty),
+                           jnp.argmax(tomb)).astype(jnp.int32)
+        table = HashTable(self.key_cols, live & ~mover,
+                          jnp.zeros((size,), jnp.bool_), size)
+        leaves, treedef = jax.tree.flatten(tuple(dense))
+        fills = list(inits) or [0] * len(leaves)
+        if len(fills) != len(leaves):
+            raise ValueError("one fill value for each leaf of `dense`")
+
+        # the movers' old slots, ascending from ``origin``: the k-th is
+        # where the running count of movers first reaches k (a binary
+        # search a batch: a sort or a ``top_k`` over the table would cost
+        # the chip's compiler half a minute, PERF.md §6)
+        B = min(RECLAIM_BATCH, size)
+        count = _scan_slots(
+            jax.lax.cumsum, jnp.add,
+            jnp.roll(mover, -origin).astype(jnp.int32), 0)
+        n_movers = count[-1]
+
+        def batch(b, carry):
+            at = jnp.searchsorted(
+                count, b * B + jnp.arange(1, B + 1, dtype=jnp.int32),
+                side="left", method="scan").astype(jnp.int32)
+            old = jnp.concatenate([
+                jnp.where(at < size, (at + origin) % size, size),
+                jnp.full((-B % K,), size, jnp.int32)])
+
+            def reinsert(t, carry):
+                table, leaves, lost = carry
+                pos = jax.lax.dynamic_slice(old, (t * K,), (K,))
+                valid = pos < size
+                safe = jnp.minimum(pos, size - 1)
+                rows = [x[safe] for x in leaves]
+                table, slots, _, over = table.lookup_or_insert(
+                    [_gather_key(c, safe) for c in table.key_cols], valid)
+                to = jnp.where(valid & ~over, slots, jnp.int32(size))
+                leaves = [x.at[to].set(r, mode="drop")
+                          for x, r in zip(leaves, rows)]
+                lost = lost + jnp.sum((valid & over).astype(jnp.int64))
+                return table, leaves, lost
+
+            n = jnp.minimum(n_movers - b * B, B)
+            return jax.lax.fori_loop(0, (n + K - 1) // K, reinsert, carry)
+
+        table, leaves, lost = jax.lax.fori_loop(
+            0, (n_movers + B - 1) // B, batch,
+            (table, leaves, jnp.zeros((), jnp.int64)))
+        leaves = [
+            jnp.where(table.occupied.reshape((size,) + (1,) * (x.ndim - 1)),
+                      x, jnp.asarray(f, x.dtype))
+            for x, f in zip(leaves, fills)]
+        return table, jax.tree.unflatten(treedef, leaves), lost
 
     def gather_keys(self, slots: jnp.ndarray) -> tuple:
         """Key column values at ``slots`` (drop-sentinel aware gathers)."""

@@ -35,7 +35,11 @@ COUNTER_ATTRS = ("inconsistency", "overflow", "emit_overflow")
 #: running tallies that ride the same vector (``AggState``): how often a
 #: mechanism engaged, not rows lost — maintenance exports them as
 #: ``hash_agg_<attr>_total`` and neither sums nor raises on them
-TALLY_ATTRS = ("apply_chunks", "rep_rows", "rep_tiles")
+TALLY_ATTRS = ("apply_chunks", "rep_rows", "rep_tiles",
+               "reclaim_passes", "reclaim_slots")
+#: levels on the same vector, as the last maintenance pass found them:
+#: exported as gauges ``hash_agg_<attr>{job}``, skipped like the tallies
+GAUGE_ATTRS = ("live_groups", "tombstones", "table_slots")
 
 
 def executor_scope(i: int, ex, phase: str):
@@ -58,7 +62,7 @@ def collect_counters(executors, states):
     for i, ex in enumerate(executors):
         st = states[i]
         with executor_scope(i, ex, "counters"):
-            for attr in COUNTER_ATTRS + TALLY_ATTRS:
+            for attr in COUNTER_ATTRS + TALLY_ATTRS + GAUGE_ATTRS:
                 if hasattr(st, attr):
                     labels.append(f"{ex}.{attr}")
                     vals.append(getattr(st, attr).astype(jnp.int64))
@@ -241,11 +245,14 @@ class Fragment:
     def _maintain_impl(self, states):
         """Checkpoint-time housekeeping, all on device: executors whose
         tombstones dominate rebuild their tables (lax.cond inside
-        maybe_rehash — no host readback of tombstone counts)."""
+        maybe_rehash — no host readback of tombstone counts).  The scope
+        is ``<Executor>.<i>/rehash``, or what the executor calls its
+        pass (``HashAgg.<i>/reclaim``)."""
         new_states = list(states)
         for i, ex in enumerate(self.executors):
             if hasattr(ex, "maybe_rehash"):
-                with executor_scope(i, ex, "rehash"):
+                with executor_scope(
+                        i, ex, getattr(ex, "maintain_phase", "rehash")):
                     new_states[i] = ex.maybe_rehash(new_states[i])
         return tuple(new_states)
 

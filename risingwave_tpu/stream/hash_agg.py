@@ -124,6 +124,17 @@ class AggState(NamedTuple):
     apply_chunks: jnp.ndarray = ()
     rep_rows: jnp.ndarray = ()
     rep_tiles: jnp.ndarray = ()
+    #: tallies of ``maybe_rehash``: passes that found a tombstone in the
+    #: group table, and the tombstones they gave back
+    reclaim_passes: jnp.ndarray = ()
+    reclaim_slots: jnp.ndarray = ()
+    #: the group table as the last ``maybe_rehash`` found it, before it
+    #: reclaimed (``fragment.GAUGE_ATTRS``): its fullest in the barrier
+    live_groups: jnp.ndarray = ()
+    tombstones: jnp.ndarray = ()
+    #: the group table's size, so that a job's levels (summed over its
+    #: aggregates) read as a share of its tables
+    table_slots: jnp.ndarray = ()
 
 
 def _empty_input_col(f: Field, n: int):
@@ -181,6 +192,8 @@ class HashAggExecutor(Executor):
 
     emits_on_apply = False
     emits_on_flush = True
+    #: the scope of ``maybe_rehash`` in a device profile
+    maintain_phase = "reclaim"
 
     def __init__(
         self,
@@ -383,6 +396,11 @@ class HashAggExecutor(Executor):
             apply_chunks=jnp.zeros((), jnp.int64),
             rep_rows=jnp.zeros((), jnp.int64),
             rep_tiles=jnp.zeros((), jnp.int64),
+            reclaim_passes=jnp.zeros((), jnp.int64),
+            reclaim_slots=jnp.zeros((), jnp.int64),
+            live_groups=jnp.zeros((), jnp.int64),
+            tombstones=jnp.zeros((), jnp.int64),
+            table_slots=jnp.asarray(size, jnp.int64),
         )
 
     # ------------------------------------------------------------------
@@ -771,18 +789,14 @@ class HashAggExecutor(Executor):
         if any(not a.spec().retractable and ai not in self._minput_aggs
                for ai, a in enumerate(self.aggs)):
             n_bad = jnp.sum((valid & (signs < 0)).astype(jnp.int64))
-        return AggState(
+        return state._replace(
             table=table,
             prims=prims,
             row_count=row_count,
             dirty=dirty,
-            prev_prims=state.prev_prims,
-            prev_row_count=state.prev_row_count,
-            emitted=state.emitted,
             overflow=state.overflow + n_over + n_over_mi + n_over_d,
             inconsistency=state.inconsistency + n_bad + n_miss_mi
             + n_bad_d,
-            wm=state.wm,
             minput_vals=tuple(minput_vals),
             minput_occ=tuple(minput_occ),
             distinct_tables=tuple(d_tables),
@@ -1134,69 +1148,66 @@ class HashAggExecutor(Executor):
         )
 
     def maybe_rehash(self, state: AggState) -> AggState:
-        """Rebuild the group table once tombstones dominate (called by
-        the runtime at checkpoint barriers after state cleaning).
+        """Give the retired groups' slots back (called by the runtime at
+        checkpoint barriers, after state cleaning): whenever the group
+        table holds a tombstone, ``HashTable.reclaimed`` empties them
+        all and reinserts the groups whose probe chains crossed one,
+        every per-slot leaf moving with its group.  The cost follows
+        what the barrier retired, so q5-inner's barriers are alike and a
+        table that retires a group now and then (q7-inner) pays the
+        ``cond`` alone.
 
         Traceable: the decision is a ``lax.cond`` on the device-resident
         tombstone count, so maintenance never reads back to the host."""
-
-        def do_rehash(state: AggState) -> AggState:
-            from risingwave_tpu.state.hash_table import permute_dense
-
-            fresh, moved = state.table.rehashed()
-            prims = []
-            prev_prims = []
-            for pi, (agg_idx, ps) in enumerate(self._prim_specs):
-                st_dt = state.prims[pi].dtype
-                init = ps.init(st_dt)
-                prims.append(permute_dense(state.prims[pi], moved, init))
-                prev_prims.append(
-                    permute_dense(state.prev_prims[pi], moved, init)
-                )
-            return state._replace(
-                table=fresh,
-                prims=tuple(prims),
-                row_count=permute_dense(state.row_count, moved),
-                dirty=permute_dense(state.dirty, moved),
-                prev_prims=tuple(prev_prims),
-                prev_row_count=permute_dense(state.prev_row_count, moved),
-                emitted=permute_dense(state.emitted, moved),
-                minput_vals=tuple(
-                    permute_dense(v, moved) for v in state.minput_vals
-                ),
-                minput_occ=tuple(
-                    permute_dense(o, moved) for o in state.minput_occ
-                ),
-            )
-
-        state = jax.lax.cond(
-            state.table.tombstone_count() > self.table_size // 4,
-            do_rehash, lambda s: s, state,
+        tombs = state.table.tombstone_count()
+        state = state._replace(
+            live_groups=state.table.count().astype(jnp.int64),
+            tombstones=tombs.astype(jnp.int64),
         )
-        if not self._distinct_aggs:
-            return state
 
-        # distinct dedup tables compact independently (their own keys)
-        def rehash_d(state: AggState) -> AggState:
-            from risingwave_tpu.state.hash_table import permute_dense
-            d_tables = []
-            d_counts = []
-            for dt, cnt in zip(state.distinct_tables,
-                               state.distinct_counts):
-                fresh, moved = dt.rehashed()
-                d_tables.append(fresh)
-                d_counts.append(permute_dense(cnt, moved))
+        def reclaim(state: AggState) -> AggState:
+            inits = [ps.init(p.dtype)
+                     for (_, ps), p in zip(self._prim_specs, state.prims)]
+            dense = (state.prims, state.row_count, state.dirty,
+                     state.prev_prims, state.prev_row_count, state.emitted,
+                     state.minput_vals, state.minput_occ)
+            fills = inits + [0, False] + inits + [0, False] + [0] * len(
+                state.minput_vals) + [False] * len(state.minput_occ)
+            table, (prims, row_count, dirty, prev_prims, prev_row_count,
+                    emitted, minput_vals, minput_occ), lost = \
+                state.table.reclaimed(dense, fills)
             return state._replace(
-                distinct_tables=tuple(d_tables),
-                distinct_counts=tuple(d_counts),
+                table=table, prims=prims, row_count=row_count, dirty=dirty,
+                prev_prims=prev_prims, prev_row_count=prev_row_count,
+                emitted=emitted, minput_vals=minput_vals,
+                minput_occ=minput_occ, overflow=state.overflow + lost,
+                reclaim_passes=state.reclaim_passes + 1,
+                reclaim_slots=state.reclaim_slots + tombs,
             )
 
-        any_tomb = state.distinct_tables[0].tombstone_count()
-        for dt in state.distinct_tables[1:]:
-            any_tomb = jnp.maximum(any_tomb, dt.tombstone_count())
-        return jax.lax.cond(
-            any_tomb > self.distinct_table_size // 4,
-            rehash_d, lambda s: s, state,
+        state = jax.lax.cond(tombs > 0, reclaim, lambda s: s, state)
+
+        # distinct dedup tables reclaim independently (their own keys)
+        def reclaim_d(dt, cnt):
+            dt, (cnt,), lost = dt.reclaimed((cnt,))
+            return dt, cnt, lost
+
+        d_tables = []
+        d_counts = []
+        overflow = state.overflow
+        for dt, cnt in zip(state.distinct_tables, state.distinct_counts):
+            dt, cnt, lost = jax.lax.cond(
+                dt.tombstone_count() > 0, reclaim_d,
+                lambda dt, cnt: (dt, cnt, jnp.zeros((), jnp.int64)),
+                dt, cnt,
+            )
+            d_tables.append(dt)
+            d_counts.append(cnt)
+            overflow = overflow + lost
+        return state._replace(
+            distinct_tables=tuple(d_tables),
+            distinct_counts=tuple(d_counts),
+            overflow=overflow,
         )
 
     # ------------------------------------------------------------------

@@ -34,6 +34,7 @@ from risingwave_tpu.common.trace import GLOBAL_TRACE
 from risingwave_tpu.stream.fragment import (
     COUNTER_ATTRS,
     Fragment,
+    GAUGE_ATTRS,
     TALLY_ATTRS,
     WM_NONE,
     WM_SAFE_FLOOR,
@@ -241,15 +242,16 @@ def check_counter_values(name: str, labels: list[str],
     ``maintenance_counter_rows{job,kind}`` gauges (what the LAST
     maintenance barrier read) before anything raises.  The tallies of
     ``fragment.TALLY_ATTRS`` count engagement, not lost rows: they go
-    out as ``hash_agg_<kind>_total{job}`` and are otherwise skipped, as
-    ``.pending`` is.
+    out as ``hash_agg_<kind>_total{job}``, the levels of
+    ``fragment.GAUGE_ATTRS`` as gauges ``hash_agg_<kind>{job}``, and
+    both are otherwise skipped, as ``.pending`` is.
     """
     kinds = [label.rsplit(".", 1)[-1] for label in labels]
     if metrics is not None:
         sums: dict[str, int] = {}
         tallies: dict[str, int] = {}
         for kind, v in zip(kinds, values):
-            if kind in TALLY_ATTRS:
+            if kind in TALLY_ATTRS + GAUGE_ATTRS:
                 tallies[kind] = tallies.get(kind, 0) + int(v)
             elif kind != "pending":
                 sums[kind] = sums.get(kind, 0) + int(v)
@@ -257,10 +259,13 @@ def check_counter_values(name: str, labels: list[str],
             metrics.set_gauge("maintenance_counter_rows", v,
                               job=name, kind=kind)
         for kind, v in tallies.items():
-            metrics.set_counter(f"hash_agg_{kind}_total", v, job=name)
+            if kind in GAUGE_ATTRS:
+                metrics.set_gauge(f"hash_agg_{kind}", v, job=name)
+            else:
+                metrics.set_counter(f"hash_agg_{kind}_total", v, job=name)
     residual = []
     for label, kind, v in zip(labels, kinds, values):
-        if kind in TALLY_ATTRS:
+        if kind in TALLY_ATTRS + GAUGE_ATTRS:
             continue
         if kind == "pending":
             if v > 0:

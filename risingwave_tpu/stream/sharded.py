@@ -305,6 +305,7 @@ class ShardedStreamingJob:
         device vector (read back once per maintenance interval)."""
         from risingwave_tpu.stream.fragment import (
             COUNTER_ATTRS,
+            GAUGE_ATTRS,
             TALLY_ATTRS,
         )
 
@@ -312,7 +313,7 @@ class ShardedStreamingJob:
         vals: list[jnp.ndarray] = []
         for i, ex in enumerate(self.sharded.executors):
             st = states[i]
-            for counter in COUNTER_ATTRS + TALLY_ATTRS:
+            for counter in COUNTER_ATTRS + TALLY_ATTRS + GAUGE_ATTRS:
                 if hasattr(st, counter):
                     labels.append(f"{ex}.{counter}")
                     vals.append(

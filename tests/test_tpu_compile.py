@@ -114,9 +114,9 @@ _INSTRUCTION = re.compile(
     r"^\s*(?:ROOT )?(%[\w.\-]+) = (\([^=]*?\)|\S+) ([\w\-]+)\((.*)$")
 
 
-def _apply_scatter_widths(hlo: str) -> dict[str, int]:
+def _apply_scatter_widths(hlo: str, phase: str = "apply") -> dict[str, int]:
     """Indices handed to every ``scatter`` of the compiled program whose
-    ``op_name`` lies under a ``HashAgg.<i>/apply`` scope, by
+    ``op_name`` lies under a ``HashAgg.<i>/<phase>`` scope, by
     instruction.  A scatter's operands are N arrays, the indices, N
     updates."""
     shape_of, scatters = {}, []
@@ -127,7 +127,7 @@ def _apply_scatter_widths(hlo: str) -> dict[str, int]:
         name, shape, op, rest = m.groups()
         shape_of[name] = shape
         if op == "scatter" and re.search(
-                r'op_name="[^"]*/HashAgg\.\d+/apply/', rest):
+                rf'op_name="[^"]*/HashAgg\.\d+/{phase}/', rest):
             scatters.append((name, rest))
     out = {}
     for name, rest in scatters:
@@ -178,6 +178,20 @@ def test_q5_barrier(jobs, one_chip):
     _, hlo = _compile(job.fragment._barrier, one_chip, job.states, EPOCH,
                       text=True)
     _assert_narrow_apply(hlo, 40960)
+
+
+def test_q5_maintain(jobs, one_chip):
+    """The reclaim of both aggregates at 2^20 slots: under
+    ``HashAgg.<i>/reclaim`` no scatter is handed the table, only one
+    tile of movers (PERF.md §6, PR 30)."""
+    from risingwave_tpu.state import hash_table
+
+    job = jobs("q5")
+    _, hlo = _compile(job.fragment._maintain, one_chip, job.states,
+                      text=True)
+    widths = _apply_scatter_widths(hlo, "reclaim")
+    assert len(widths) >= 5, widths
+    assert max(widths.values()) <= hash_table.RECLAIM_TILE, widths
 
 
 def test_q8_step_person(jobs, one_chip):

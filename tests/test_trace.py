@@ -670,7 +670,7 @@ def test_window_program_names_generator_and_every_executor():
     for phase in ("flush", "watermark", "counters"):
         assert f"HashAgg.2/{phase}" in barrier
     maintain = " ".join(locs(job.fragment._maintain.lower(job.states)))
-    assert "HashAgg.2/rehash" in maintain
+    assert "HashAgg.2/reclaim" in maintain
 
 
 def test_dropped_span_leaves_no_record():
@@ -737,5 +737,15 @@ def test_hash_agg_tallies_reach_metrics(tmp_path, accel_branch, view,
         r'^maintenance_counter_rows{job="q7",kind="(\w+)"} (\S+)$',
         text, re.M)
     assert rows and all(float(v) == 0 for _, v in rows)
-    assert not {k for k, _ in rows} & {"apply_chunks", "rep_rows",
-                                       "rep_tiles"}
+    assert not {k for k, _ in rows} & {
+        "apply_chunks", "rep_rows", "rep_tiles", "reclaim_passes",
+        "reclaim_slots", "live_groups", "tombstones", "table_slots"}
+    # the reclaim's tallies and the table's levels, read with the same
+    # vector: nothing has retired after two barriers, so no pass yet
+    assert "# TYPE hash_agg_reclaim_passes_total counter" in text
+    assert "# TYPE hash_agg_live_groups gauge" in text
+    assert series("hash_agg_reclaim_passes_total") == 0
+    assert series("hash_agg_reclaim_slots_total") == 0
+    assert series("hash_agg_tombstones") == 0
+    assert series("hash_agg_live_groups") >= 1
+    assert series("hash_agg_table_slots") == 8192
