@@ -108,6 +108,18 @@ def _handshake(role: str, **fields) -> None:
     print(json.dumps(line), flush=True)
 
 
+def _wait_for_sigint() -> None:
+    """Park a role's main thread until SIGINT raises KeyboardInterrupt
+    in it.  Python runs a signal's handler in the main thread only, and
+    only when that thread next runs bytecode: a SIGINT the kernel hands
+    to another thread (the runtime's, an uploader's) does not end a
+    long ``sleep`` here, and the orderly stop would not begin until the
+    sleep did (seen on the chip: the node still ticking 60 s after the
+    signal, 2 runs of 39).  So the sleep is short."""
+    while True:
+        time.sleep(0.2)
+
+
 class SingleNode:
     def __init__(self, config=None, data_dir: str | None = None):
         from risingwave_tpu.sql.engine import Engine
@@ -257,8 +269,7 @@ def _run_meta(args) -> None:
     if args.barrier_interval_ms > 0:
         threading.Thread(target=tick_loop, daemon=True).start()
     try:
-        while True:
-            time.sleep(3600)
+        _wait_for_sigint()
     except KeyboardInterrupt:
         stop.set()
         meta.stop()
@@ -288,8 +299,7 @@ def _run_compute(args) -> None:
     _handshake("compute", worker_id=worker.worker_id, port=worker.port,
                metrics_port=args.metrics_port or None)
     try:
-        while True:
-            time.sleep(3600)
+        _wait_for_sigint()
     except KeyboardInterrupt:
         worker.stop()
 
@@ -316,8 +326,7 @@ def _run_serving(args) -> None:
                metrics_port=args.metrics_port or None,
                jax_loaded="jax" in sys.modules)
     try:
-        while True:
-            time.sleep(3600)
+        _wait_for_sigint()
     except KeyboardInterrupt:
         replica.stop()
 
@@ -424,8 +433,7 @@ def main() -> None:
     _handshake("single", pgwire_port=args.port,
                metrics_port=args.metrics_port or None)
     try:
-        while True:
-            time.sleep(3600)
+        _wait_for_sigint()
     except KeyboardInterrupt:
         node.stop()
         server.shutdown()

@@ -16,10 +16,16 @@ so the natural delta is *dirty blocks of those arrays*:
    ON DEVICE (storage/digest.py — the SAME scheme the in-memory shadow
    snapshot uses, so on the async path the digest vector is computed
    once per snapshot and handed in; the store never re-reads state).
-2. Blocks whose digest changed since the last checkpoint are fetched as
-   flat slices (adjacent dirty blocks coalesce into runs) and written
-   as a delta file — device→host traffic and disk bytes scale with the
-   epoch's actual write set, not the state size.
+2. Blocks whose digest changed since the last checkpoint are gathered
+   ON DEVICE by one cached program a job (the dirty blocks' starts are
+   an argument, a leaf's capacity is static: 1/64 of its blocks) and
+   only those blocks cross to the host, all leaves in one transfer;
+   adjacent blocks coalesce into runs there and are written as a delta
+   file.  A leaf with more dirty blocks than its capacity crosses
+   whole.  Device→host traffic and disk bytes scale with the epoch's
+   actual write set, not the state size.  A device slice with static
+   bounds is never used: it is a program per run, compiled at the
+   barrier that first needs it.
 3. Every ``full_interval`` checkpoints (or when >50% of blocks are
    dirty) a full snapshot re-bases the chain, bounding restore length
    and letting GC reclaim old chains.
@@ -78,6 +84,20 @@ def _leaf_block_count(shape, dtype, block: int) -> int:
     return leaf_block_count(shape, block)
 
 
+def _dirty_runs(leaf_dirty: np.ndarray, rows: int, m: int, block: int):
+    """Adjacent dirty blocks of one leaf coalesced into runs, as
+    ``(first element, end element, blocks)``.  A lane leaf (``rows`` >
+    1) is walked a shard row at a time, so no run crosses a shard
+    boundary; a run that reaches a row's ragged tail ends with it."""
+    nb_row = leaf_dirty.shape[0] // rows
+    for r in range(rows):
+        row_dirty = leaf_dirty[r * nb_row:(r + 1) * nb_row]
+        edges = np.flatnonzero(np.diff(np.r_[False, row_dirty, False]))
+        for b, e in zip(edges[::2], edges[1::2]):
+            yield (r * m + int(b) * block,
+                   r * m + min(int(e) * block, m), int(e - b))
+
+
 class CheckpointStore:
     """All durable I/O goes through an ``ObjectStore``
     (storage/hummock/object_store.py) — the same seam the SST layer
@@ -105,6 +125,8 @@ class CheckpointStore:
         #: per-job digest program + last digests (in-memory fast path;
         #: a restarted process re-bases with a full snapshot)
         self._digest_fns: dict[str, Any] = {}
+        #: per-job dirty-block gather program (``_gather_fn``)
+        self._gather_fns: dict[str, Any] = {}
         self._last_digests: dict[str, tuple[int, np.ndarray]] = {}
         self._since_full: dict[str, int] = {}
         #: serializes manifest read-modify-write + digest-cache updates
@@ -174,6 +196,7 @@ class CheckpointStore:
             if cached is not None:
                 self._last_digests.pop(job_name, None)
                 self._since_full.pop(job_name, None)
+                self._gather_fns.pop(job_name, None)
             block = self.block_elems
             nblocks = [
                 leaf_block_count(np.shape(x), block) for x in leaves
@@ -186,6 +209,129 @@ class CheckpointStore:
 
             self._digest_fns[job_name] = (jax.jit(digest), nblocks, sig)
             return self._digest_fns[job_name][0], nblocks
+
+    # -- the delta fetch ------------------------------------------------
+    def _gather_fn(self, job_name: str, leaves, nblocks):
+        """Cached jitted gather of dirty blocks, one per job-state
+        signature (as ``_digest_fn``, and dropped with it).  Returns
+        ``(fn, caps)``: ``caps[i]`` is how many blocks of leaf ``i`` one
+        call brings back — ``shadow._copy_leaf``'s first rung, 1/64 of
+        the leaf's blocks — and 0 for a leaf that never takes part (a
+        host array, or fewer elements than one block).  ``fn(leaves,
+        starts)`` takes the leaves that take part and, for each, a
+        ``caps[i]``-long int32 vector of window starts; it returns a
+        ``(caps[i], block)`` array a leaf.  The starts are an ARGUMENT:
+        a static slice bound is a program per dirty set, which on the
+        chip cost q8 18 s a barrier in compiles and round trips (PR
+        22); this one is compiled once, at the job's first delta."""
+        block = self.block_elems
+        sig = tuple(
+            (str(x.dtype), np.shape(x), nb)
+            if isinstance(x, jax.Array) else None
+            for x, nb in zip(leaves, nblocks)
+        )
+        with self._lock:
+            cached = self._gather_fns.get(job_name)
+            if cached is not None and cached[2] == sig:
+                return cached[0], cached[1]
+            caps = [
+                max(1, nb // 64)
+                if s is not None and block <= int(np.prod(s[1])) < 2 ** 31
+                else 0
+                for s, nb in zip(sig, nblocks)
+            ]
+            dnums = jax.lax.GatherDimensionNumbers(
+                offset_dims=(1,), collapsed_slice_dims=(),
+                start_index_map=(0,),
+            )
+
+            def gather(leaves, starts):
+                return tuple(
+                    jax.lax.gather(
+                        x.reshape(-1), s[:, None], dnums,
+                        slice_sizes=(block,),
+                        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+                    )
+                    for x, s in zip(leaves, starts)
+                )
+
+            fn = jax.jit(gather) if any(caps) else None
+            self._gather_fns[job_name] = (fn, caps, sig)
+            return fn, caps
+
+    def _fetch_delta(self, job_name: str, leaves, nblocks, lanes, dirty,
+                     payload: dict) -> tuple[dict, dict]:
+        """Fill ``payload`` with the dirty runs of a delta checkpoint,
+        keyed ``r_<leaf>_<first element>`` in leaf then element order.
+        One algorithm, three sources a leaf: a host array is cut where
+        it is; a device leaf with at most its capacity of dirty blocks
+        has those gathered on the device; any other dirty device leaf
+        crosses whole.  Everything that crosses does so in ONE
+        ``device_get``.  Returns the bytes that crossed, by path, and
+        the fetch span's ``blocks`` / ``whole_leaves``."""
+        block = self.block_elems
+        fn, caps = self._gather_fn(job_name, leaves, nblocks)
+        starts = [np.zeros(c, np.int32) for c in caps]
+        runs: dict[int, list] = {}     # every leaf with a dirty block
+        windows: dict[int, tuple] = {}  # gathered leaf -> where each
+        #                                 block lies in its window
+        whole: list[int] = []          # device leaves that cross whole
+        off = 0
+        for i, (x, nb, ln) in enumerate(zip(leaves, nblocks, lanes)):
+            leaf_dirty = dirty[off:off + nb]
+            off += nb
+            ids = np.flatnonzero(leaf_dirty)
+            if not ids.size:
+                continue
+            n = int(np.prod(np.shape(x)))
+            rows, m = ln if ln else (1, n)
+            runs[i] = list(_dirty_runs(leaf_dirty, rows, m, block))
+            if ids.size <= caps[i]:
+                # lane leaves restart their blocks at every shard row;
+                # a window that would run past the leaf's end (its
+                # ragged tail) starts earlier instead
+                b = ids % (nb // rows)
+                first = (ids // (nb // rows)) * m + b * block
+                start = np.minimum(first, n - block)
+                starts[i][:ids.size] = start
+                windows[i] = (first - start,
+                              np.minimum(block, m - b * block))
+            elif isinstance(x, jax.Array):
+                whole.append(i)
+        gathered: dict[int, Any] = {}
+        if fn is not None:
+            # dispatched at every delta, so the program is compiled at
+            # a job's first and never at a later one's first need
+            part = [i for i, c in enumerate(caps) if c]
+            gathered = dict(zip(part, fn(
+                tuple(leaves[i] for i in part),
+                tuple(starts[i] for i in part),
+            )))
+        fetched = jax.device_get(
+            [gathered[i] for i in windows] + [leaves[i] for i in whole]
+        )
+        blocks = dict(zip(windows, fetched))
+        flats = dict(zip(whole, fetched[len(windows):]))
+        for i, leaf_runs in runs.items():
+            if i in blocks:
+                got, (offs, lens), k = blocks[i], windows[i], 0
+                for s_el, _, nblk in leaf_runs:
+                    # only a run's last block can be short, or start
+                    # late in its window
+                    last = k + nblk - 1
+                    payload[f"r_{i}_{s_el}"] = np.concatenate([
+                        got[k:last].reshape(-1),
+                        got[last, offs[last]:offs[last] + lens[last]],
+                    ])
+                    k += nblk
+            else:
+                flat = np.asarray(flats.get(i, leaves[i])).reshape(-1)
+                for s_el, e_el, _ in leaf_runs:
+                    payload[f"r_{i}_{s_el}"] = flat[s_el:e_el].copy()
+        return ({"gathered": sum(h.nbytes for h in blocks.values()),
+                 "whole": sum(h.nbytes for h in flats.values())},
+                {"blocks": sum(len(w[0]) for w in windows.values()),
+                 "whole_leaves": len(whole)})
 
     # -- checkpoint save: prepare (fetch) / commit (write) --------------
     def prepare(self, job_name: str, epoch: int, leaves, shapes,
@@ -244,41 +390,27 @@ class CheckpointStore:
         # the device→host transfer (and the cut into runs), apart from
         # the diff above
         with GLOBAL_TRACE.span("ckpt_prepare.fetch", job=job_name,
-                               kind=kind):
+                               kind=kind) as span:
             if kind == "full":
                 host = jax.device_get(
                     [jnp.asarray(x).reshape(-1) for x in leaves]
                 )
                 for i, (h, s) in enumerate(zip(host, shapes)):
                     payload[f"leaf_{i}"] = np.asarray(h).reshape(s)
+                moved = {"whole": sum(
+                    h.nbytes for x, h in zip(leaves, host)
+                    if isinstance(x, jax.Array))}
+                counts = {"blocks": 0, "whole_leaves": len(host)}
             else:
-                # fetch only dirty runs, flat per leaf; lane leaves walk
-                # per shard row so no run crosses a shard boundary
-                off = 0
-                for i, (x, nb, shape, ln) in enumerate(
-                        zip(leaves, nblocks, shapes, lanes)):
-                    leaf_dirty = dirty[off:off + nb]
-                    off += nb
-                    if not leaf_dirty.any():
-                        continue
-                    # ONE transfer for a leaf with anything dirty, runs
-                    # cut on the host: a device slice per run is a program
-                    # per run (its bounds are static), which on the chip
-                    # cost q8 18 s a barrier in compiles and round trips
-                    flat = np.asarray(x).reshape(-1)
-                    n = flat.shape[0]
-                    rows, m = ln if ln else (1, n)
-                    nb_row = nb // rows
-                    for r in range(rows):
-                        row_dirty = leaf_dirty[r * nb_row:(r + 1) * nb_row]
-                        base_el = r * m
-                        # coalesce adjacent dirty blocks into runs
-                        edges = np.flatnonzero(np.diff(
-                            np.r_[False, row_dirty, False]))
-                        for b, e in zip(edges[::2], edges[1::2]):
-                            s_el = base_el + int(b) * block
-                            e_el = base_el + min(int(e) * block, m)
-                            payload[f"r_{i}_{s_el}"] = flat[s_el:e_el].copy()
+                moved, counts = self._fetch_delta(
+                    job_name, leaves, nblocks, lanes, dirty, payload
+                )
+            span.set(bytes=sum(moved.values()), **counts)
+        if self.metrics is not None:
+            for path, nbytes in moved.items():
+                if nbytes:
+                    self.metrics.inc("checkpoint_fetch_bytes_total",
+                                     nbytes, job=job_name, path=path)
         return {
             "job": job_name, "epoch": epoch, "kind": kind,
             "payload": payload, "treedef": treedef,

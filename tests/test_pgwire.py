@@ -230,3 +230,33 @@ def test_single_node_handshake_and_config_json(tmp_path):
     finally:
         if proc.poll() is None:
             proc.kill()
+
+
+def test_sigint_taken_by_another_thread_still_stops_the_node():
+    """The kernel may hand a process's SIGINT to any thread; Python
+    raises KeyboardInterrupt only in the main one, when it next runs
+    bytecode.  The roles' main threads must notice within a moment,
+    not when an hour's sleep ends."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import signal, sys, threading, time\n"
+        "from risingwave_tpu import server\n"
+        "def poke():\n"
+        "    time.sleep(0.3)\n"
+        "    signal.pthread_kill(threading.get_ident(), signal.SIGINT)\n"
+        "    time.sleep(60)\n"
+        "threading.Thread(target=poke, daemon=True).start()\n"
+        "t0 = time.monotonic()\n"
+        "try:\n"
+        "    server._wait_for_sigint()\n"
+        "except KeyboardInterrupt:\n"
+        "    sys.exit(0 if time.monotonic() - t0 < 5 else 3)\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, "-c", code], cwd=root,
+                         capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0, out.stderr[-2000:]
+

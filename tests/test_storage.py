@@ -198,6 +198,257 @@ def test_incremental_checkpoint_gc_keeps_chain_base(tmp_path):
     assert epoch == 6 and loaded["a"][6] == 6 and loaded["a"][3] == 3
 
 
+def _plain_delta(old_leaves, new_leaves, lanes, block):
+    """The delta payload cut by plain numpy from host copies of two
+    states: every block whose elements differ, adjacent ones joined
+    into runs that never cross a shard row."""
+    out = {}
+    for i, (a, b, ln) in enumerate(zip(old_leaves, new_leaves, lanes)):
+        fa, fb = np.asarray(a).reshape(-1), np.asarray(b).reshape(-1)
+        rows, m = ln if ln else (1, fb.size)
+        for r in range(rows):
+            ra, rb = fa[r * m:(r + 1) * m], fb[r * m:(r + 1) * m]
+            run = None
+            for k in range(max(1, -(-m // block)) + 1):
+                blk = slice(k * block, min((k + 1) * block, m))
+                if k * block < m and not np.array_equal(ra[blk], rb[blk]):
+                    run = blk.start if run is None else run
+                elif run is not None:
+                    out[f"r_{i}_{r * m + run}"] = \
+                        rb[run:min(k * block, m)].copy()
+                    run = None
+    return out
+
+
+def _assert_payload_is(prep, want):
+    got = prep["payload"]
+    assert list(got) == list(want)
+    for k in want:
+        assert got[k].dtype == want[k].dtype and got[k].ndim == 1, k
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+
+
+def _moved(reg, job, path):
+    """Bytes ``prepare`` counted as crossed from the device by ``path``
+    (a path never taken has no series)."""
+    try:
+        return reg.get("checkpoint_fetch_bytes_total", job=job, path=path)
+    except KeyError:
+        return 0
+
+
+def _values(n, dtype, salt):
+    v = (np.arange(n, dtype=np.int64) * 2654435761 + salt) % 1000003
+    return (v % 2 == 0) if dtype == "bool" else v.astype(dtype)
+
+
+#: (blocks touched, all within the leaf's capacity of 2?) for four
+#: deltas in a row: one block; two adjacent; the first and the last
+#: (the ragged tail, where there is one); three, which is over capacity
+_DIRTY_SETS = [([5], True), ([17, 18], True), ([0, -1], True),
+               ([3, 40, 41], False)]
+
+
+@pytest.mark.parametrize("tail", [0, 37], ids=["even", "ragged"])
+@pytest.mark.parametrize("dtype", ["int64", "int32", "bool", "float32"])
+def test_delta_fetch_gathers_what_plain_numpy_cuts(tmp_path, dtype, tail):
+    """A delta of DEVICE leaves brings back only the dirty blocks, and
+    its payload is, key for key and element for element, what plain
+    numpy cuts from host copies; the chain restores the live state."""
+    import jax
+    import jax.numpy as jnp
+
+    from risingwave_tpu.common.metrics import MetricsRegistry
+
+    block, nb = 64, 128
+    n = block * nb + tail
+    reg = MetricsRegistry()
+    store = CheckpointStore(str(tmp_path), keep_epochs=8, block_elems=block,
+                            metrics=reg)
+    host = {"s": np.zeros((), np.int64), "x": _values(n, dtype, 1)}
+    nb_all = -(-n // block)
+
+    def save(epoch, host):
+        leaves, treedef = jax.tree.flatten(
+            {k: jnp.asarray(v) for k, v in host.items()})
+        prep = store.prepare("j", epoch, leaves,
+                             [np.shape(x) for x in leaves], treedef, {})
+        store.commit(prep)
+        return prep
+
+    assert save(1, host)["kind"] == "full"
+    for epoch, (touched, fits) in enumerate(_DIRTY_SETS, start=2):
+        new = {"s": np.int64(epoch), "x": host["x"].copy()}
+        for b in touched:
+            at = (b % nb_all) * block + 3
+            new["x"][at] = ~new["x"][at] if dtype == "bool" \
+                else new["x"][at] + 1
+        before = _moved(reg, "j", "gathered")
+        prep = save(epoch, new)
+        assert prep["kind"] == "delta"
+        _assert_payload_is(prep, _plain_delta(
+            [host["s"], host["x"]], [new["s"], new["x"]], [None, None],
+            block))
+        # within its capacity the leaf crossed as two blocks' worth,
+        # over it whole; the program that gathers was compiled once
+        crossed = _moved(reg, "j", "gathered") - before
+        assert crossed == (2 * block * new["x"].itemsize if fits else 0)
+        assert store._gather_fns["j"][0]._cache_size() == 1
+        host = new
+        _, loaded, _ = store.load("j", epoch)
+        for k in host:
+            assert loaded[k].dtype == host[k].dtype
+            assert loaded[k].tobytes() == host[k].tobytes()
+
+
+@pytest.mark.parametrize("placed", ["one_device", "over_the_mesh"])
+@pytest.mark.parametrize("m", [64 * 40 + 36, 100],
+                         ids=["ragged_lanes", "lanes_under_a_block"])
+def test_delta_fetch_of_a_lane_leaf(tmp_path, m, placed):
+    """A mesh-stacked leaf digested in per-shard lanes: block starts
+    are ``lane * m + b * block``, a lane's ragged tail is its own run,
+    no run crosses a shard row, and the last lane's tail is read by a
+    window that starts early, also where the leaf lies across devices."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from risingwave_tpu.stream.shadow import ShadowSnapshot
+
+    S, block = 8, 64
+    nb_row = -(-m // block)
+    store = CheckpointStore(str(tmp_path), keep_epochs=8, block_elems=block)
+    sharding = NamedSharding(Mesh(np.array(jax.devices()[:S]), ("s",)),
+                             PartitionSpec("s"))
+
+    def place(host):
+        tree = {k: jnp.asarray(v) for k, v in host.items()}
+        if placed == "over_the_mesh":
+            tree = jax.device_put(tree, sharding)
+        return tree
+
+    host = {"n": np.zeros((S,), np.int64),
+            "x": _values(S * m, "int64", 3).reshape(S, m)}
+    sh = ShadowSnapshot(place(host), block_elems=block, shard_rows=S)
+
+    def save(epoch, digests):
+        prep = store.prepare("j", epoch, sh.leaves, sh.shapes, sh.treedef,
+                             {}, digests=np.asarray(digests), lanes=sh.lanes)
+        store.commit(prep)
+        return prep
+
+    assert save(1, sh.digests)["kind"] == "full"
+    cap = store._gather_fn("j", sh.leaves, sh.nblocks)[1][1]
+    # (lane, block) sets: a middle lane's tail; a lane's tail and the
+    # next lane's first block (adjacent in memory, two runs); the
+    # leaf's first block and the last lane's tail; more than capacity
+    sets = [[(1, -1)], [(2, -1), (3, 0)], [(0, 0), (S - 1, -1)],
+            [(r, 0) for r in range(cap + 1)]]
+    for epoch, cells in enumerate(sets, start=2):
+        new = {"n": host["n"] + 1, "x": host["x"].copy()}
+        for r, b in cells:
+            new["x"][r, min((b % nb_row) * block + 3, m - 1)] -= 1
+        prep = save(epoch, sh.update(place(new)))
+        assert prep["kind"] == "delta"
+        _assert_payload_is(prep, _plain_delta(
+            [host["n"], host["x"]], [new["n"], new["x"]],
+            [(S, 1), (S, m)], block))
+        host = new
+        _, loaded, _ = store.load("j", epoch)
+        for k in host:
+            assert loaded[k].tobytes() == host[k].tobytes()
+    assert store._gather_fns["j"][0]._cache_size() == 1
+
+
+def test_delta_of_host_arrays_dispatches_no_device_program(
+        tmp_path, monkeypatch):
+    """``save`` of a numpy tree (a spill tier's ``host_state``): the
+    runs are cut where the arrays are; nothing is gathered on, or
+    fetched from, the device."""
+    import jax
+
+    from risingwave_tpu.common.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    store = CheckpointStore(str(tmp_path), keep_epochs=8, block_elems=64,
+                            metrics=reg)
+    a = {"x": _values(64 * 128 + 5, "int64", 2), "s": np.int64(0)}
+    store.save("t", 1, a, {})
+    b = {"x": a["x"].copy(), "s": np.int64(1)}
+    b["x"][[70, 8196]] = -1
+    fetched = []
+    real = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda t: fetched.append(t) or real(t))
+    store.save("t", 2, b, {})
+    assert store.checkpoint_kind("t", 2) == "delta"
+    assert fetched == [[]]
+    assert store._gather_fns["t"][0] is None
+    assert _moved(reg, "t", "gathered") == _moved(reg, "t", "whole") == 0
+    _, loaded, _ = store.load("t", 2)
+    assert loaded["x"].tobytes() == b["x"].tobytes()
+    assert int(loaded["s"]) == 1
+
+
+def test_no_compile_after_the_first_delta(tmp_path):
+    """The dirty blocks' starts are an argument of one program: deltas
+    with other dirty counts, leaves and paths add nothing to its cache,
+    and a job of another shape gets a program of its own."""
+    import jax.numpy as jnp
+
+    store = CheckpointStore(str(tmp_path), keep_epochs=8, block_elems=64)
+    tree = {"a": jnp.zeros(64 * 256, jnp.int64),
+            "b": jnp.zeros(64 * 128, jnp.bool_),
+            "c": jnp.zeros((3, 700), jnp.int32),
+            "s": jnp.zeros((), jnp.int64)}
+    store.save("j", 1, tree, {})
+    touch = [{"a": [1]}, {"a": [1, 2, 3, 4], "b": [9]},
+             {"a": range(0, 64 * 40, 64), "c": [5]}, {}]
+    for epoch, cells in enumerate(touch, start=2):
+        for k, at in cells.items():
+            flat = tree[k].reshape(-1).at[jnp.asarray(list(at))].set(
+                epoch % 2 == 0 if k == "b" else epoch)
+            tree = dict(tree, **{k: flat.reshape(tree[k].shape)})
+        store.save("j", epoch, tree, {})
+        assert store.checkpoint_kind("j", epoch) == "delta"
+        assert store._gather_fns["j"][0]._cache_size() == 1
+    _, loaded, _ = store.load("j", epoch)
+    for k in tree:
+        np.testing.assert_array_equal(loaded[k], np.asarray(tree[k]))
+    first = store._gather_fns["j"][0]
+    store.save("j", 9, {"a": jnp.zeros(64 * 64, jnp.int64)}, {})
+    assert store._gather_fns.get("j", (None,))[0] is not first
+
+
+def test_fetch_bytes_counter_follows_the_write_set(tmp_path):
+    """``checkpoint_fetch_bytes_total``: a full moves the state's
+    bytes, a delta with one dirty block a leaf under 1/32 of them."""
+    import jax.numpy as jnp
+
+    from risingwave_tpu.common.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    store = CheckpointStore(str(tmp_path), keep_epochs=8, block_elems=64,
+                            metrics=reg)
+    tree = {"k": jnp.arange(64 * 128, dtype=jnp.int64),
+            "v": jnp.zeros(64 * 128, jnp.int64),
+            "live": jnp.zeros(64 * 128, jnp.bool_),
+            "n": jnp.zeros((), jnp.int64)}
+    state_bytes = sum(np.asarray(x).nbytes for x in tree.values())
+
+    def moved():
+        return _moved(reg, "j", "gathered") + _moved(reg, "j", "whole")
+
+    store.save("j", 1, tree, {})
+    assert moved() == state_bytes
+    assert _moved(reg, "j", "gathered") == 0
+    tree = {"k": tree["k"].at[100].set(-1), "v": tree["v"].at[100].set(7),
+            "live": tree["live"].at[100].set(True), "n": jnp.int64(1)}
+    store.save("j", 2, tree, {})
+    assert store.checkpoint_kind("j", 2) == "delta"
+    assert 0 < moved() - state_bytes < state_bytes / 32
+
+
 def test_export_mv_sst(tmp_path):
     from risingwave_tpu.common.chunk import Chunk
     from risingwave_tpu.common.types import DataType, Schema
