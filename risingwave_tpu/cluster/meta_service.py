@@ -527,13 +527,19 @@ class MetaService:
         self._assign_pending()
         return {"worker_id": wid, "cluster_epoch": self.cluster_epoch}
 
-    def rpc_heartbeat(self, worker_id: int) -> dict:
+    def rpc_heartbeat(self, worker_id: int,
+                      port: int | None = None) -> dict:
         with self._lock:
             w = self.workers.get(int(worker_id))
-            if w is None or not w.alive:
+            if w is None or not w.alive \
+                    or (port is not None and int(port) != w.port):
                 # a dead-marked worker must re-register: its jobs may
                 # already run elsewhere (ref: expired workers rejoin
-                # through the registration path)
+                # through the registration path).  So must one whose
+                # id a RESTARTED meta (ids count from 1 again) has
+                # since given to another worker: the port tells them
+                # apart, or its beats would keep the other alive and
+                # it would never rejoin
                 raise ValueError(f"unknown or expired worker {worker_id}")
             w.last_seen = time.monotonic()
         return {"ok": True, "cluster_epoch": self.cluster_epoch}

@@ -176,7 +176,8 @@ class ComputeWorker:
         while not self._stop.wait(self.heartbeat_interval_s):
             try:
                 self._meta_client.call("heartbeat",
-                                       worker_id=self.worker_id)
+                                       worker_id=self.worker_id,
+                                       port=self.port)
                 self.heartbeats_sent += 1
             except (ConnectionError, OSError):
                 # meta unreachable (restarting / partitioned): the
@@ -823,7 +824,7 @@ class ComputeWorker:
         fabric = get_fabric()
         upload_retries = 0
         for j in self.engine.jobs:
-            up = getattr(j, "_uploader", None)
+            up = j._uploader
             if up is not None:
                 upload_retries += getattr(up, "retries_total", 0)
         return {

@@ -66,7 +66,7 @@ def describe_job(job) -> dict:
         "kind": type(job).__name__,
         "committed_epoch": job.committed_epoch,
         "barriers": job.barriers_seen,
-        "paused": getattr(job, "paused", False),
+        "paused": job.paused,
     }
     if isinstance(job, StreamingJob):
         info["source_offset"] = getattr(job.source, "offset", None)
@@ -97,7 +97,7 @@ def describe_job(job) -> dict:
                 })
     elif isinstance(job, ShardedStreamingJob):
         info["n_shards"] = job.sharded.n_shards
-        info["source_offset"] = getattr(job.reader, "offset", None)
+        info["source_offset"] = getattr(job.source, "offset", None)
         info["executors"] = [
             {"executor": f"[sharded] {ex!r}",
              **_state_gauges(ex, job.states[i])}

@@ -447,11 +447,8 @@ class Engine:
             for _ in range(4096):
                 pending = 0
                 for job in self.jobs:
-                    srcs = list(getattr(job, "sources", {}).values())
-                    if not srcs:
-                        s = getattr(job, "source", None) \
-                            or getattr(job, "reader", None)
-                        srcs = [s] if s is not None else []
+                    srcs = job.sources.values() \
+                        if hasattr(job, "sources") else [job.source]
                     for s in srcs:
                         if hasattr(s, "pending"):
                             pending += s.pending()
@@ -521,11 +518,8 @@ class Engine:
         # the states are fresh — the real checkpoint must NOT be
         # overwritten; the trailing recover() will rescale-restore.
         if self.checkpoint_store is not None and not self._replaying:
-            self.checkpoint_store.save(
-                entry.job.name, entry.job.committed_epoch,
-                entry.job.states,
-                {"offset": entry.job.reader.offset},
-            )
+            entry.job.reseed_checkpoint()
+            entry.job.drain_uploads()
         return None
 
     def _dml_rows(self, stmt, entry, verb: str) -> list[tuple]:
@@ -1784,7 +1778,7 @@ class Engine:
                 continue
             if isinstance(ex, _SK):
                 # per-shard ring cursors; host merge delivery at the
-                # snapshot barrier (ShardedStreamingJob._deliver_sinks)
+                # snapshot barrier (ShardedStreamingJob._deliver_all_sinks)
                 has_sink = True
                 continue
             if not isinstance(ex, (_F, _P, _M, _AOM)):
@@ -2185,8 +2179,7 @@ class Engine:
             rows = 0
             for _ in range(barriers):
                 for job in self.jobs:
-                    if hasattr(job, "write_stall_hook"):
-                        job.write_stall_hook = stall_hook
+                    job.write_stall_hook = stall_hook
                     rows += self._job_barrier(job, chunks_per_barrier,
                                               cadence)
             # batch boundary = durability point: uploads sealed inside
@@ -2196,12 +2189,10 @@ class Engine:
             # instead — there the seal/ack split is the meta's global
             # protocol.
             for job in self.jobs:
-                if hasattr(job, "drain_uploads"):
-                    job.drain_uploads()
+                job.drain_uploads()
                 self._export_checkpoint_gauges(job)
             sp.set(rows=rows, epoch=max(
-                (getattr(j, "sealed_epoch", j.committed_epoch)
-                 for j in self.jobs), default=0))
+                (j.sealed_epoch for j in self.jobs), default=0))
 
     def _barrier_cadence(self) -> tuple[int, int, int, int]:
         """(checkpoint_frequency, maintenance interval, snapshot
@@ -2223,19 +2214,15 @@ class Engine:
         ``barrier_phase_seconds{phase}``; returns the rows pulled."""
         (job.checkpoint_frequency, job.maintenance_interval,
          job.snapshot_interval, job.upload_window) = cadence
-        if getattr(job, "metrics", None) is None:
+        if job.metrics is None:
             job.metrics = self.metrics
         name = job.name
         t0 = time.perf_counter()
         with GLOBAL_TRACE.span("run_chunks", metrics=self.metrics,
                                job=name) as sp:
-            if hasattr(job, "run_chunks"):
-                # traceable sources batch the whole inter-barrier
-                # window into one dispatch (q1 host-overhead fix)
-                rows = job.run_chunks(chunks_per_barrier)
-            else:
-                rows = sum(job.chunk_round()
-                           for _ in range(chunks_per_barrier))
+            # traceable sources batch the whole inter-barrier window
+            # into one dispatch (q1 host-overhead fix)
+            rows = job.run_chunks(chunks_per_barrier)
             sp.set(rows=rows)
         t1 = time.perf_counter()
         if fenced:
@@ -2250,9 +2237,7 @@ class Engine:
                 for _ in range(1 << 20):
                     if not self._fenced_pending(job):
                         break
-                    rows += job.run_chunks(chunks_per_barrier) \
-                        if hasattr(job, "run_chunks") \
-                        else job.chunk_round()
+                    rows += job.run_chunks(chunks_per_barrier)
         t2 = time.perf_counter()
         with GLOBAL_TRACE.span("inject_barrier", metrics=self.metrics,
                                job=name):
@@ -2289,7 +2274,7 @@ class Engine:
         # the SEAL, not the durable commit: the cluster's global epoch
         # advances only when every job's upload acks (meta polls
         # job_epochs) — the per-job barrier RPC never blocks on I/O
-        return getattr(job, "sealed_epoch", job.committed_epoch)
+        return job.sealed_epoch
 
     #: rolling window feeding the spike-ratio gauge; ~128 barriers of
     #: history keeps the median stable while a 1-in-100 spike still
@@ -2342,18 +2327,18 @@ class Engine:
         """Cheap (no device sync) checkpoint-pipeline gauges."""
         self.metrics.set_gauge("committed_epoch", job.committed_epoch,
                                job=job.name)
-        sealed = getattr(job, "sealed_epoch", job.committed_epoch)
-        self.metrics.set_gauge("sealed_epoch", sealed, job=job.name)
+        self.metrics.set_gauge("sealed_epoch", job.sealed_epoch,
+                               job=job.name)
         self.metrics.set_gauge(
             "checkpoint_seal_lag_epochs",
-            max(0, sealed - job.committed_epoch), job=job.name,
+            max(0, job.sealed_epoch - job.committed_epoch),
+            job=job.name,
         )
-        if hasattr(job, "upload_queue_depth"):
-            self.metrics.set_gauge(
-                "checkpoint_upload_queue_depth",
-                job.upload_queue_depth(), job=job.name,
-            )
-        up = getattr(job, "_uploader", None)
+        self.metrics.set_gauge(
+            "checkpoint_upload_queue_depth",
+            job.upload_queue_depth(), job=job.name,
+        )
+        up = job._uploader
         if up is not None:
             self.metrics.set_gauge("checkpoint_uploads_total",
                                    up.uploads_total, job=job.name)
@@ -2370,20 +2355,17 @@ class Engine:
         only runs when meta drives it, so durable progress must be
         observable between rounds."""
         job = self._job_by_name(name)
-        if hasattr(job, "_process_upload_acks"):
-            job._process_upload_acks()
+        job._process_upload_acks()
         return {
-            "sealed": getattr(job, "sealed_epoch", job.committed_epoch),
+            "sealed": job.sealed_epoch,
             "durable": job.committed_epoch,
-            "upload_queue": job.upload_queue_depth()
-            if hasattr(job, "upload_queue_depth") else 0,
+            "upload_queue": job.upload_queue_depth(),
         }
 
     def drain_uploads(self) -> None:
         """Flush every job's checkpoint-upload queue (orderly stop)."""
         for job in self.jobs:
-            if hasattr(job, "drain_uploads"):
-                job.drain_uploads()
+            job.drain_uploads()
 
     def collect_shard_metrics(self) -> None:
         """How a mesh job's rows and state spread over its shards — on
@@ -2445,7 +2427,7 @@ class Engine:
         steady loop never calls it."""
         for job in self.jobs:
             self._export_checkpoint_gauges(job)
-            shadow = getattr(job, "_shadow", None)
+            shadow = job._shadow
             if shadow is not None:
                 self.metrics.set_gauge(
                     "snapshot_dirty_block_ratio",
@@ -2455,11 +2437,10 @@ class Engine:
                     "snapshot_shadow_blocks", shadow.total_blocks,
                     job=job.name,
                 )
-            if hasattr(job, "stall_seconds"):
-                self.metrics.set_gauge(
-                    "checkpoint_stall_seconds_total",
-                    job.stall_seconds, job=job.name,
-                )
+            self.metrics.set_gauge(
+                "checkpoint_stall_seconds_total",
+                job.stall_seconds, job=job.name,
+            )
 
     def _job_by_name(self, name: str):
         for job in self.jobs:
@@ -2945,7 +2926,7 @@ class Engine:
         )
         part.maintenance_interval = job.maintenance_interval
         part.snapshot_interval = job.snapshot_interval
-        part.metrics = getattr(job, "metrics", None)
+        part.metrics = job.metrics
         part.ckpt_key = ckpt_key
         part.vnode_gates = [(0, 0), (1, 0)]
         part.n_vnodes = n_vnodes
@@ -3102,7 +3083,6 @@ class Engine:
             slice_job_states,
             transplant_job,
         )
-        from risingwave_tpu.stream.runtime import restore_source
 
         entry = self.catalog.get(name)
         job = entry.job
@@ -3114,13 +3094,6 @@ class Engine:
                 job.committed_epoch != rewind_epoch
                 or job.sealed_epoch != rewind_epoch):
             job.recover(rewind_epoch)
-
-        def _src_state():
-            if is_dag:
-                return {n: (s.state() if hasattr(s, "state") else {})
-                        for n, s in job.sources.items()}
-            return job.source.state() \
-                if hasattr(job.source, "state") else {}
 
         def _check_cursor(ours, donor) -> None:
             if ("offset" in ours and "offset" in donor
@@ -3161,14 +3134,10 @@ class Engine:
                     # all donors sealed the same round at the same
                     # fence: any donor's cursor is THE cursor of the
                     # handover epoch
-                    if is_dag:
-                        for sname, src in job.sources.items():
-                            restore_source(src, d_src.get(sname, {}))
-                    else:
-                        restore_source(job.source, d_src)
+                    job._restore_sources(d_src)
                     fresh = False
                 else:
-                    ours = _src_state()
+                    ours = job._source_state()
                     if is_dag:
                         for sname in job.sources:
                             _check_cursor(ours.get(sname, {}),
@@ -3193,7 +3162,7 @@ class Engine:
             self.checkpoint_store.invalidate(job.ckpt_key)
             self.checkpoint_store.save(
                 job.ckpt_key, job.committed_epoch, job.states,
-                _src_state(),
+                job._source_state(),
             )
             durable = job.committed_epoch
         # the export diff base is vnode-filtered: ownership changed, so
@@ -3828,7 +3797,7 @@ class Engine:
             # checkpoints live under the JOB's lineage key — an MV
             # attached to a shared DagJob (MV-on-MV) reads its job's
             # snapshot; a partitioned job reads its own partition's
-            ckpt_name = getattr(entry.job, "ckpt_key", entry.job.name)
+            ckpt_name = entry.job.ckpt_key
             epochs = self.checkpoint_store.epochs(ckpt_name)
             if qe not in epochs:
                 raise PlanError(

@@ -34,8 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from risingwave_tpu.common.epoch import EpochPair
-from risingwave_tpu.common.trace import GLOBAL_TRACE
 from risingwave_tpu.stream.fragment import (
     COUNTER_ATTRS,
     Fragment,
@@ -44,12 +42,9 @@ from risingwave_tpu.stream.fragment import (
     collect_counters,
 )
 from risingwave_tpu.stream.runtime import (
-    CheckpointPipelineMixin,
-    CheckpointSnapshot,
-    check_counter_values,
+    BarrierLoop,
     deliver_sinks,
     restore_source,
-    rewind_spill_tier,
 )
 
 from risingwave_tpu.parallel.exchange import (
@@ -85,7 +80,7 @@ class JoinNode:
         return self.join.init_state()
 
 
-class DagJob(CheckpointPipelineMixin):
+class DagJob(BarrierLoop):
     """A streaming job over an arbitrary DAG of fragments and joins.
 
     ``sources`` maps names to chunk readers; ``nodes`` is a topological
@@ -108,11 +103,9 @@ class DagJob(CheckpointPipelineMixin):
         exchanges: dict | None = None,
         staged: bool = False,
     ):
+        super().__init__(name, checkpoint_frequency, checkpoint_store)
         self.sources = dict(sources)
         self.nodes: list = list(nodes)
-        self.name = name
-        self.checkpoint_frequency = checkpoint_frequency
-        self.checkpoint_store = checkpoint_store
         #: sharded execution (ref: every stateful op is vnode-parallel,
         #: src/meta/src/stream/stream_graph/actor.rs:435): the whole
         #: reachable subgraph runs per-shard inside shard_map, with
@@ -143,41 +136,24 @@ class DagJob(CheckpointPipelineMixin):
         #: dispatches is a throughput cliff — exported as
         #: ``dag_fused_fallback_total{reason}`` as it is counted)
         self.fused_fallbacks: dict[str, int] = {}
-        self.maintenance_interval = 1
-        self._ckpts_since_maintain = 0
-        self.snapshot_interval = 1
-        self._ckpts_since_snapshot = 0
+        #: host spill tiers, one list (a tier per shard) per spill-
+        #: enabled agg site (node_idx, exec_idx), and their programs
+        self._spill_tiers: dict = {}
+        self._spill_progs: dict = {}
         self.states = self._init_states()
-        self.epoch = EpochPair.first()
-        self.barriers_seen = 0
-        self.checkpoints: list[CheckpointSnapshot] = []
-        self.committed_epoch = 0
-        self.paused = False
-        #: cumulative seconds stalled on checkpoint-upload backpressure
-        self.stall_seconds = 0.0
-        self._counters = None
         self.counter_labels: list[str] = []
-        self._init_pipeline()
         self._rebuild()
 
     def _init_states(self):
+        def one_shard(_=None):
+            return tuple(
+                n.init_state() if n is not None else None
+                for n in self.nodes
+            )
+
         if self.mesh is None:
-            return tuple(
-                n.init_state() if n is not None else None
-                for n in self.nodes
-            )
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        def one_shard(_):
-            return tuple(
-                n.init_state() if n is not None else None
-                for n in self.nodes
-            )
-
-        stacked = jax.vmap(one_shard)(jnp.arange(self.n_shards))
-        return jax.device_put(
-            stacked, NamedSharding(self.mesh, P(self.AXIS))
-        )
+            return one_shard()
+        return self._place(jax.vmap(one_shard)(jnp.arange(self.n_shards)))
 
     def _sharding_spec(self):
         from jax.sharding import PartitionSpec as P
@@ -258,13 +234,10 @@ class DagJob(CheckpointPipelineMixin):
             else:
                 # sharded job: the new node's state gets the same
                 # stacked-and-sharded layout as _init_states
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
-                stacked = jax.vmap(lambda _: n.init_state())(
-                    jnp.arange(self.n_shards)
-                )
-                states.append(jax.device_put(
-                    stacked, NamedSharding(self.mesh, P(self.AXIS))
+                states.append(self._place(
+                    jax.vmap(lambda _: n.init_state())(
+                        jnp.arange(self.n_shards)
+                    )
                 ))
             ids.append(len(self.nodes) - 1)
         self.states = tuple(states)
@@ -296,44 +269,7 @@ class DagJob(CheckpointPipelineMixin):
             del self.exchanges[key]
         self._rebuild()
 
-    def reseed_checkpoint(self) -> None:
-        """Re-snapshot after a topology change: retained checkpoints
-        hold the OLD state-tree shape (and old source-name keys), so a
-        recover() between the change and the next commit would restore
-        a structurally incompatible tree.  Callers invoke this once the
-        change (attach/merge/remove + backfill) is complete."""
-        self._snapshot_and_save(self.committed_epoch)
-
-    def _snapshot_and_save(self, epoch: int) -> None:
-        """The shared checkpoint tail: incremental shadow snapshot +
-        async durable upload (used by both the barrier commit and
-        topology reseeds).  Sharded meshes ride the SAME pipeline with
-        per-shard digest lanes (stream/shadow.py ``shard_rows``): no
-        digest block spans a shard row, so dirty tracking — and the
-        delta upload — is exact per shard, replacing the old full-copy
-        full-upload path."""
-        src_state = {
-            name: (src.state() if hasattr(src, "state") else {})
-            for name, src in self.sources.items()
-        }
-        # ONE host materialization per tier, shared by the in-memory
-        # snapshot and the durable save; keys carry the shard index
-        spill_host = {
-            (idx, j, s): tier.snapshot()
-            for (idx, j), tiers in getattr(self, "_spill_tiers",
-                                           {}).items()
-            for s, tier in enumerate(tiers)
-            if tier.rows_absorbed
-        }
-        spill_items = [
-            (self._spill_key(idx, j, s), host_state)
-            for (idx, j, s), host_state in spill_host.items()
-        ]
-        self._snapshot_commit(epoch, src_state, spill_host, spill_items)
-
     def _shadow_shard_rows(self) -> int | None:
-        """Mesh-stacked trees digest in per-shard lanes (see
-        CheckpointPipelineMixin._snapshot_commit)."""
         return self.n_shards if self.mesh is not None else None
 
     def downstream_closure(self, ref: Ref,
@@ -680,7 +616,6 @@ class DagJob(CheckpointPipelineMixin):
         reader = self.sources[src_name]
         if self.mesh is not None:
             if not fused:
-                from jax.sharding import NamedSharding, PartitionSpec as P
                 chunk = reader.next_chunk()
                 host = jax.device_get(chunk)
                 empty = jax.tree.map(np.zeros_like, host)
@@ -688,10 +623,7 @@ class DagJob(CheckpointPipelineMixin):
                     lambda *xs: np.stack(xs),
                     *([host] + [empty] * (self.n_shards - 1)),
                 )
-                stacked = jax.device_put(
-                    stacked, NamedSharding(self.mesh, P(self.AXIS))
-                )
-                self.states = prog(self.states, stacked)
+                self.states = prog(self.states, self._place(stacked))
                 return chunk.capacity
             # one cap-stride ordinal block per shard (split readers own
             # disjoint ordinal ranges, like the reference's source
@@ -870,21 +802,16 @@ class DagJob(CheckpointPipelineMixin):
         ``next_base()`` sequence the per-chunk path consumes — the
         generated streams are ordinal-identical to n per-chunk rounds,
         so fused and unfused runs stay byte-identical."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
         prog = self._multi_prog(n)
         rows = 0
         base_cols = []
-        sharding = NamedSharding(self.mesh, P(self.AXIS))
         for nm, k in self._pulls:
             reader = self.sources[nm]
             arr = np.empty((n * k, self.n_shards), np.int64)
             for i in range(n * k):
                 for s in range(self.n_shards):
                     arr[i, s] = reader.next_base()
-            base_cols.append(jax.device_put(
-                jnp.asarray(arr.T), sharding
-            ))
+            base_cols.append(self._place(jnp.asarray(arr.T)))
             rows += reader.cap * n * k * self.n_shards
         self.states = prog(self.states, *base_cols)
         return rows
@@ -1119,37 +1046,17 @@ class DagJob(CheckpointPipelineMixin):
             out_specs=(spec, P()),
         ), donate_argnums=(0,))
 
-    def _barrier_epoch_arg(self, sealed):
-        if self.mesh is None:
-            return sealed
-        return jnp.full((self.n_shards,), sealed, jnp.int64)
-
-    def inject_barrier(self) -> None:
-        self.barriers_seen += 1
-        sealed = self.epoch.curr.value
-        with GLOBAL_TRACE.span("inject_barrier.dispatch", job=self.name):
-            if self.staged:
-                self._counters = self._staged_barrier(sealed)
-            else:
-                if self._barrier_prog is None:
-                    self._barrier_prog = self._make_barrier_prog()
-                self.states, self._counters = self._barrier_prog(
-                    self.states, self._barrier_epoch_arg(sealed)
-                )
-
-        if self.barriers_seen % self.checkpoint_frequency == 0:
-            self._ckpts_since_maintain += 1
-            if self._ckpts_since_maintain >= self.maintenance_interval:
-                self._maintain(sealed)
-                self._ckpts_since_maintain = 0
-            self._ckpts_since_snapshot += 1
-            if self._ckpts_since_snapshot >= self.snapshot_interval:
-                self._ckpts_since_snapshot = 0
-                self._commit_checkpoint(sealed)
-        # cheap ack poll: committed_epoch (and deferred sink delivery)
-        # advances while uploads complete in the background
-        self._process_upload_acks()
-        self.epoch = self.epoch.bump()
+    def _cross_barrier(self, epoch_val) -> None:
+        if self.staged:
+            self._counters = self._staged_barrier(epoch_val)
+            return
+        if self._barrier_prog is None:
+            self._barrier_prog = self._make_barrier_prog()
+        if self.mesh is not None:
+            epoch_val = jnp.full((self.n_shards,), epoch_val, jnp.int64)
+        self.states, self._counters = self._barrier_prog(
+            self.states, epoch_val
+        )
 
     # -- maintenance ----------------------------------------------------
     def _maintain_impl(self, states):
@@ -1178,33 +1085,10 @@ class DagJob(CheckpointPipelineMixin):
             body, mesh=self.mesh, in_specs=(spec,), out_specs=spec,
         ), donate_argnums=(0,))
 
-    def _maintain(self, sealed) -> None:
+    def _run_maintain(self) -> None:
         if self._maintain_prog is None:
             self._maintain_prog = self._make_maintain_prog()
-        with GLOBAL_TRACE.span("_maintain", job=self.name):
-            self.states = self._maintain_prog(self.states)
-            if self._counters is None:
-                return
-            # THE one device sync
-            with GLOBAL_TRACE.span("_maintain.device_wait",
-                                   job=self.name):
-                values = np.asarray(self._counters)
-            residual = check_counter_values(
-                self.name, self.counter_labels, values, self.metrics
-            )
-            for _ in range(64):
-                if not residual:
-                    break
-                if self.staged:
-                    self._counters = self._staged_barrier(sealed)
-                else:
-                    self.states, self._counters = self._barrier_prog(
-                        self.states, self._barrier_epoch_arg(sealed)
-                    )
-                residual = check_counter_values(
-                    self.name, self.counter_labels,
-                    np.asarray(self._counters), self.metrics,
-                )
+        self.states = self._maintain_prog(self.states)
 
     # -- checkpoint / recovery ------------------------------------------
     def _deliver_all_sinks(self, epoch_val) -> None:
@@ -1216,35 +1100,40 @@ class DagJob(CheckpointPipelineMixin):
                 )
         self.states = tuple(new_states)
 
-    def _commit_checkpoint(self, sealed) -> None:
-        # spill tiers drain under the mesh too (per-shard tiers); only
-        # sink delivery stays meshless (sharded plans exclude sinks)
-        with GLOBAL_TRACE.span("_commit_checkpoint", job=self.name,
-                               epoch=sealed):
-            self._drain_spill_tiers(sealed)
-            if self.mesh is None:
-                up = self._ensure_uploader()
-                if up is None or up.pending() == 0:
-                    with GLOBAL_TRACE.span("_commit_checkpoint.sinks",
-                                           job=self.name):
-                        self._deliver_all_sinks(sealed)
-                else:
-                    # uploader behind: delivery advances on ack only
-                    self._sinks_due = True
-            self._snapshot_and_save(sealed)
+    def _place(self, tree):
+        """A stacked ``[n_shards, ...]`` tree (states, chunks) pinned
+        to the mesh layout: loaded trees are host arrays and shadow
+        restores land on the default device."""
+        if self.mesh is None:
+            return super()._place(tree)
+        from jax.sharding import NamedSharding
+        return jax.device_put(
+            tree, NamedSharding(self.mesh, self._sharding_spec())
+        )
+
+    def _source_state(self) -> dict:
+        return {
+            name: (src.state() if hasattr(src, "state") else {})
+            for name, src in self.sources.items()
+        }
+
+    def _restore_sources(self, state: dict) -> None:
+        for name, src in self.sources.items():
+            restore_source(src, state.get(name, {}))
+
+    def _reset_sources(self) -> None:
+        for src in self.sources.values():
+            if hasattr(src, "offset"):
+                src.offset = 0
 
     # -- spill-to-host (stream/spill.py) --------------------------------
-    def _restore_spill_tiers(self, epoch: int) -> None:
-        """Recovery companion: rewind host tiers via the shared policy
-        (see runtime.rewind_spill_tier), one per shard."""
+    def _iter_spill_tiers(self):
+        """One tier per shard per site; snapshot keys carry the shard
+        index."""
         for idx, j, ex in self._spill_sites():
             self._ensure_spill_tier(idx, j, ex)
             for s, tier in enumerate(self._spill_tiers[(idx, j)]):
-                key = self._spill_key(idx, j, s)
-                self.checkpoint_store.invalidate(key)
-                rewind_spill_tier(
-                    self.checkpoint_store, key, epoch, tier
-                )
+                yield (idx, j, s), self._spill_key(idx, j, s), tier
 
     def _spill_sites(self):
         """[(node_idx, exec_idx, executor)] of spill-enabled aggs."""
@@ -1264,9 +1153,6 @@ class DagJob(CheckpointPipelineMixin):
         return base if self.n_shards == 1 else f"{base}_s{s}"
 
     def _ensure_spill_tier(self, idx: int, j: int, ex) -> None:
-        if not hasattr(self, "_spill_tiers"):
-            self._spill_tiers = {}
-            self._spill_progs = {}
         key = (idx, j)
         if key in self._spill_tiers:
             return
@@ -1348,11 +1234,10 @@ class DagJob(CheckpointPipelineMixin):
         mesh every shard drains into its own tier; the merged
         changelogs inject back shard-aligned through the sharded
         program (exchanges included)."""
-        import numpy as _np
         for idx, j, ex in self._spill_sites():
             self._ensure_spill_tier(idx, j, ex)
             key = (idx, j)
-            counts = _np.asarray(self.states[idx][j].spill_count)
+            counts = np.asarray(self.states[idx][j].spill_count)
             if int(counts.sum()) == 0:
                 continue
             drain_p, inject_p = self._spill_progs[key]
@@ -1371,86 +1256,15 @@ class DagJob(CheckpointPipelineMixin):
                 outs.append(tiers[s].process(shard_chunk, sealed))
             if all(o is None for o in outs):
                 continue
-            import numpy as _np2
             proto = next(o for o in outs if o is not None)
             empty = jax.tree.map(
-                lambda x: _np2.zeros_like(_np2.asarray(x)), proto
+                lambda x: np.zeros_like(np.asarray(x)), proto
             )
             stacked = jax.tree.map(
-                lambda *xs: _np2.stack([_np2.asarray(x) for x in xs]),
+                lambda *xs: np.stack([np.asarray(x) for x in xs]),
                 *[o if o is not None else empty for o in outs],
             )
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            stacked = jax.device_put(
-                stacked, NamedSharding(self.mesh, P(self.AXIS))
-            )
-            self.states = inject_p(self.states, stacked)
-
-    def recover(self, epoch: int | None = None) -> None:
-        """Reset to the last committed checkpoint (ref §3.5).  Drains
-        the upload queue first — sealed epochs finish becoming durable
-        before the rewind target is chosen.  ``epoch`` pins the rewind
-        to a specific retained checkpoint (partitioned DagJobs rewind
-        to the handover round before a vnode-slice transplant, exactly
-        like StreamingJob partitions); checkpoints live under
-        ``ckpt_key`` — a partition's lineage, not the job name."""
-        self._counters = None
-        if self._uploader is not None:
-            self._uploader.drain(raise_error=False)
-            self._process_upload_acks()
-            self._uploader.clear_error()
-            self._sinks_due = False
-        if self.checkpoint_store is not None:
-            # see StreamingJob.recover: rewinds invalidate the digest
-            # cache so the next save re-bases with a full snapshot
-            # (and vacuum orphan files of a crashed upload)
-            self.checkpoint_store.invalidate(self.ckpt_key)
-            loaded = self.checkpoint_store.load(self.ckpt_key, epoch)
-            if loaded is not None:
-                epoch_v, states, src_state = loaded
-                if self.mesh is not None:
-                    from jax.sharding import (
-                        NamedSharding, PartitionSpec as P,
-                    )
-                    self.states = jax.device_put(
-                        states, NamedSharding(self.mesh, P(self.AXIS))
-                    )
-                else:
-                    self.states = jax.device_put(states)
-                self.committed_epoch = epoch_v
-                self.sealed_epoch = epoch_v
-                for name, src in self.sources.items():
-                    restore_source(src, src_state.get(name, {}))
-                self._restore_spill_tiers(epoch_v)
-                return
-        if not self.checkpoints:
-            self.states = self._init_states()
-            for src in self.sources.values():
-                if hasattr(src, "offset"):
-                    src.offset = 0
-            for tiers in getattr(self, "_spill_tiers", {}).values():
-                for tier in tiers:
-                    tier.reset()
-            return
-        snap = self.checkpoints[-1]
-        states = self._restore_in_memory(snap)
-        if self.mesh is not None:
-            # shadow restores land on the default device; re-pin the
-            # stacked tree to the mesh layout before programs run
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            states = jax.device_put(
-                states, NamedSharding(self.mesh, P(self.AXIS))
-            )
-        self.states = states
-        for name, src in self.sources.items():
-            restore_source(src, snap.source_state.get(name, {}))
-        for (idx, j), tiers in getattr(self, "_spill_tiers",
-                                       {}).items():
-            for s, tier in enumerate(tiers):
-                if snap.spill and (idx, j, s) in snap.spill:
-                    tier.restore(snap.spill[(idx, j, s)])
-                else:
-                    tier.reset()
+            self.states = inject_p(self.states, self._place(stacked))
 
     # -- serving (sharded) ----------------------------------------------
     def mv_rows(self, mv_executor, state_index):
@@ -1534,14 +1348,6 @@ class DagJob(CheckpointPipelineMixin):
                 new_states, node_id, chunk, side, direct
             )
         return tuple(new_states)
-
-    # -- driving --------------------------------------------------------
-    def run(self, barriers: int, chunks_per_barrier: int) -> None:
-        for _ in range(barriers):
-            for _ in range(chunks_per_barrier):
-                self.chunk_round()
-            self.inject_barrier()
-        self.drain_uploads()
 
     @classmethod
     def binary(

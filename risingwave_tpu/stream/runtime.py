@@ -17,7 +17,10 @@ synchronous host↔device round trips:
 - error counters (overflow/inconsistency) are collected into ONE device
   vector per barrier and read back once per maintenance interval;
 - rehash decisions are ``lax.cond`` on device tombstone counts;
-- in-memory snapshots are jit-compiled device→device tree copies.
+- snapshots are incremental device→device shadow updates.
+
+``BarrierLoop`` is that protocol, once; the runtimes (``StreamingJob``
+here, ``DagJob``, ``ShardedStreamingJob``) are device programs + hooks.
 """
 
 from __future__ import annotations
@@ -32,69 +35,93 @@ import numpy as np
 from risingwave_tpu.common.epoch import EpochPair
 from risingwave_tpu.common.trace import GLOBAL_TRACE
 from risingwave_tpu.stream.fragment import (
-    COUNTER_ATTRS,
     Fragment,
     GAUGE_ATTRS,
     TALLY_ATTRS,
-    WM_NONE,
-    WM_SAFE_FLOOR,
-    collect_counters,
 )
 from risingwave_tpu.stream.message import Barrier, BarrierKind
 
 
 @dataclass
 class CheckpointSnapshot:
-    """A committed epoch: device snapshot of all state + source offsets.
+    """A committed epoch's host-side record: the state itself lives in
+    the job's incremental ``ShadowSnapshot`` (stream/shadow.py), which
+    ``recover()`` restores from when there is no durable store.
 
     ref: Hummock ``commit_epoch`` (src/meta/src/hummock/manager/
     commit_epoch.rs:73) — the in-memory snapshot stays device-resident;
     only the durable store pays a device→host transfer.
-
-    ``states is None`` marks a SHADOW-BACKED snapshot: the state lives
-    in the job's incremental ``ShadowSnapshot`` (stream/shadow.py) and
-    ``recover()`` restores from there — the full-copy tree is only
-    retained on paths that still take it (sharded meshes).
     """
 
     epoch: int
-    states: Any
     source_state: dict
     #: host copies of spill-tier states at this epoch (key → pytree);
     #: None/missing key = the tier had absorbed nothing yet
     spill: dict | None = None
 
 
-#: jitted device→device snapshot copy (one dispatch for the whole tree)
-@jax.jit
-def _snapshot_copy(tree):
-    return jax.tree.map(jnp.copy, tree)
+class BarrierLoop:
+    """The host side of a barrier, once, for every runtime: cadence,
+    maintain, commit, recover.  A runtime (``StreamingJob``, ``DagJob``,
+    ``ShardedStreamingJob``) is its device programs plus the hooks this
+    class calls; it sets ``self.states`` itself once its programs exist.
 
+    Checkpoints are incremental shadow snapshots with pipelined async
+    durable uploads (stream/shadow.py, stream/checkpoint.py): a
+    snapshot barrier SEALS the epoch (``sealed_epoch``) in one async
+    device dispatch and enqueues persistence to a background uploader;
+    ``committed_epoch`` (the recovery/serving pin) advances only when
+    the upload ACKS.  Without a durable store, seal and commit coincide
+    (the shadow IS the commit).  The loop stalls only when the uploader
+    falls more than ``upload_window`` epochs behind — the checkpoint
+    analog of the L0-depth write stall.
 
-class CheckpointPipelineMixin:
-    """Incremental shadow snapshots + pipelined async durable uploads,
-    shared by StreamingJob and DagJob (see stream/shadow.py and
-    stream/checkpoint.py).
-
-    Contract: a snapshot barrier SEALS the epoch (``sealed_epoch``) in
-    one async device dispatch and enqueues persistence to a background
-    uploader; ``committed_epoch`` (the recovery/serving pin) advances
-    only when the upload ACKS.  Without a durable store, seal and
-    commit coincide (the shadow IS the commit).  The barrier loop
-    stalls only when the uploader falls more than ``upload_window``
-    epochs behind — the checkpoint analog of the L0-depth write stall.
+    Hooks a runtime provides: ``_cross_barrier``, ``_run_maintain``,
+    ``counter_labels``, ``_init_states``, ``run_chunk``; where it
+    differs from one plain source on one device and no spill tier:
+    ``_place``, ``_source_state`` / ``_restore_sources`` /
+    ``_reset_sources``, ``_drain_spill_tiers`` / ``_iter_spill_tiers``,
+    ``_deliver_all_sinks``, ``_shadow_shard_rows``.
     """
 
     #: max sealed-but-unacked epochs before the barrier loop stalls
     upload_window: int = 4
     #: optional MetricsRegistry (the engine attaches its own)
     metrics = None
-    _shadow = None
-    _uploader = None
-    _sinks_due = False
+    _ckpt_key = None
 
-    def _init_pipeline(self) -> None:
+    def __init__(self, name: str, checkpoint_frequency: int = 1,
+                 checkpoint_store=None):
+        self.name = name
+        self.checkpoint_frequency = checkpoint_frequency
+        #: optional durable store (storage.CheckpointStore); when set,
+        #: commits persist across process restarts
+        self.checkpoint_store = checkpoint_store
+        #: checkpoints between maintenance passes (amortizes the ONE
+        #: counters readback + rehash program)
+        self.maintenance_interval = 1
+        self._ckpts_since_maintain = 0
+        #: checkpoints between snapshot commits
+        self.snapshot_interval = 1
+        self._ckpts_since_snapshot = 0
+        #: storage-service backpressure (the Hummock write-limit
+        #: contract): when set, every barrier crossing first calls
+        #: this hook, which blocks while the storage L0 is deeper than
+        #: its stall threshold — ingest yields to the compactor
+        #: instead of burying it.  Returns seconds stalled.
+        self.write_stall_hook = None
+        #: cumulative seconds stalled (write stall + upload window)
+        self.stall_seconds = 0.0
+        self.epoch = EpochPair.first()
+        self.barriers_seen = 0
+        self.checkpoints: list[CheckpointSnapshot] = []
+        #: committed epoch visible to batch reads (ref pinned snapshots)
+        self.committed_epoch: int = 0
         self.sealed_epoch = 0
+        self.paused = False
+        #: counters vector from the last barrier program (device array;
+        #: read back once per maintenance interval)
+        self._counters = None
         self._shadow = None
         self._uploader = None
         self._sinks_due = False
@@ -105,11 +132,184 @@ class CheckpointPipelineMixin:
         partitioned job (cluster scale plane) runs one replica per
         worker over ONE shared store — each partition checkpoints
         under its own lineage key instead of the job name."""
-        return getattr(self, "_ckpt_key", None) or self.name
+        return self._ckpt_key or self.name
 
     @ckpt_key.setter
     def ckpt_key(self, value: str) -> None:
         self._ckpt_key = value
+
+    # -- hooks ----------------------------------------------------------
+    def _cross_barrier(self, epoch_val):
+        """Dispatch the barrier crossing at ``epoch_val`` (async),
+        leaving ``self.states`` and ``self._counters`` set; may return
+        the first flush pass's emissions."""
+        raise NotImplementedError
+
+    def _run_maintain(self) -> None:
+        """Dispatch the maintain program over ``self.states`` (a
+        runtime without one keeps this)."""
+
+    def _init_states(self):
+        """A fresh state tree, placed where the programs expect it."""
+        raise NotImplementedError
+
+    def _place(self, states):
+        """Put a loaded (host) or shadow-restored tree where the
+        programs expect it."""
+        return jax.device_put(states)
+
+    def _source_state(self) -> dict:
+        return self.source.state() if hasattr(self.source, "state") \
+            else {}
+
+    def _restore_sources(self, state: dict) -> None:
+        restore_source(self.source, state)
+
+    def _reset_sources(self) -> None:
+        if hasattr(self.source, "offset"):
+            self.source.offset = 0
+
+    def _drain_spill_tiers(self, epoch_val) -> None:
+        """Snapshot-barrier hook: divert ring rows to the host tiers
+        and inject their changelog downstream."""
+
+    def _iter_spill_tiers(self):
+        """(snapshot key, durable-store key, tier) of every host spill
+        tier."""
+        return ()
+
+    def _deliver_all_sinks(self, epoch_val) -> None:
+        """Drain sink ring buffers at ``epoch_val``."""
+
+    def _shadow_shard_rows(self) -> int | None:
+        """Leading per-shard axis length of every state leaf
+        (mesh-stacked trees digest in per-shard lanes), None for
+        linear trees."""
+        return None
+
+    # -- driving --------------------------------------------------------
+    def chunk_round(self) -> int:
+        """One scheduling round (one chunk for a single-source job)."""
+        return self.run_chunk()
+
+    def run_chunks(self, n: int) -> int:
+        """n scheduling rounds; runtimes with a fused window program
+        make them one dispatch."""
+        if self.paused:
+            return 0
+        return sum(self.chunk_round() for _ in range(n))
+
+    def run(self, barriers: int, chunks_per_barrier: int) -> None:
+        """The steady-state loop (ref §3.3).  Uploads pipeline within
+        the batch; the batch boundary drains them (durability point)."""
+        for _ in range(barriers):
+            for _ in range(chunks_per_barrier):
+                self.chunk_round()
+            self.inject_barrier()
+        self.drain_uploads()
+
+    # -- the barrier ----------------------------------------------------
+    def inject_barrier(self, barrier: Barrier | None = None):
+        """Cross a barrier: one async dispatch (flush + drain +
+        watermarks + counters), then maintenance / checkpoint on their
+        cadences.
+
+        Returns the chunks emitted by the first flush pass where the
+        runtime has them (they have already flowed through the
+        downstream executors — e.g. into a trailing Materialize — so
+        callers usually ignore them).
+        """
+        if barrier is None:
+            self.barriers_seen += 1
+            kind = (
+                BarrierKind.CHECKPOINT
+                if self.barriers_seen % self.checkpoint_frequency == 0
+                else BarrierKind.BARRIER
+            )
+            # the barrier SEALS the epoch data has been flowing in
+            # (epoch.curr) and opens the next one (ref EpochPair)
+            barrier = Barrier(self.epoch.bump(), kind)
+        if barrier.mutation is not None:
+            self._apply_mutation(barrier.mutation)
+        if self.write_stall_hook is not None:
+            # the barrier loop is the ingest clock: stalling HERE (not
+            # per chunk) applies backpressure at epoch granularity
+            # without touching the fused steady-state dispatch
+            self.stall_seconds += self.write_stall_hook()
+
+        epoch_val = barrier.epoch.prev.value
+        with GLOBAL_TRACE.span("inject_barrier.dispatch", job=self.name):
+            outs = self._cross_barrier(epoch_val)
+        if barrier.is_checkpoint:
+            self._ckpts_since_maintain += 1
+            if self._ckpts_since_maintain >= self.maintenance_interval:
+                self._maintain(epoch_val)
+                self._ckpts_since_maintain = 0
+            self._ckpts_since_snapshot += 1
+            if self._ckpts_since_snapshot >= self.snapshot_interval:
+                self._ckpts_since_snapshot = 0
+                self._commit_checkpoint(epoch_val)
+        # cheap ack poll keeps committed_epoch (and deferred sink
+        # delivery) advancing while uploads complete in the background
+        self._process_upload_acks()
+        self.epoch = barrier.epoch
+        return outs
+
+    def _apply_mutation(self, mutation) -> None:
+        if mutation.kind == "pause":
+            self.paused = True
+        elif mutation.kind == "resume":
+            self.paused = False
+        elif mutation.kind == "stop":
+            self.paused = True
+
+    def _maintain(self, epoch_val) -> None:
+        """Rehash (on device) + the single counters readback."""
+        with GLOBAL_TRACE.span("_maintain", job=self.name):
+            self._run_maintain()
+            if self._counters is None:
+                return
+            # THE one device sync: the host blocked on the chip until
+            # the window, barrier and maintain programs have run
+            with GLOBAL_TRACE.span("_maintain.device_wait",
+                                   job=self.name):
+                values = np.asarray(self._counters)
+            residual = check_counter_values(
+                self.name, self.counter_labels, values, self.metrics
+            )
+            # residual pending beyond MAX_DRAIN_ROUNDS×emit_capacity
+            # per barrier: pathological; finish draining with host loops
+            for _ in range(64):
+                if not residual:
+                    break
+                self._cross_barrier(epoch_val)
+                residual = check_counter_values(
+                    self.name, self.counter_labels,
+                    np.asarray(self._counters), self.metrics,
+                )
+
+    def _commit_checkpoint(self, epoch_val) -> None:
+        """Seal one snapshot epoch: spill drain + sink delivery + the
+        incremental shadow update, then hand durable persistence to the
+        background uploader.  Recovery rewinds to the last DURABLE
+        epoch, so ``committed_epoch`` (and deferred sink delivery)
+        advance only on uploader ack; without a store, seal == commit
+        (the shadow is the recovery point)."""
+        with GLOBAL_TRACE.span("_commit_checkpoint", job=self.name,
+                               epoch=epoch_val):
+            self._drain_spill_tiers(epoch_val)
+            up = self._ensure_uploader()
+            if up is None or up.pending() == 0:
+                # at-least-once delivery (rows delivered before their
+                # epoch is durable ride THIS epoch's snapshot via the
+                # advanced read_cursor)
+                with GLOBAL_TRACE.span("_commit_checkpoint.sinks",
+                                       job=self.name):
+                    self._deliver_all_sinks(epoch_val)
+            else:
+                # uploader behind: defer delivery to the ack poll
+                self._sinks_due = True
+            self._snapshot_commit(epoch_val)
 
     # -- uploader plumbing ----------------------------------------------
     def _ensure_uploader(self):
@@ -150,23 +350,43 @@ class CheckpointPipelineMixin:
                 self._uploader.drain(raise_error=raise_error)
                 self._process_upload_acks()
 
-    def _deliver_all_sinks(self, epoch_val) -> None:
-        """Subclass hook: drain sink ring buffers at ``epoch_val``."""
+    def _drop_shadow(self) -> None:
+        """Forget the shadow — and the store's digest chain — of a
+        state tree that changed shape (or of a job that gained/lost a
+        durable store): in-flight uploads drain first, the next
+        snapshot re-bases from scratch."""
+        if self._shadow is None:
+            return
+        if self._uploader is not None:
+            self._uploader.drain()
+            self._process_upload_acks()
+        if self.checkpoint_store is not None:
+            self.checkpoint_store.invalidate(self.ckpt_key)
+        self._shadow = None
 
-    def _shadow_shard_rows(self) -> int | None:
-        """Subclass hook: leading per-shard axis length of every state
-        leaf (mesh-stacked trees digest in per-shard lanes), None for
-        linear trees."""
-        return None
+    def reseed_checkpoint(self) -> None:
+        """Re-snapshot after a change of the state tree's shape
+        (topology edit, rescale): retained checkpoints hold the OLD
+        shape, so a recover() between the change and the next commit
+        would restore a structurally incompatible tree.  Callers invoke
+        this once the change (and any backfill) is complete."""
+        self._snapshot_commit(self.committed_epoch)
 
-    # -- the shared snapshot-commit tail ---------------------------------
-    def _snapshot_commit(self, epoch_val: int, src_state: dict,
-                         spill_host: dict, spill_items: list) -> None:
+    # -- the snapshot-commit tail ----------------------------------------
+    def _snapshot_commit(self, epoch_val: int) -> None:
         """Seal one epoch: shadow update (one async dispatch) +
         uploader enqueue (or, with no store, the in-memory commit)."""
         from risingwave_tpu.storage.digest import DEFAULT_BLOCK_ELEMS
         from risingwave_tpu.stream.shadow import ShadowSnapshot
 
+        src_state = self._source_state()
+        # ONE host materialization per tier, shared by the in-memory
+        # snapshot and the durable save
+        spill_host, spill_items = {}, []
+        for key, store_key, tier in self._iter_spill_tiers():
+            if tier.rows_absorbed:
+                spill_host[key] = tier.snapshot()
+                spill_items.append((store_key, spill_host[key]))
         store = self.checkpoint_store
         up = self._ensure_uploader()
         if up is not None:
@@ -178,16 +398,7 @@ class CheckpointPipelineMixin:
         if self._shadow is not None and (
                 not self._shadow.matches(self.states)
                 or self._shadow.digest_mode != (store is not None)):
-            # topology changed (or the job gained/lost a durable
-            # store): the shadow — and the store's digest chain —
-            # describe the OLD configuration; drain in-flight uploads,
-            # then rebuild from scratch (full re-base)
-            if up is not None:
-                up.drain()
-                self._process_upload_acks()
-            if store is not None:
-                store.invalidate(self.ckpt_key)
-            self._shadow = None
+            self._drop_shadow()
         if self._shadow is not None and up is not None:
             # the update donates the shadow buffers in-flight fetches
             # still read — wait for the fetch point only
@@ -209,8 +420,7 @@ class CheckpointPipelineMixin:
                 digests = self._shadow.update(self.states, epoch_val)
         self.sealed_epoch = epoch_val
         self.checkpoints = [CheckpointSnapshot(
-            epoch=epoch_val, states=None, source_state=src_state,
-            spill=spill_host,
+            epoch=epoch_val, source_state=src_state, spill=spill_host,
         )]
         if store is not None:
             from risingwave_tpu.stream.checkpoint import UploadTask
@@ -225,12 +435,60 @@ class CheckpointPipelineMixin:
         else:
             self.committed_epoch = epoch_val
 
-    def _restore_in_memory(self, snap: CheckpointSnapshot):
-        """States tree for an in-memory recover: from the shadow when
-        the snapshot is shadow-backed, else the retained full copy."""
-        if snap.states is None:
-            return self._shadow.restore()
-        return _snapshot_copy(snap.states)
+    # -- recovery -------------------------------------------------------
+    def recover(self, epoch: int | None = None) -> None:
+        """Reset to the last committed checkpoint (ref §3.5 recovery:
+        rebuild actors + resume from last committed epoch).  Drains the
+        upload queue first (sealed epochs finish becoming durable, a
+        failed upload is swallowed — the rewind IS its resolution),
+        then prefers the durable store (survives process restarts) over
+        the in-memory shadow.  ``epoch`` pins the rewind to a specific
+        retained checkpoint (the scale plane rewinds survivors to the
+        handover round before transplanting moved-vnode slices);
+        checkpoints live under ``ckpt_key`` — a partition's lineage,
+        not the job name."""
+        self._counters = None
+        if self._uploader is not None:
+            self._uploader.drain(raise_error=False)
+            self._process_upload_acks()
+            self._uploader.clear_error()
+            self._sinks_due = False
+        store = self.checkpoint_store
+        if store is not None:
+            # any rewind invalidates the store's in-memory digest
+            # cache: the next save must re-base with a full snapshot,
+            # or a delta computed against post-rewind live state could
+            # overwrite a valid chain entry with a wrong-base delta
+            # (invalidate also vacuums orphan files a crashed upload
+            # left between object write and manifest commit)
+            store.invalidate(self.ckpt_key)
+            loaded = store.load(self.ckpt_key, epoch)
+            if loaded is not None:
+                epoch_v, states, src_state = loaded
+                self.states = self._place(states)
+                self.committed_epoch = epoch_v
+                self.sealed_epoch = epoch_v
+                self._restore_sources(src_state)
+                for _, store_key, tier in self._iter_spill_tiers():
+                    store.invalidate(store_key)
+                    rewind_spill_tier(store, store_key, epoch_v, tier)
+                return
+        if not self.checkpoints:
+            self.states = self._init_states()
+            self._reset_sources()
+            for _, _, tier in self._iter_spill_tiers():
+                tier.reset()
+            return
+        snap = self.checkpoints[-1]
+        # the shadow's restore is a copy: the next step donates its
+        # input buffers, which must not invalidate the shadow itself
+        self.states = self._place(self._shadow.restore())
+        self._restore_sources(snap.source_state)
+        for key, _, tier in self._iter_spill_tiers():
+            if snap.spill and key in snap.spill:
+                tier.restore(snap.spill[key])
+            else:
+                tier.reset()
 
 
 def check_counter_values(name: str, labels: list[str],
@@ -290,17 +548,6 @@ def check_counter_values(name: str, labels: list[str],
     return residual
 
 
-def check_state_counters(name: str, st) -> None:
-    """Eager single-state check (test/debug surface; one readback per
-    counter — not for the steady-state loop)."""
-    for attr in ("inconsistency", "overflow"):
-        if hasattr(st, attr) and int(getattr(st, attr)) > 0:
-            check_counter_values(
-                name, [f"state.{attr}"],
-                np.asarray([int(getattr(st, attr))]),
-            )
-
-
 def restore_source(source, state: dict) -> None:
     """Restore a source from its checkpointed state() dict.
 
@@ -318,9 +565,8 @@ def rewind_spill_tier(store, key: str, epoch: int, tier) -> None:
     save and the job save leaves the tier one epoch ahead); when no
     eligible checkpoint exists the tier postdates every commit and must
     RESET — keeping its live state would double-count the replayed
-    rows.  Shared by StreamingJob and DagJob."""
-    cands = [e for e in store.epochs(key) if e <= epoch] \
-        if store is not None else []
+    rows."""
+    cands = [e for e in store.epochs(key) if e <= epoch]
     loaded = store.load(key, cands[-1]) if cands else None
     if loaded is not None:
         tier.restore(loaded[1])
@@ -339,7 +585,7 @@ def deliver_sinks(fragment: Fragment, states, epoch_val):
     return tuple(states)
 
 
-class StreamingJob(CheckpointPipelineMixin):
+class StreamingJob(BarrierLoop):
     """A linear source → fragment pipeline driven by the barrier loop.
 
     The fragment typically ends in a Materialize executor (the MV).
@@ -354,39 +600,10 @@ class StreamingJob(CheckpointPipelineMixin):
         checkpoint_frequency: int = 1,
         checkpoint_store=None,
     ):
+        super().__init__(name, checkpoint_frequency, checkpoint_store)
         self.source = source
         self.fragment = fragment
-        self.name = name
-        self.checkpoint_frequency = checkpoint_frequency
-        #: optional durable store (storage.CheckpointStore); when set,
-        #: commits persist across process restarts
-        self.checkpoint_store = checkpoint_store
-        #: checkpoints between maintenance passes (amortizes the ONE
-        #: counters readback + rehash program)
-        self.maintenance_interval = 1
-        self._ckpts_since_maintain = 0
-        #: checkpoints between in-memory snapshot copies
-        self.snapshot_interval = 1
-        self._ckpts_since_snapshot = 0
-        #: storage-service backpressure (the Hummock write-limit
-        #: contract): when set, every barrier crossing first calls
-        #: this hook, which blocks while the storage L0 is deeper than
-        #: its stall threshold — ingest yields to the compactor
-        #: instead of burying it.  Returns seconds stalled.
-        self.write_stall_hook = None
-        #: cumulative seconds this job spent write-stalled
-        self.stall_seconds = 0.0
-        self.states = fragment.init_states()
-        self.epoch = EpochPair.first()
-        self.barriers_seen = 0
-        self.checkpoints: list[CheckpointSnapshot] = []
-        #: committed epoch visible to batch reads (ref pinned snapshots)
-        self.committed_epoch: int = 0
-        self._init_pipeline()
-        self.paused = False
-        #: counters vector from the last barrier program (device array;
-        #: read back once per maintenance interval)
-        self._counters = None
+        self.states = self._init_states()
         #: spill-to-host tiers (stream/spill.py) per spill-enabled agg:
         #: [(exec_idx, drain_jit, inject_jit, tier)]
         self._spill: list = []
@@ -451,10 +668,7 @@ class StreamingJob(CheckpointPipelineMixin):
         if self.paused or n <= 0:
             return 0
         if self._fused is None or n == 1:
-            rows = 0
-            for _ in range(n):
-                rows += self.run_chunk()
-            return rows
+            return super().run_chunks(n)
         prog = self._multi_prog(n)
         k0 = jnp.int64(self.source.next_base())
         # the cursor already advanced one block; skip the other n-1
@@ -487,80 +701,22 @@ class StreamingJob(CheckpointPipelineMixin):
             self._fused_multi[n] = prog
         return prog
 
-    def inject_barrier(self, barrier: Barrier | None = None) -> list:
-        """Cross a barrier: one async dispatch (flush + drain +
-        watermarks + counters), then maintenance / checkpoint on their
-        cadences.
+    # -- BarrierLoop hooks ------------------------------------------------
+    def _init_states(self):
+        return self.fragment.init_states()
 
-        Returns the chunks emitted by the first flush pass (they have
-        already flowed through the downstream executors inside the
-        fragment — e.g. into a trailing Materialize — so callers
-        usually ignore them).
-        """
-        if barrier is None:
-            self.barriers_seen += 1
-            kind = (
-                BarrierKind.CHECKPOINT
-                if self.barriers_seen % self.checkpoint_frequency == 0
-                else BarrierKind.BARRIER
-            )
-            # the barrier SEALS the epoch data has been flowing in
-            # (epoch.curr) and opens the next one (ref EpochPair)
-            barrier = Barrier(
-                EpochPair(self.epoch.curr.next(), self.epoch.curr), kind
-            )
-        if barrier.mutation is not None:
-            self._apply_mutation(barrier.mutation)
-        if self.write_stall_hook is not None:
-            # the barrier loop is the ingest clock: stalling HERE (not
-            # per chunk) applies backpressure at epoch granularity
-            # without touching the fused steady-state dispatch
-            self.stall_seconds += self.write_stall_hook()
+    @property
+    def counter_labels(self) -> list[str]:
+        return self.fragment.counter_labels
 
-        epoch_val = barrier.epoch.prev.value
-        with GLOBAL_TRACE.span("inject_barrier.dispatch", job=self.name):
-            self.states, outs, self._counters = self.fragment.barrier(
-                self.states, epoch_val
-            )
-        if barrier.is_checkpoint:
-            self._ckpts_since_maintain += 1
-            if self._ckpts_since_maintain >= self.maintenance_interval:
-                self._maintain(epoch_val)
-                self._ckpts_since_maintain = 0
-            self._commit_checkpoint(barrier)
-        # cheap ack poll keeps committed_epoch (and deferred sink
-        # delivery) advancing while uploads complete in the background
-        self._process_upload_acks()
-        self.epoch = barrier.epoch
+    def _cross_barrier(self, epoch_val):
+        self.states, outs, self._counters = self.fragment.barrier(
+            self.states, epoch_val
+        )
         return outs
 
-    def _maintain(self, epoch_val) -> None:
-        """Rehash (on device) + the single counters readback."""
-        with GLOBAL_TRACE.span("_maintain", job=self.name):
-            self.states = self.fragment.maintain(self.states)
-            if self._counters is None:
-                return
-            # THE one device sync: the host blocked on the chip until
-            # the window, barrier and maintain programs have run
-            with GLOBAL_TRACE.span("_maintain.device_wait",
-                                   job=self.name):
-                values = np.asarray(self._counters)
-            residual = check_counter_values(
-                self.name, self.fragment.counter_labels, values,
-                self.metrics,
-            )
-            # residual pending beyond MAX_DRAIN_ROUNDS×emit_capacity
-            # per barrier: pathological; finish draining with host loops
-            for _ in range(64):
-                if not residual:
-                    break
-                self.states, _, self._counters = self.fragment.barrier(
-                    self.states, epoch_val
-                )
-                residual = check_counter_values(
-                    self.name, self.fragment.counter_labels,
-                    np.asarray(self._counters), self.metrics,
-                )
+    def _run_maintain(self) -> None:
+        self.states = self.fragment.maintain(self.states)
 
     def _drain_impl(self, states, i, ex):
         new_states = list(states)
@@ -583,10 +739,8 @@ class StreamingJob(CheckpointPipelineMixin):
         """Snapshot-barrier hook: divert ring rows to the host tier and
         inject its changelog downstream (ref: state beyond memory via
         the state-store tier, state_table.rs:187)."""
-        import numpy as _np
         for i, drain, inject, tier in self._spill:
-            cnt = int(_np.asarray(self.states[i].spill_count))
-            if cnt == 0:
+            if int(np.asarray(self.states[i].spill_count)) == 0:
                 continue
             self.states, chunk = drain(self.states)
             host_chunk = jax.device_get(chunk)
@@ -597,126 +751,6 @@ class StreamingJob(CheckpointPipelineMixin):
     def _deliver_all_sinks(self, epoch_val) -> None:
         self.states = deliver_sinks(self.fragment, self.states, epoch_val)
 
-    def _commit_checkpoint(self, barrier: Barrier) -> None:
-        """Seal one snapshot epoch: spill drain + sink delivery + the
-        incremental shadow update, then hand durable persistence to the
-        background uploader.  Recovery rewinds to the last DURABLE
-        epoch, so ``committed_epoch`` (and deferred sink delivery)
-        advance only on uploader ack; without a store, seal == commit
-        (the shadow is the recovery point)."""
-        epoch_val = barrier.epoch.prev.value
-        self._ckpts_since_snapshot += 1
-        if self._ckpts_since_snapshot < self.snapshot_interval:
-            return
-        self._ckpts_since_snapshot = 0
-        with GLOBAL_TRACE.span("_commit_checkpoint", job=self.name,
-                               epoch=epoch_val):
-            self._drain_spill_tiers(epoch_val)
-            up = self._ensure_uploader()
-            if up is None or up.pending() == 0:
-                # at-least-once delivery, same window as the
-                # synchronous path (rows delivered before their epoch
-                # is durable ride THIS epoch's snapshot via the
-                # advanced read_cursor)
-                with GLOBAL_TRACE.span("_commit_checkpoint.sinks",
-                                       job=self.name):
-                    self.states = deliver_sinks(
-                        self.fragment, self.states, epoch_val
-                    )
-            else:
-                # uploader behind: defer delivery to the ack poll
-                self._sinks_due = True
-            src_state = self.source.state() \
-                if hasattr(self.source, "state") else {}
-            # ONE host materialization per tier, shared by the
-            # in-memory snapshot and the durable save
-            spill_host = {i: tier.snapshot()
-                          for i, _, _, tier in self._spill
-                          if tier.rows_absorbed}
-            spill_items = [(f"{self.ckpt_key}@spill{i}", spill_host[i])
-                           for i in spill_host]
-            self._snapshot_commit(epoch_val, src_state, spill_host,
-                                  spill_items)
-
-    def _apply_mutation(self, mutation) -> None:
-        if mutation.kind == "pause":
-            self.paused = True
-        elif mutation.kind == "resume":
-            self.paused = False
-        elif mutation.kind == "stop":
-            self.paused = True
-
-    # -- recovery -------------------------------------------------------
-    def recover(self, epoch: int | None = None) -> None:
-        """Reset to the last committed checkpoint (ref §3.5 recovery:
-        rebuild actors + resume from last committed epoch).  Drains the
-        upload queue first (sealed epochs finish becoming durable, a
-        failed upload is swallowed — the rewind IS its resolution),
-        then prefers the durable store (survives process restarts) over
-        the in-memory shadow.  ``epoch`` pins the rewind to a specific
-        retained checkpoint (the scale plane rewinds survivors to the
-        handover round before transplanting moved-vnode slices)."""
-        self._counters = None
-        if self._uploader is not None:
-            self._uploader.drain(raise_error=False)
-            self._process_upload_acks()
-            self._uploader.clear_error()
-            self._sinks_due = False
-        if self.checkpoint_store is not None:
-            # any rewind invalidates the store's in-memory digest
-            # cache: the next save must re-base with a full snapshot,
-            # or a delta computed against post-rewind live state could
-            # overwrite a valid chain entry with a wrong-base delta
-            # (invalidate also vacuums orphan files a crashed upload
-            # left between object write and manifest commit)
-            self.checkpoint_store.invalidate(self.ckpt_key)
-            loaded = self.checkpoint_store.load(self.ckpt_key, epoch)
-            if loaded is not None:
-                epoch_v, states, src_state = loaded
-                self.states = jax.device_put(states)
-                self.committed_epoch = epoch_v
-                self.sealed_epoch = epoch_v
-                restore_source(self.source, src_state)
-                for i, _, _, tier in self._spill:
-                    key = f"{self.ckpt_key}@spill{i}"
-                    self.checkpoint_store.invalidate(key)
-                    rewind_spill_tier(
-                        self.checkpoint_store, key, epoch_v, tier
-                    )
-                return
-        if not self.checkpoints:
-            self.states = self.fragment.init_states()
-            if hasattr(self.source, "offset"):
-                self.source.offset = 0
-            for _, _, _, tier in self._spill:
-                tier.reset()
-            return
-        snap = self.checkpoints[-1]
-        # copy: the next step donates its input buffers, which must not
-        # invalidate the retained snapshot (shadow-backed snapshots
-        # restore from the shadow tree — the shadow itself survives)
-        self.states = self._restore_in_memory(snap)
-        restore_source(self.source, snap.source_state)
+    def _iter_spill_tiers(self):
         for i, _, _, tier in self._spill:
-            if snap.spill and i in snap.spill:
-                tier.restore(snap.spill[i])
-            else:
-                tier.reset()
-
-    # ------------------------------------------------------------------
-    def chunk_round(self) -> int:
-        """Uniform driving interface shared with DagJob (one scheduling
-        round = one chunk for a single-source linear job)."""
-        return self.run_chunk()
-
-    def run(self, barriers: int, chunks_per_barrier: int) -> None:
-        """The steady-state loop (ref §3.3).  Uploads pipeline within
-        the batch; the batch boundary drains them (durability point)."""
-        for _ in range(barriers):
-            for _ in range(chunks_per_barrier):
-                self.run_chunk()
-            self.inject_barrier()
-        self.drain_uploads()
-
-    def executor_state(self, idx: int):
-        return self.states[idx]
+            yield i, f"{self.ckpt_key}@spill{i}", tier

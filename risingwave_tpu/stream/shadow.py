@@ -3,9 +3,9 @@
 Reference counterpart: Hummock never re-uploads a full state snapshot
 per epoch — ``commit_epoch`` persists only each epoch's dirty deltas
 (docs/dev/src/design/checkpoint.md).  The old in-memory snapshot here
-(``_snapshot_copy``) was the opposite: a full device tree copy every
-snapshot barrier, a periodic multi-second stall that PERF_ATTRIBUTION
-round 6 measured at roughly HALF the q8 window.
+was the opposite: a full device tree copy every snapshot barrier, a
+periodic multi-second stall that PERF_ATTRIBUTION round 6 measured at
+roughly HALF the q8 window.
 
 TPU-first incremental design: the snapshot is a persistent device-side
 SHADOW of the state tree plus its block-digest vector.  One jitted
@@ -33,8 +33,7 @@ update still converges, because every differing block is by definition
 dirty under the diff.
 
 Programs are cached process-wide by (state signature, block size) —
-tests and restarted jobs with identical tree shapes reuse compiles,
-like the global ``_snapshot_copy`` jit cache they replace.
+tests and restarted jobs with identical tree shapes reuse compiles.
 
 Collision caveat: a 64-bit block digest collision would silently skip
 a changed block.  The durable delta store has always accepted this

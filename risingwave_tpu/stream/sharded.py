@@ -25,7 +25,13 @@ from risingwave_tpu.parallel.exchange import axis_min, shard_map_nocheck
 from risingwave_tpu.common.chunk import Chunk
 from risingwave_tpu.parallel.exchange import shuffle_chunk
 from risingwave_tpu.stream.executor import Executor
-from risingwave_tpu.stream.fragment import WM_NONE, WM_SAFE_FLOOR, Fragment
+from risingwave_tpu.stream.fragment import (
+    WM_NONE,
+    WM_SAFE_FLOOR,
+    Fragment,
+    collect_counters,
+)
+from risingwave_tpu.stream.runtime import BarrierLoop
 
 
 def make_mesh(n_devices: int | None = None, axis: str = "shard") -> Mesh:
@@ -85,6 +91,22 @@ class ShardedJob:
                 out_specs=(spec, spec),
             )
         )
+        self._counters_prog = jax.jit(self._shard_summed_counters)
+        #: labels aligned with the counters vector; set on first trace
+        self.counter_labels: list[str] = []
+
+    def on_mesh(self, mesh: Mesh) -> "ShardedJob":
+        """The same executor descriptors over another mesh."""
+        return ShardedJob(
+            mesh,
+            source_fn=self.source_fn,
+            chunk_capacity=self.cap,
+            local_executors=list(
+                self.local_frag.executors if self.local_frag else []
+            ),
+            exchange_key_fn=self.exchange_key_fn,
+            keyed_executors=list(self.keyed_frag.executors),
+        )
 
     # ------------------------------------------------------------------
     def init_states(self):
@@ -92,9 +114,13 @@ class ShardedJob:
         def one_shard(_):
             return tuple(ex.init_state() for ex in self.executors)
 
-        stacked = jax.vmap(one_shard)(jnp.arange(self.n_shards))
-        sharding = jax.NamedSharding(self.mesh, P(self.AXIS))
-        return jax.device_put(stacked, sharding)
+        return self.place(jax.vmap(one_shard)(jnp.arange(self.n_shards)))
+
+    def place(self, stacked):
+        """A ``[n_shards, ...]`` tree, one leading row on each shard."""
+        return jax.device_put(
+            stacked, jax.NamedSharding(self.mesh, P(self.AXIS))
+        )
 
     # -- traced per-shard bodies ----------------------------------------
     def _split(self, states):
@@ -216,7 +242,22 @@ class ShardedJob:
                 keys[j] = ex2.on_watermark(keys[j], wm)
         return tuple(locs), tuple(keys)
 
+    def _shard_summed_counters(self, states):
+        """``fragment.collect_counters`` of every shard, summed over
+        the shard axis: ONE device vector, read back once per
+        maintenance interval."""
+        def one_shard(shard_states):
+            self.counter_labels, vec = collect_counters(
+                self.executors, shard_states
+            )
+            return vec
+
+        return jnp.sum(jax.vmap(one_shard)(states), axis=0)
+
     # -- host API --------------------------------------------------------
+    def counters(self, states):
+        return self._counters_prog(states)
+
     def step(self, states, k0_per_shard: jnp.ndarray):
         """One chunk per shard; ``k0_per_shard`` int64 [n_shards]."""
         return self._step(states, k0_per_shard)
@@ -250,43 +291,24 @@ class ShardedJob:
         return states, all_outs
 
 
-class ShardedStreamingJob:
-    """StreamingJob-shaped adapter over a ShardedJob.
+class ShardedStreamingJob(BarrierLoop):
+    """A ShardedJob's programs under the barrier loop.
 
-    Lets the engine drive vnode-sharded MVs with the same barrier-loop
-    interface as linear jobs (ref: the reference's adaptive parallelism
-    — N actors per fragment — behind one scheduling surface).
+    Lets the engine drive vnode-sharded MVs like linear jobs (ref: the
+    reference's adaptive parallelism — N actors per fragment — behind
+    one scheduling surface).  Checkpoints ride the shadow + uploader
+    pipeline with one digest lane per shard.
 
     Round-1 scope: traceable sources, no watermark-driven cleaning in
     the sharded path (planner gates eligibility).
     """
 
-    #: optional MetricsRegistry (the engine attaches its own)
-    metrics = None
-
-    def __init__(self, sharded: ShardedJob, reader, name: str,
+    def __init__(self, sharded: ShardedJob, source, name: str,
                  checkpoint_frequency: int = 1, checkpoint_store=None):
-        from risingwave_tpu.common.epoch import EpochPair
-
+        super().__init__(name, checkpoint_frequency, checkpoint_store)
         self.sharded = sharded
-        self.reader = reader
-        self.name = name
-        self.checkpoint_frequency = checkpoint_frequency
-        self.checkpoint_store = checkpoint_store
-        self.maintenance_interval = 1
-        self._ckpts_since_maintain = 0
-        self.snapshot_interval = 1
-        self._ckpts_since_snapshot = 0
-        self.states = sharded.init_states()
-        self.epoch = EpochPair.first()
-        self.barriers_seen = 0
-        self.committed_epoch = 0
-        self.paused = False
-        self._mem_snapshot = None
-
-    def chunk_round(self) -> int:
-        """Uniform driving interface shared with DagJob."""
-        return self.run_chunk()
+        self.source = source
+        self.states = self._init_states()
 
     def run_chunk(self) -> int:
         if self.paused:
@@ -295,138 +317,44 @@ class ShardedStreamingJob:
         # next_base() owns split→global ordinal mapping; one cap-stride
         # block per shard
         k0 = jnp.asarray(
-            [self.reader.next_base() for _ in range(n)], jnp.int64
+            [self.source.next_base() for _ in range(n)], jnp.int64
         )
         self.states = self.sharded.step(self.states, k0)
         return n * cap
 
-    def _gather_counters(self, states):
-        """All shard-summed error counters + residual pending as ONE
-        device vector (read back once per maintenance interval)."""
-        from risingwave_tpu.stream.fragment import (
-            COUNTER_ATTRS,
-            GAUGE_ATTRS,
-            TALLY_ATTRS,
-        )
+    # -- BarrierLoop hooks ------------------------------------------------
+    def _init_states(self):
+        return self.sharded.init_states()
 
-        labels: list[str] = []
-        vals: list[jnp.ndarray] = []
-        for i, ex in enumerate(self.sharded.executors):
-            st = states[i]
-            for counter in COUNTER_ATTRS + TALLY_ATTRS + GAUGE_ATTRS:
-                if hasattr(st, counter):
-                    labels.append(f"{ex}.{counter}")
-                    vals.append(
-                        jnp.sum(getattr(st, counter)).astype(jnp.int64)
-                    )
-            if hasattr(ex, "pending_flush"):
-                # pending_flush maps over the [n_shards] leading axis
-                labels.append(f"{ex}.pending")
-                vals.append(jnp.sum(jax.vmap(ex.pending_flush)(st))
-                            .astype(jnp.int64))
-        self._counter_labels = labels
-        return jnp.stack(vals) if vals else jnp.zeros((0,), jnp.int64)
+    @property
+    def counter_labels(self) -> list[str]:
+        return self.sharded.counter_labels
 
-    def inject_barrier(self, barrier=None) -> None:
-        from risingwave_tpu.stream.runtime import (
-            _snapshot_copy,
-            check_counter_values,
-        )
-
-        self.barriers_seen += 1
-        sealed = self.epoch.curr.value
+    def _cross_barrier(self, epoch_val) -> None:
         # flush drains on device inside the shard_map body — the host
         # never reads pending counts
-        self.states, _ = self.sharded.flush(self.states, sealed)
-        if self.barriers_seen % self.checkpoint_frequency == 0:
-            self._ckpts_since_maintain += 1
-            if self._ckpts_since_maintain >= self.maintenance_interval:
-                values = jax.device_get(
-                    self._gather_counters(self.states)
-                )  # THE one device sync
-                residual = check_counter_values(
-                    self.name, self._counter_labels, values,
-                    self.metrics,
-                )
-                # pathological pending beyond the device drain bound:
-                # finish with host-looped flushes before committing
-                for _ in range(64):
-                    if not residual:
-                        break
-                    self.states, _ = self.sharded.flush(self.states, sealed)
-                    residual = check_counter_values(
-                        self.name, self._counter_labels,
-                        jax.device_get(self._gather_counters(self.states)),
-                        self.metrics,
-                    )
-                self._ckpts_since_maintain = 0
-            self._ckpts_since_snapshot += 1
-            if self._ckpts_since_snapshot >= self.snapshot_interval:
-                self._ckpts_since_snapshot = 0
-                self._deliver_sinks(sealed)
-                snap_states = _snapshot_copy(self.states)
-                self._mem_snapshot = (
-                    sealed, snap_states, {"offset": self.reader.offset}
-                )
-                self.committed_epoch = sealed
-                if self.checkpoint_store is not None:
-                    self.checkpoint_store.save(
-                        self.name, sealed, jax.device_get(snap_states),
-                        {"offset": self.reader.offset},
-                    )
-        self.epoch = self.epoch.bump()
+        self.states, _ = self.sharded.flush(self.states, epoch_val)
+        self._counters = self.sharded.counters(self.states)
 
-    def recover(self) -> None:
-        if self.checkpoint_store is not None:
-            loaded = self.checkpoint_store.load(self.name)
-            if loaded is not None:
-                epoch, states, src = loaded
-                # an online rescale may have committed a DIFFERENT
-                # parallelism than the DDL replanned: rebuild the mesh
-                # to the checkpoint's shard dim (state is authoritative
-                # — silently truncating shards would drop groups)
-                n_ckpt = jax.tree.leaves(states)[0].shape[0]
-                if n_ckpt != self.sharded.n_shards:
-                    if n_ckpt > len(jax.devices()):
-                        raise RuntimeError(
-                            f"checkpoint has {n_ckpt} shards but only "
-                            f"{len(jax.devices())} devices are visible"
-                        )
-                    old = self.sharded
-                    self.sharded = ShardedJob(
-                        make_mesh(n_ckpt),
-                        source_fn=old.source_fn,
-                        chunk_capacity=old.cap,
-                        local_executors=list(
-                            old.local_frag.executors
-                            if old.local_frag else []
-                        ),
-                        exchange_key_fn=old.exchange_key_fn,
-                        keyed_executors=list(old.keyed_frag.executors),
-                    )
-                sharding = jax.NamedSharding(
-                    self.sharded.mesh, P(self.sharded.AXIS)
-                )
-                self.states = jax.device_put(states, sharding)
-                self.committed_epoch = epoch
-                from risingwave_tpu.stream.runtime import restore_source
-                restore_source(self.reader, src)
-                return
-        if self._mem_snapshot is not None:
-            import jax.numpy as _jnp
-            epoch, states, src = self._mem_snapshot
-            self.states = jax.tree.map(_jnp.copy, states)
-            self.committed_epoch = epoch
-            from risingwave_tpu.stream.runtime import restore_source
-            restore_source(self.reader, src)
-            return
-        # nothing committed yet: reset to initial state (mirrors
-        # StreamingJob.recover)
-        self.states = self.sharded.init_states()
-        if hasattr(self.reader, "offset"):
-            self.reader.offset = 0
+    def _shadow_shard_rows(self) -> int:
+        return self.sharded.n_shards
 
-    def _deliver_sinks(self, sealed: int) -> None:
+    def _place(self, states):
+        # an online rescale may have committed a DIFFERENT parallelism
+        # than the DDL replanned: rebuild the mesh to the tree's shard
+        # dim (state is authoritative — silently truncating shards
+        # would drop groups)
+        n = jax.tree.leaves(states)[0].shape[0]
+        if n != self.sharded.n_shards:
+            if n > len(jax.devices()):
+                raise RuntimeError(
+                    f"checkpoint has {n} shards but only "
+                    f"{len(jax.devices())} devices are visible"
+                )
+            self.sharded = self.sharded.on_mesh(make_mesh(n))
+        return self.sharded.place(states)
+
+    def _deliver_all_sinks(self, sealed: int) -> None:
         """Per-shard sink cursors, merged host-side at the snapshot
         barrier (ref sink.rs delivery; cross-shard row order is
         unspecified, matching the reference's per-parallelism sinks).
@@ -449,10 +377,8 @@ class ShardedStreamingJob:
                 # (the closed-epoch reader protocol, sinks.py)
                 host_shards.append(ex.deliver(st, sealed, commit=False))
             ex.sink.commit(sealed)
-            states[i] = jax.device_put(
-                jax.tree.map(lambda *xs: jnp.stack(xs), *host_shards),
-                jax.NamedSharding(self.sharded.mesh,
-                                  P(self.sharded.AXIS)),
+            states[i] = self.sharded.place(
+                jax.tree.map(lambda *xs: jnp.stack(xs), *host_shards)
             )
         self.states = tuple(states)
 
@@ -518,16 +444,7 @@ class ShardedStreamingJob:
                 )
 
         # 3. fresh job on the new mesh (same executor descriptors)
-        new = ShardedJob(
-            make_mesh(new_n),
-            source_fn=old.source_fn,
-            chunk_capacity=old.cap,
-            local_executors=list(
-                old.local_frag.executors if old.local_frag else []
-            ),
-            exchange_key_fn=old.exchange_key_fn,
-            keyed_executors=list(keyed),
-        )
+        new = old.on_mesh(make_mesh(new_n))
         states = jax.device_get(new.init_states())
 
         # 4. route extracted rows by the SAME vnode map onto new shards
@@ -568,13 +485,14 @@ class ShardedStreamingJob:
         restacked = jax.tree.map(
             lambda *xs: jnp.stack(xs), *per_shard
         )
-        sharding = jax.NamedSharding(new.mesh, P(new.AXIS))
         self.sharded = new
-        self.states = jax.device_put(restacked, sharding)
+        self.states = new.place(restacked)
         # 5. first flush re-emits every group into the fresh downstream
         # states (TopN bands, MV) before anything is served
         self.states, _ = self.sharded.flush(self.states, sealed)
-        self._mem_snapshot = None  # old-shape snapshots are invalid
+        # old-shape snapshots are invalid
+        self._drop_shadow()
+        self.checkpoints = []
 
     # serving: per-shard MV partitions merged host-side
     def mv_rows(self, mv_executor, state_index: int):

@@ -49,7 +49,6 @@ import jax.numpy as jnp  # noqa: E402
 
 from risingwave_tpu.sql import Engine  # noqa: E402
 from risingwave_tpu.sql.planner import PlannerConfig  # noqa: E402
-from risingwave_tpu.stream.runtime import _snapshot_copy  # noqa: E402
 
 CAP = 8192
 
@@ -490,7 +489,7 @@ def main():
     st_prep = job.states[prep_idx]
     _, chunk = gen_prep(st_prep, k0)
     timeit("gen + wm + tumble", lambda: gen_prep(st_prep, k0)[1])
-    jstate = _snapshot_copy(job.states[jidx])
+    jstate = jax.tree.map(jnp.copy, job.states[jidx])
     st2, pending = join_begin(jstate, chunk)
 
     def begin_threaded():

@@ -10,11 +10,7 @@ import pytest
 from risingwave_tpu.cluster import ComputeWorker, MetaService
 from risingwave_tpu.common.config import RwConfig
 from risingwave_tpu.serve import ServingWorker
-from risingwave_tpu.serve.worker import (
-    ResultCache,
-    ServeUnsupported,
-    plan_read,
-)
+from risingwave_tpu.serve.worker import ResultCache, plan_read
 
 
 def _cfg():
@@ -92,9 +88,13 @@ def test_plan_read_index_rewrite():
     assert p.hi is not None and p.hi > p.lo
     # pk predicates still take the point-get path, not the index
     assert plan("SELECT g FROM m WHERE g = 1").mode == "get"
-    # a pin OLDER than the index's first export must not use it
-    with pytest.raises(ServeUnsupported):
-        plan("SELECT g FROM m WHERE n = 42", at_epoch=3)
+    # a pin OLDER than the index's first export must not use it: the
+    # read is a filtered scan of the primary (the non-pk compare rides
+    # as a residual of the block-walk evaluator), never the index
+    p_old = plan("SELECT g FROM m WHERE n = 42", at_epoch=3)
+    assert p_old.mode == "scan" and not p_old.index_mv
+    assert p_old.lo.startswith(b"m:m\x00") and p_old.hi > p_old.lo
+    assert p_old.residual == [(1, "equal", 42)]
     # index RANGE scan (Exchange-lite round): WHERE n > x bounds the
     # index byte range — the memcomparable encoding already sorts
     p = plan("SELECT g FROM m WHERE n > 42")
@@ -109,10 +109,12 @@ def test_plan_read_index_rewrite():
     p3 = plan("SELECT g FROM m WHERE n = 42 AND g > 7")
     assert p3.mode == "index" and p3.index_mv == "m_n"
     assert (0, "greater_than", 7) in (p3.residual or [])
-    # no schema_of (no index discovery): old behavior preserved
+    # no schema_of (no index discovery): the same filtered scan
     (sel,) = parse("SELECT g FROM m WHERE n = 42")
-    with pytest.raises(ServeUnsupported):
-        plan_read(sel, prim)
+    p4 = plan_read(sel, prim)
+    assert p4.mode == "scan" and not p4.index_mv
+    assert (p4.lo, p4.hi, p4.residual) == \
+        (p_old.lo, p_old.hi, p_old.residual)
 
 
 # -- the in-process cluster smoke (tier-1 fast) --------------------------
@@ -281,12 +283,17 @@ def test_index_byte_identity_through_retraction_churn(tmp_path):
                 assert dead == []
             seen_s.add(s_live)
         # drop the index: the upstream doc stops advertising it, so
-        # the replica refuses (owner fallback) instead of answering
-        # from tombstoned index rows
+        # the replica answers with a filtered scan of the primary —
+        # the same rows, never the tombstoned index rows
         eng.execute("DROP INDEX am_s")
         sv.view.refresh(None)
-        with pytest.raises(ServeUnsupported):
-            sv.read(f"SELECT g, s FROM am WHERE s = {max(seen_s)}")
+        sql = f"SELECT g, s FROM am WHERE s = {max(seen_s)}"
+        assert sv._plan(sql).mode == "scan"
+        _, got, _ = sv.read(sql)
+        assert sorted(got) == scan
+        for s_dead in seen_s - {max(seen_s)}:
+            assert sv.read(
+                f"SELECT g, s FROM am WHERE s = {s_dead}")[1] == []
     finally:
         if started:
             sv.stop()

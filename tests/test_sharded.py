@@ -753,6 +753,7 @@ def test_sharded_sink_delivers_exactly_once_across_recovery():
     assert isinstance(job, ShardedStreamingJob), "sink job should shard"
     job.run_chunk()
     job.inject_barrier()
+    job.drain_uploads()  # the epoch is on disk before the "crash"
 
     # fold the delivered changelog: per-key latest insert wins
     def fold():
