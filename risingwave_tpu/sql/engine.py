@@ -48,6 +48,7 @@ from risingwave_tpu.sql.planner import (
 )
 from risingwave_tpu.storage.checkpoint_store import _mc_encode_value
 from risingwave_tpu.stream.dag import DagJob, FragNode, JoinNode
+from risingwave_tpu.stream.materialize import view_rows
 from risingwave_tpu.stream.runtime import StreamingJob
 
 
@@ -3804,34 +3805,23 @@ class Engine:
                     f"epoch {qe} is not retained for {entry.name} "
                     f"(retained: {epochs})"
                 )
-            _, states, _ = self.checkpoint_store.load(ckpt_name, qe)
-            st = states
-            for i in entry.mv_state_index:
-                st = st[i]
-            if getattr(entry.job, "mesh", None) is not None:
-                import jax as _jax
-                rows = []
-                for shard in range(entry.job.n_shards):
-                    rows.extend(entry.mv_executor.to_host(
-                        _jax.tree.map(lambda x: x[shard], st)
-                    ))
-                return rows
-            if vn_set is not None:
-                st = self._vnode_filtered_mv_state(st, vn_set, n_vn)
-            return entry.mv_executor.to_host(st)
-
-        idx = entry.mv_state_index
-        if isinstance(entry.job, ShardedStreamingJob):
-            return entry.job.mv_rows(entry.mv_executor, idx[0])
-        if getattr(entry.job, "mesh", None) is not None:
-            return entry.job.mv_rows(entry.mv_executor, idx)
-        state = entry.job.states
-        for i in idx:
+            _, state, _ = self.checkpoint_store.load(ckpt_name, qe)
+        else:
+            state = entry.job.states
+        for i in entry.mv_state_index:
             state = state[i]
-        if vn_set is not None:
+        # a mesh job's state is stacked, a shard a leading row
+        stacked = isinstance(entry.job, ShardedStreamingJob) \
+            or getattr(entry.job, "mesh", None) is not None
+        if vn_set is not None and not stacked:
             state = self._vnode_filtered_mv_state(state, vn_set, n_vn)
-        with GLOBAL_TRACE.span("_mv_rows.to_host"):
-            return entry.mv_executor.to_host(state)
+        with GLOBAL_TRACE.span("_mv_rows.to_host") as span:
+            rows, moved = view_rows(entry.mv_executor, state, stacked)
+            span.set(**moved)
+        if moved.get("bytes"):
+            self.metrics.inc("mv_read_bytes_total", moved["bytes"],
+                             job=entry.name, path=moved["path"])
+        return rows
 
     @staticmethod
     def _order_permutation(chunk, order_by, n_rows: int) -> list[int]:

@@ -1266,21 +1266,6 @@ class DagJob(BarrierLoop):
             )
             self.states = inject_p(self.states, self._place(stacked))
 
-    # -- serving (sharded) ----------------------------------------------
-    def mv_rows(self, mv_executor, state_index):
-        """Host view of a sharded MV: per-shard partitions merged (the
-        serving analog of ShardedStreamingJob.mv_rows)."""
-        st = self.states
-        for i in state_index:
-            st = st[i]
-        host = jax.device_get(st)  # one transfer
-        rows = []
-        for shard in range(self.n_shards):
-            rows.extend(mv_executor.to_host(
-                jax.tree.map(lambda x: x[shard], host)
-            ))
-        return rows
-
     # -- backfill -------------------------------------------------------
     def backfill_node(self, node_id: int, chunks, side: str | None = None,
                       ) -> None:

@@ -16,12 +16,14 @@ Two device-resident variants, chosen by the plan:
 - ``AppendOnlyMaterialize``: a ring buffer + cursor for pk-less /
   append-only MVs (e.g. Nexmark q1) — one dynamic-slice write per chunk.
 
-Snapshot serving reads (`to_host`) gather live slots at barrier time —
-the batch-side `BatchTable` scan of SURVEY §3.4, collapsed to a gather.
+Snapshot serving reads (`fetch` / `to_host`) gather the pk table's live
+blocks on the device at barrier time and move those — the batch-side
+`BatchTable` scan of SURVEY §3.4, collapsed to a gather.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Sequence
 
 import jax
@@ -40,6 +42,7 @@ from risingwave_tpu.common.chunk import (
     decode_strings,
     split_col,
 )
+from risingwave_tpu.common import compact
 from risingwave_tpu.common.compact import mask_indices
 from risingwave_tpu.common.types import Schema
 from risingwave_tpu.state.hash_table import HashTable
@@ -170,26 +173,120 @@ class MaterializeExecutor(Executor):
         )
 
     # -- serving (snapshot read) ----------------------------------------
-    def to_host(self, state: MvState) -> list[tuple]:
-        """Read the MV as python rows (batch serving path)."""
-        occ = np.asarray(state.table.occupied)
+    def fetch(self, state: MvState):
+        """The view's slots on the host: ``(occupied, values, moved)``,
+        ``occupied`` a flat bool vector and every leaf of ``values`` as
+        many leading rows, slots ascending.  A mesh view's stacked state
+        (one leading shard axis a leaf) comes shard after shard.  One
+        algorithm, its path chosen by what it observes:
+
+        - ``gathered``: the blocks that hold a row fit the program's
+          capacity (``_live_blocks``): those blocks alone cross, with
+          their count, in one ``device_get``;
+        - ``whole``: more live blocks than that: every leaf whole, in
+          one ``device_get`` more;
+        - ``host``: a state that holds a host array (a loaded
+          checkpoint) is cut where it is; no program is dispatched, and
+          a leaf that is on the device all the same crosses ``whole``.
+
+        ``moved`` is ``{"path", "bytes", "blocks"}``: the bytes that
+        crossed from the device, and the live blocks the program found."""
+        occ = state.table.occupied
+        leaves, treedef = jax.tree.flatten(state.values)
+        rests = [x.shape[occ.ndim:] for x in leaves]
+        lanes = occ.size // occ.shape[-1]
+        on_device = all(isinstance(x, jax.Array) for x in (occ, *leaves))
+        moved = {"path": "host", "bytes": 0, "blocks": 0}
+        host = None
+        # the program's window starts are int32 element offsets
+        if on_device and max(x.size for x in leaves) // lanes < 2 ** 31:
+            fn = _live_blocks_fn(occ.ndim == 2, compact.accel_tuned())
+            live, *windows = jax.device_get(fn(occ, leaves))
+            live = np.atleast_1d(live)
+            cap = windows[0].shape[-2]
+            moved = {"path": "gathered", "blocks": int(live.sum()),
+                     "bytes": live.nbytes + sum(w.nbytes for w in windows)}
+            if live.max() <= cap:
+                host = [
+                    np.concatenate([
+                        lane[:n] for lane, n in
+                        zip(w.reshape(lanes, cap, -1), live)
+                    ]) for w in windows
+                ]
+        if host is None:
+            host = jax.device_get([occ, *leaves])
+            crossed = sum(h.nbytes for x, h in zip((occ, *leaves), host)
+                          if isinstance(x, jax.Array))
+            if crossed:
+                moved["path"] = "whole"
+                moved["bytes"] += crossed
+        values = jax.tree.unflatten(treedef, [
+            h.reshape(-1, *rest) for h, rest in zip(host[1:], rests)
+        ])
+        return host[0].reshape(-1), values, moved
+
+    def rows(self, occ: np.ndarray, values) -> list[tuple]:
+        """The occupied slots of fetched columns as python rows."""
         cols = []
-        for f, store in zip(self.in_schema, state.values):
+        for f, store in zip(self.in_schema, values):
             store, null = split_col(store)
             if isinstance(store, StrCol):
-                out = decode_strings(
-                    np.asarray(store.data)[occ], np.asarray(store.lens)[occ]
-                )
+                out = decode_strings(store.data[occ], store.lens[occ])
             else:
-                arr = np.asarray(store)[occ]
+                arr = store[occ]
                 if f.data_type.value == "numeric":
                     arr = arr.astype(np.float64) / 10**f.decimal_scale
                 out = arr
             if null is not None:
-                out = apply_null_mask(out, np.asarray(null)[occ])
+                out = apply_null_mask(out, null[occ])
             cols.append(out)
         n = int(occ.sum())
         return [tuple(c[i] for c in cols) for i in range(n)]
+
+    def to_host(self, state: MvState) -> list[tuple]:
+        """Read the MV as python rows (batch serving path)."""
+        occ, values, _ = self.fetch(state)
+        return self.rows(occ, values)
+
+
+#: slots in a block of the read's gather
+READ_BLOCK = 512
+
+_WINDOWS = jax.lax.GatherDimensionNumbers(
+    offset_dims=(1,), collapsed_slice_dims=(), start_index_map=(0,),
+)
+
+
+def _live_blocks(occ, leaves):
+    """The blocks of one table that hold a row: their count, and the
+    first ``cap = max(1, blocks // 64)`` of them, ascending, as ``(cap,
+    block)`` windows of ``occ`` and of every leaf (a leaf's trailing
+    axes folded into its window).  Past the count the windows repeat
+    block 0.  Which blocks are live is computed here, from the table:
+    nothing about a view's contents is a static bound, so a view
+    compiles this once, at its first read."""
+    size = occ.shape[0]
+    block = min(READ_BLOCK, size)
+    live = jnp.any(occ.reshape(size // block, block), axis=1)
+    ids = mask_indices(live, max(1, live.shape[0] // 64), 0)
+
+    def windows(x):
+        w = block * (x.size // size)
+        return jax.lax.gather(
+            x.reshape(-1), (ids * w)[:, None], _WINDOWS, slice_sizes=(w,),
+            mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+        )
+
+    return (jnp.sum(live, dtype=jnp.int32), windows(occ),
+            *map(windows, leaves))
+
+
+@functools.cache
+def _live_blocks_fn(stacked: bool, chip: bool):
+    """``_live_blocks`` jitted, over the shard axis of a stacked state
+    where there is one.  ``chip`` is ``accel_tuned()``, a trace-time
+    branch of ``mask_indices``: a key, so that each has its trace."""
+    return jax.jit(jax.vmap(_live_blocks) if stacked else _live_blocks)
 
 
 class RingState(NamedTuple):
@@ -264,3 +361,23 @@ class AppendOnlyMaterialize(Executor):
                 out = apply_null_mask(out, np.asarray(null)[sel])
             cols.append(out)
         return [tuple(c[i] for c in cols) for i in range(n)]
+
+
+def view_rows(mv_executor, state, stacked: bool):
+    """A view's rows and what reading them moved (``fetch``'s
+    ``moved``).  ``stacked``: a mesh job's state, whose leaves carry one
+    leading shard axis; the rows come shard after shard.  The ring
+    reads as it always did and reports nothing."""
+    if isinstance(mv_executor, MaterializeExecutor):
+        occ, values, moved = mv_executor.fetch(state)
+        return mv_executor.rows(occ, values), moved
+    moved: dict = {}
+    if not stacked:
+        return mv_executor.to_host(state), moved
+    host = jax.device_get(state)  # one transfer
+    rows = []
+    for shard in range(jax.tree.leaves(host)[0].shape[0]):
+        rows.extend(mv_executor.to_host(
+            jax.tree.map(lambda x: x[shard], host)
+        ))
+    return rows, moved

@@ -493,12 +493,3 @@ class ShardedStreamingJob(BarrierLoop):
         # old-shape snapshots are invalid
         self._drop_shadow()
         self.checkpoints = []
-
-    # serving: per-shard MV partitions merged host-side
-    def mv_rows(self, mv_executor, state_index: int):
-        host = jax.device_get(self.states[state_index])  # one transfer
-        rows = []
-        for shard in range(self.sharded.n_shards):
-            st = jax.tree.map(lambda x: x[shard], host)
-            rows.extend(mv_executor.to_host(st))
-        return rows

@@ -260,3 +260,81 @@ def test_sigint_taken_by_another_thread_still_stops_the_node():
                          capture_output=True, text=True, timeout=60)
     assert out.returncode == 0, out.stderr[-2000:]
 
+
+
+def test_reads_between_ticks_gather_the_views_blocks():
+    """A served node with its ticker running: every read of a windowed
+    view, taken between two barriers, returns the reference's rows; the
+    readback's span says what it moved and ``/metrics`` counts it under
+    the view."""
+    import time
+
+    import numpy as np
+
+    from risingwave_tpu.common.trace import GLOBAL_TRACE
+    from risingwave_tpu.connector.nexmark import (
+        NexmarkConfig,
+        NexmarkGenerator,
+    )
+
+    slots = 1 << 19
+    n = SingleNode(PlannerConfig(
+        chunk_capacity=256, agg_table_size=256, agg_emit_capacity=64,
+        mv_table_size=slots, mv_ring_size=1024,
+    ))
+    n.engine.system_params.set("barrier_interval_ms", 20)
+    server = n.start(port=0)
+    reads = []
+    try:
+        c = MiniPgClient(*server.server_address)
+        c.query(
+            "CREATE SOURCE bid (auction BIGINT, price BIGINT, "
+            "date_time TIMESTAMP, "
+            "WATERMARK FOR date_time AS date_time - INTERVAL '4' SECOND) "
+            "WITH (connector='nexmark', nexmark.table='bid', "
+            "nexmark.event.rate='2000')")
+        c.query(
+            "CREATE MATERIALIZED VIEW v AS SELECT window_start, "
+            "max(price) AS hi, count(*) AS n "
+            "FROM TUMBLE(bid, date_time, INTERVAL '2' SECOND) "
+            "GROUP BY window_start")
+        GLOBAL_TRACE.clear()
+        deadline = time.time() + 60
+        while time.time() < deadline and (
+                len(reads) < 8 or len(reads[-1]) < 4):
+            _, rows = c.query(
+                "SELECT window_start, hi, n FROM v ORDER BY window_start")
+            reads.append([(int(hi), int(cnt)) for _, hi, cnt in rows])
+            time.sleep(0.03)
+        c.close()
+        text = n.render_metrics()
+    finally:
+        n.stop()
+        server.shutdown()
+    assert len(reads) >= 8 and len(reads[-1]) >= 4, reads[-1]
+    # the reference: bids come in event-time order, so every window but
+    # the newest of a read is complete
+    taken = int(n.engine.metrics.get("stream_rows_total", job="v"))
+    _, cols, _ = NexmarkGenerator(
+        NexmarkConfig(inter_event_us=500)).gen_bids(0, taken).to_host()
+    price, ts = np.asarray(cols[2]), np.asarray(cols[5])
+    starts = ts - ts % 2_000_000
+    want = [(int(price[starts == w].max()), int((starts == w).sum()))
+            for w in np.unique(starts)]
+    for got in reads:
+        assert got[:-1] == want[:len(got) - 1], (got, want)
+        if got:
+            hi, cnt = want[len(got) - 1]
+            assert got[-1][0] <= hi and got[-1][1] <= cnt
+    spans = [s["attrs"] for s in GLOBAL_TRACE.dump()
+             if s["name"] == "_mv_rows.to_host"]
+    assert len(spans) >= len(reads)
+    table_bytes = slots * (3 * 8 + 1)
+    for attrs in spans:
+        assert attrs["path"] == "gathered", attrs
+        assert 0 < attrs["blocks"] <= slots // 512 // 64
+        assert 0 < attrs["bytes"] < table_bytes / 32
+    line, = [ln for ln in text.splitlines()
+             if ln.startswith("mv_read_bytes_total{")]
+    assert 'job="v"' in line and 'path="gathered"' in line
+    assert float(line.rsplit(" ", 1)[1]) == sum(a["bytes"] for a in spans)

@@ -231,3 +231,37 @@ def test_string_ring_digest_fits(one_chip):
         x.reshape(-1), nb, DEFAULT_BLOCK_ELEMS))
     mem = _compile(prog, one_chip, jax.ShapeDtypeStruct(shape, jnp.uint8))
     assert mem.temp_size_in_bytes < 8 << 30
+
+
+@pytest.mark.parametrize("view", ["q7", "q5"])
+def test_view_read_gather(jobs, one_chip, view):
+    """The read's program (``materialize._live_blocks``) over the
+    view's table at the smoke's size: ``lax.top_k`` over the blocks and
+    one windowed gather a leaf."""
+    from risingwave_tpu.stream import materialize
+
+    job = jobs(view)
+    (at, mv), = [(i, ex) for i, ex in enumerate(job.fragment.executors)
+                 if isinstance(ex, materialize.MaterializeExecutor)]
+    state = job.states[at]
+    mem = _compile(materialize._live_blocks_fn(False, True), one_chip,
+                   state.table.occupied, jax.tree.leaves(state.values))
+    blocks = mv.table_size // materialize.READ_BLOCK
+    table = sum(x.nbytes for x in jax.tree.leaves(
+        (state.table.occupied, state.values)))
+    assert mem.output_size_in_bytes <= table * max(1, blocks // 64) \
+        // blocks + 4096
+
+
+def test_view_read_gather_stacked_strings(one_chip):
+    """A mesh view's stacked state with a string column: the same
+    program under ``vmap``, the string's bytes folded into its window."""
+    from risingwave_tpu.stream import materialize
+
+    size = 1 << 20
+    _compile(
+        materialize._live_blocks_fn(True, True), one_chip,
+        jax.ShapeDtypeStruct((4, size), jnp.bool_),
+        [jax.ShapeDtypeStruct((4, size), jnp.int64),
+         jax.ShapeDtypeStruct((4, size, 24), jnp.uint8),
+         jax.ShapeDtypeStruct((4, size), jnp.int32)])
