@@ -3209,8 +3209,8 @@ class Engine:
           calls in the compiled update path (the fused (hash, rank)
           probe keeps this at 1 per append-only side);
         - ``join_probe_iters_per_chunk``: device probe-loop trips;
-        - ``join_pool_occupancy``: bump-allocator fill of each pool
-          side (live cursor / capacity);
+        - ``join_pool_occupancy``: fill of each pool side's row ring
+          (live rows / capacity);
         - ``join_emit_window_fill_ratio``: staged emission rows over
           drained window capacity (small = oversized out_capacity);
         - ``join_drain_windows_per_chunk``: emission windows per probe
@@ -3268,7 +3268,7 @@ class Engine:
                         _jax.tree.map(lambda x: x[0], s.rows)
                     self.metrics.set_gauge(
                         "join_pool_occupancy",
-                        float(_np.asarray(s.pool_len).sum())
+                        float(_np.asarray(s.head - s.tail).sum())
                         / (_pool_capacity(rows0) * n_shards),
                         side=side_name, **labels,
                     )
@@ -3307,7 +3307,7 @@ class Engine:
                         else join.right_schema
                     keys = join.left_keys if side == "left" \
                         else join.right_keys
-                    clean = getattr(join, f"{side}_clean", None)
+                    clean = join.clean_rule(side)
                     proto = _empty_chunk(schema, 4)
                     sstate = getattr(job.states[idx], side)
                     if job.mesh is not None:

@@ -16,12 +16,18 @@ sharded runtime applies the hash exchange at the agg/join boundary.
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Any
 
 from risingwave_tpu.common.config import StateConfig
 from risingwave_tpu.common.types import Schema
-from risingwave_tpu.expr.node import Expr, FuncCall as EFuncCall, InputRef
+from risingwave_tpu.expr.node import (
+    Expr,
+    FuncCall as EFuncCall,
+    InputRef,
+    Literal as ELiteral,
+)
 from risingwave_tpu.meta.catalog import Catalog
 from risingwave_tpu.sql import ast
 from risingwave_tpu.expr.agg import AggCall
@@ -34,7 +40,7 @@ from risingwave_tpu.stream.executor import (
 )
 from risingwave_tpu.stream.fragment import Fragment
 from risingwave_tpu.stream.hash_agg import HashAggExecutor
-from risingwave_tpu.stream.hash_join import HashJoinExecutor
+from risingwave_tpu.stream.hash_join import HashJoinExecutor, JoinClean
 from risingwave_tpu.stream.materialize import (
     AppendOnlyMaterialize,
     MaterializeExecutor,
@@ -64,6 +70,16 @@ class PlannedInput:
     #: changelog (the reference's *stream key*) — required to key the
     #: materialization of retractable non-agg plans
     stream_key: "list[int] | None" = None
+    #: event-time columns that carry a watermark: column position ->
+    #: how far (us) its watermark trails the source's watermark filter
+    #: (the time column itself 0, ``window_end`` 0, ``window_start`` the
+    #: window size; a group key or a projected column keeps its
+    #: input's: a closed window cannot change, late rows are dropped at
+    #: the filter)
+    wm_lags: "dict[int, int] | None" = None
+    #: the filter's time column (its own schema's position): what the
+    #: runtime looks for upstream (``DagJob._upstream_wm``)
+    wm_src_col: "int | None" = None
 
 
 @dataclass
@@ -817,7 +833,14 @@ class Planner:
         return (group_pos, order_pos, spec)
 
     # -- FROM resolution ------------------------------------------------
-    def _resolve_input(self, from_) -> PlannedInput:
+    def _resolve_input(self, from_,
+                       read_cols: "set | None" = None) -> PlannedInput:
+        """One FROM item as a stream input.  ``read_cols`` (``(table,
+        name)`` pairs, ``_named_columns``) lets an append-only source
+        drop, ahead of everything else, the columns the statement never
+        names: upstream's column pruning.  A join keeps every row of
+        such a side in its state, so what the side does not carry it
+        does not store."""
         if isinstance(from_, ast.TableRef):
             entry = self.catalog.get(from_.name)
             if entry.kind == "mview":
@@ -837,21 +860,47 @@ class Planner:
             reader = entry.reader_factory()
             qual = from_.alias or from_.name
             execs: list[Executor] = []
+            schema = entry.schema
+            at = {i: i for i in range(len(schema))}
+            if read_cols is not None and entry.append_only:
+                keep = [
+                    i for i, f in enumerate(schema)
+                    if any(n == f.name and t in (None, qual, from_.name)
+                           for t, n in read_cols)
+                    or i in (entry.stream_key or ())
+                    or (entry.watermark is not None
+                        and i == entry.watermark[0])
+                ]
+                if keep and len(keep) < len(schema):
+                    execs.append(ProjectExecutor(
+                        schema, [(schema[i].name, InputRef(i))
+                                 for i in keep]))
+                    schema = execs[-1].out_schema
+                    at = {old: new for new, old in enumerate(keep)}
             wm_col = None
             if entry.watermark is not None:
                 col, delay = entry.watermark
+                wm_col = at[col]
                 execs.append(
-                    WatermarkFilterExecutor(entry.schema, col, delay)
+                    WatermarkFilterExecutor(schema, wm_col, delay)
                 )
-                wm_col = col
             return PlannedInput(
-                reader, execs, Scope.of(entry.schema, qual), entry.schema,
+                reader, execs, Scope.of(schema, qual), schema,
                 wm_col, None, entry.append_only,
-                stream_key=list(entry.stream_key)
+                stream_key=[at[k] for k in entry.stream_key]
                 if entry.stream_key else None,
+                wm_lags={wm_col: 0} if wm_col is not None else None,
+                wm_src_col=wm_col,
             )
         if isinstance(from_, (ast.Tumble, ast.Hop)):
-            inner = self._resolve_input(from_.table)
+            if read_cols is not None:
+                # the window's time column is named by a string
+                read_cols = read_cols | {(None, from_.time_col)}
+                if from_.alias:
+                    # an aliased window table re-qualifies its columns
+                    read_cols |= {(None, n) for t, n in read_cols
+                                  if t == from_.alias}
+            inner = self._resolve_input(from_.table, read_cols)
             ts_idx = inner.scope.resolve(from_.time_col, None)
             if isinstance(from_, ast.Tumble):
                 size = from_.size.micros
@@ -869,10 +918,18 @@ class Planner:
             scope = Scope(hop.out_schema, quals)
             # window_start is addressable by the window alias OR the
             # underlying table name (postgres-ish leniency)
+            wm_lags = None
+            if inner.wm_lags is not None \
+                    and inner.wm_lags.get(ts_idx) is not None:
+                lag = inner.wm_lags[ts_idx]
+                n_in = len(inner.schema)  # window_start, window_end follow
+                wm_lags = {**inner.wm_lags, n_in: lag + size,
+                           n_in + 1: lag}
             return PlannedInput(
                 inner.reader, inner.executors + [hop], scope,
                 hop.out_schema, inner.watermark_col, size,
                 inner.append_only, window_slide=slide,
+                wm_lags=wm_lags, wm_src_col=inner.wm_src_col,
             )
         raise PlanError(f"unsupported FROM clause {from_!r}")
 
@@ -1619,6 +1676,9 @@ class Planner:
         cfg = self.config
         sources: dict[str, Any] = {}
         nodes: list = []
+        #: catalog source -> the plan source that reads it
+        read_once: dict[str, str] = {}
+        read_cols = self._named_columns(select, group_topn)
 
         def reorder_cross(jn: ast.Join) -> ast.Join:
             """Greedy connectivity ordering of a comma-join chain: each
@@ -1687,17 +1747,28 @@ class Planner:
                 return resolve_join(from_)
             if isinstance(from_, ast.SubqueryRef):
                 return resolve_subquery(from_)
-            pin = self._resolve_input(from_)
+            pin = self._resolve_input(from_, read_cols)
+            table = from_
+            while not isinstance(table, ast.TableRef):
+                table = table.table
             if isinstance(from_, ast.TableRef):
                 base = from_.alias or from_.name
             else:
                 base = from_.alias or from_.table.name
-            name = base
-            i = 1
-            while name in sources:
-                name = f"{base}_{i}"
-                i += 1
-            sources[name] = pin.reader
+            # a source the job names twice is read ONCE: its chunk fans
+            # out to every consumer (left side first), so an event is
+            # generated and counted once
+            name = None if isinstance(pin.reader, MvTap) \
+                else read_once.get(table.name)
+            if name is None:
+                name = base
+                i = 1
+                while name in sources:
+                    name = f"{base}_{i}"
+                    i += 1
+                sources[name] = pin.reader
+                if not isinstance(pin.reader, MvTap):
+                    read_once[table.name] = name
             ref = ("source", name)
             if pin.executors:
                 # window columns shift stream-key positions? no — hop
@@ -1810,10 +1881,28 @@ class Planner:
             if execs:
                 nodes.append(FragNode(Fragment(execs), ref))
                 ref = ("node", len(nodes) - 1)
+            # an output column that is a bare input column (a group key,
+            # where the subquery aggregates) keeps that column's
+            # watermark: changes to come carry values at or above it
+            wm_lags: dict[int, int] = {}
+            if iinfo.wm_lags and not inner_dyn \
+                    and not any(isinstance(i.expr, ast.Star)
+                                for i in inner.items):
+                for pos, item in enumerate(inner.items):
+                    if not isinstance(item.expr, ast.ColumnRef):
+                        continue
+                    try:
+                        src = scope.resolve(item.expr.name, item.expr.table)
+                    except BindError:
+                        continue
+                    if src in iinfo.wm_lags:
+                        wm_lags[pos] = iinfo.wm_lags[src]
             info = PlannedInput(
                 None, [], Scope.of(out_schema, sq.alias), out_schema,
                 None, None, append_only,
                 stream_key=pk_positions or None,
+                wm_lags=wm_lags or None,
+                wm_src_col=iinfo.wm_src_col if wm_lags else None,
             )
             return ref, info
 
@@ -2044,11 +2133,20 @@ class Planner:
                     i for i, f in enumerate(pin.schema)
                     if f.name in ("window_start", "window_end")
                 ]
-                for ki, ke in enumerate(keys):
+                for ke in keys:
                     if isinstance(ke, InputRef) and ke.index in window_idxs:
-                        setattr(join, f"{side_name}_clean",
-                                (ki, pin.window_size, pin.watermark_col))
+                        setattr(join, f"{side_name}_clean", JoinClean(
+                            ke, pin.window_size, pin.wm_src_col))
                         break
+            if join_type == "inner":
+                # a time band between the sides' watermarked event-time
+                # columns bounds the state too (ON or WHERE; the
+                # conjuncts stay behind as post-join filters)
+                self._band_cleaning(
+                    join, left, right,
+                    residual + list(where_conjs),
+                    both,
+                )
             nodes.append(JoinNode(join, lref, rref))
             ref = ("node", len(nodes) - 1)
             if residual:
@@ -2227,6 +2325,102 @@ class Planner:
         return DagPlan(
             sources, nodes, len(nodes) - 1, len(post_execs) - 1
         )
+
+    @staticmethod
+    def _named_columns(*roots) -> "set | None":
+        """``(table, name)`` of every column a statement names anywhere
+        (its FROM subqueries included), or None where a ``SELECT *``
+        makes the set open."""
+        refs: set = set()
+        stack = list(roots)
+        while stack:
+            x = stack.pop()
+            if isinstance(x, ast.ColumnRef):
+                refs.add((x.table, x.name))
+            elif isinstance(x, ast.SelectItem) \
+                    and isinstance(x.expr, ast.Star):
+                return None
+            elif dataclasses.is_dataclass(x) and not isinstance(x, type):
+                stack.extend(getattr(x, f.name)
+                             for f in dataclasses.fields(x))
+            elif isinstance(x, (tuple, list)):
+                stack.extend(x)
+        return refs
+
+    @staticmethod
+    def _time_offset(e: Expr) -> "tuple[int, int] | None":
+        """``(column, offset us)`` of ``col`` or ``col +/- constant``."""
+        if isinstance(e, InputRef):
+            return e.index, 0
+        if isinstance(e, EFuncCall) and e.name in ("add", "subtract") \
+                and len(e.args) == 2:
+            a, b = e.args
+            if e.name == "add" and isinstance(a, ELiteral):
+                a, b = b, a
+            if isinstance(a, InputRef) and isinstance(b, ELiteral) \
+                    and isinstance(b.value, int) \
+                    and not isinstance(b.value, bool):
+                return a.index, b.value if e.name == "add" else -b.value
+        return None
+
+    def _band_cleaning(self, join: HashJoinExecutor, left: PlannedInput,
+                       right: PlannedInput, conjuncts: list,
+                       both: Scope) -> None:
+        """Turn a band between two watermarked event-time columns into
+        the join's cleaning rules.
+
+        ``L.t >= R.u + lo`` (a lower bound on the left): a left row is
+        dead once every right row still to come lies too far ahead, so
+        the left side retires below ``watermark(R.u) + lo``.
+        ``L.t <= R.u + hi``: a right row is dead once every left row
+        still to come lies past it: the right side retires below
+        ``watermark(L.t) - hi``.  Strict bounds count as loose ones
+        (keeps a row a moment longer).  A side whose join key already
+        cleans it (a window key) keeps that rule."""
+        if not (left.wm_lags and right.wm_lags) \
+                or left.wm_src_col is None or right.wm_src_col is None:
+            return
+        n_left = len(left.schema)
+        flip = {"greater_than_or_equal": "less_than_or_equal",
+                "greater_than": "less_than",
+                "less_than_or_equal": "greater_than_or_equal",
+                "less_than": "greater_than"}
+        lower = upper = None   # (left col, right col, offset us)
+        for conj in conjuncts:
+            try:
+                e = Binder(both).bind(conj)
+            except Exception:
+                continue  # not this join's columns (or not a scalar)
+            if not (isinstance(e, EFuncCall) and e.name in flip
+                    and len(e.args) == 2):
+                continue
+            x, y = (self._time_offset(a) for a in e.args)
+            if x is None or y is None:
+                continue
+            op = e.name
+            if x[0] >= n_left > y[0]:       # R.. op L..  ->  L.. op' R..
+                x, y, op = y, x, flip[op]
+            if not (x[0] < n_left <= y[0]):
+                continue
+            lcol, rcol = x[0], y[0] - n_left
+            if lcol not in left.wm_lags or rcol not in right.wm_lags:
+                continue
+            bound = (lcol, rcol, y[1] - x[1])   # L.t op R.u + offset
+            if op.startswith("greater"):
+                if lower is None or bound[2] > lower[2]:
+                    lower = bound
+            elif upper is None or bound[2] < upper[2]:
+                upper = bound
+        if lower is not None and join.left_clean is None:
+            lcol, rcol, lo = lower
+            join.left_clean = JoinClean(
+                InputRef(lcol), right.wm_lags[rcol] - lo,
+                left.wm_src_col, right.wm_src_col)
+        if upper is not None and join.right_clean is None:
+            lcol, rcol, hi = upper
+            join.right_clean = JoinClean(
+                InputRef(rcol), left.wm_lags[lcol] + hi,
+                right.wm_src_col, left.wm_src_col)
 
     def _conjuncts(self, e) -> list:
         if isinstance(e, ast.BinaryOp) and e.op == "and":

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from risingwave_tpu.common.chunk import NCol, StrCol
+from risingwave_tpu.common.compact import mask_indices
 from risingwave_tpu.common.hash import (
     hash64_columns,
     hash64_extend,
@@ -53,6 +54,15 @@ from risingwave_tpu.common.hash import (
 RECLAIM_TILE = 128
 #: movers it lists at a time (one binary search over the table each)
 RECLAIM_BATCH = 4096
+#: movers ``TagTable.reclaimed`` reinserts at a time: its probe loop
+#: narrows by itself (``_probe_loop``), so a wide tile shares the rounds'
+#: fixed cost (a join retires ten times an aggregate's slots a barrier:
+#: PERF.md §6, PR 35)
+TAG_RECLAIM_TILE = 4096
+#: widths a ``TagTable`` probe loop narrows to as a chunk's rows resolve
+#: (``_probe_loop``): the open rows go on in a tile of the next width
+#: once they fit it
+STRAGGLER_TILES = (1024, 128)
 
 #: trace-time probe accounting: how many table-probe loops a compiled
 #: program contains.  Incremented while TRACING (each jitted program
@@ -153,6 +163,158 @@ def permute_dense(arr, moved: jnp.ndarray, init=None):
     else:
         out = jnp.full_like(arr, init)
     return out.at[moved].set(arr, mode="drop")
+
+
+def _probe_loop(step, shared, rows: tuple, inputs: tuple, max_iters: int):
+    """Run a chunk's probe rounds until every row has resolved.
+
+    ``step(shared, rows, inputs) -> (shared, rows)`` is one round over
+    rows of any width: ``shared`` is what all rows probe (the table's
+    arrays), ``rows`` per-row state with ``rows[0]`` the ``done`` mask,
+    ``inputs`` per-row values that do not change.  A round costs the
+    same for a resolved row as for an open one, and the longest chain of
+    a chunk is ten times its mean (at load 0.41, 8,192 rows: 2.6 slots a
+    row, 30-45 rounds): so once no more rows are open than the next of
+    ``STRAGGLER_TILES`` holds they are gathered into a tile of that
+    width, go on there, and are put back.  The first round is unrolled
+    into the enclosing program: at sane load factors most rows resolve
+    at once, and the common case should not pay a loop iteration's fixed
+    overhead (PERF.md §6, PR 27).  Returns ``(shared, rows, rounds)``."""
+    shared, rows = step(shared, rows, inputs)
+    return _narrowing_rounds(step, shared, rows, inputs, jnp.int32(1),
+                             max_iters, STRAGGLER_TILES)
+
+
+def _narrowing_rounds(step, shared, rows, inputs, iters, max_iters, tiles):
+    width = rows[0].shape[0]
+    tiles = [t for t in tiles if t < width]
+    more_than = tiles[0] if tiles else 0
+
+    def cond(carry):
+        _, rows, iters = carry
+        still = jnp.sum(~rows[0], dtype=jnp.int32)
+        return (still > more_than) & (iters < max_iters)
+
+    def body(carry):
+        shared, rows, iters = carry
+        shared, rows = step(shared, rows, inputs)
+        return shared, rows, iters + 1
+
+    shared, rows, iters = jax.lax.while_loop(
+        cond, body, (shared, rows, iters))
+    if not tiles:
+        return shared, rows, iters
+    at = mask_indices(~rows[0], tiles[0], width)
+    picked = at < width
+    safe = jnp.minimum(at, width - 1)
+    few = tuple(x[safe] for x in rows)
+    few = (few[0] | ~picked,) + few[1:]
+    shared, few, iters = _narrowing_rounds(
+        step, shared, few, tuple(x[safe] for x in inputs), iters,
+        max_iters, tiles[1:])
+    to = jnp.where(picked, at, width)
+    rows = tuple(x.at[to].set(y, mode="drop") for x, y in zip(rows, few))
+    return shared, rows, iters
+
+
+def _reclaim(size: int, live, tomb, home, emptied, reinsert, occupied_of,
+             dense, inits, tile: int = RECLAIM_TILE):
+    """Give an open-addressing table's tombstones back at a cost that
+    follows what was retired: the live entries whose probe chain crosses
+    a tombstone are taken out and inserted again, ``tile`` at a time,
+    and nothing else moves.  One implementation for ``HashTable``
+    (key columns) and ``TagTable`` (packed tags).
+
+    Every index of a table-wide scatter or gather costs the v5e ~66 ns,
+    live or dropped (PERF.md §6), and a rebuild of the whole table with
+    a permute of every per-slot leaf hands it the table once a leaf.
+    Here the table-wide work is elementwise and two scans.  A live
+    entry is a *mover* if it is off its home slot and a tombstone lies
+    before it in its run (the stretch of non-empty slots it sits in): a
+    superset of the entries a freed slot can bring nearer home, and
+    closed, because an entry ahead of its run's first tombstone has no
+    freed slot on its chain.  All tombstones and movers become empty at
+    once; then the movers go back in, in the order of their old slots
+    counted from an empty one (so no run is cut), which keeps each
+    reinserted entry at or before the last old slot of its tile: the
+    old slots' contents are read a tile ahead of anything that could
+    overwrite them, and no staging copy is needed.
+
+    ``live`` / ``tomb`` / ``home`` are ``[size]``: occupancy, tombstones
+    and each live entry's home slot.  ``emptied(keep)`` is the table
+    with exactly the ``keep`` slots occupied and no tombstone;
+    ``reinsert(table, old_slots, valid)`` puts the entries that sat at
+    ``old_slots`` back and returns ``(table, new_slots, overflow)``;
+    ``occupied_of(table)`` its occupancy.  ``dense`` / ``inits`` as in
+    ``HashTable.reclaimed``.  Returns ``(table, dense', lost)``.
+    """
+    K = min(tile, size)
+    idx = jnp.arange(size, dtype=jnp.int32)
+    empty = ~live & ~tomb
+
+    def last_at_or_before(mask):
+        """Slot of the nearest ``mask`` slot at or before each slot,
+        around the table's end (then negative); none: below all."""
+        none = -2 * size - 2
+        at = _scan_slots(jax.lax.cummax, jnp.maximum,
+                         jnp.where(mask, idx, none), none)
+        return jnp.maximum(at, at[-1] - size)
+
+    mover = live & (home != idx) & (
+        last_at_or_before(tomb) > last_at_or_before(empty))
+    # slots are counted from an empty one, or, in a table without,
+    # from a tombstone (then every displaced entry is a mover)
+    origin = jnp.where(jnp.any(empty), jnp.argmax(empty),
+                       jnp.argmax(tomb)).astype(jnp.int32)
+    table = emptied(live & ~mover)
+    leaves, treedef = jax.tree.flatten(tuple(dense))
+    fills = list(inits) or [0] * len(leaves)
+    if len(fills) != len(leaves):
+        raise ValueError("one fill value for each leaf of `dense`")
+
+    # the movers' old slots, ascending from ``origin``: the k-th is
+    # where the running count of movers first reaches k (a binary
+    # search a batch: a sort or a ``top_k`` over the table would cost
+    # the chip's compiler half a minute, PERF.md §6)
+    B = min(RECLAIM_BATCH, size)
+    count = _scan_slots(
+        jax.lax.cumsum, jnp.add,
+        jnp.roll(mover, -origin).astype(jnp.int32), 0)
+    n_movers = count[-1]
+
+    def batch(b, carry):
+        at = jnp.searchsorted(
+            count, b * B + jnp.arange(1, B + 1, dtype=jnp.int32),
+            side="left", method="scan").astype(jnp.int32)
+        old = jnp.concatenate([
+            jnp.where(at < size, (at + origin) % size, size),
+            jnp.full((-B % K,), size, jnp.int32)])
+
+        def put_back(t, carry):
+            table, leaves, lost = carry
+            pos = jax.lax.dynamic_slice(old, (t * K,), (K,))
+            valid = pos < size
+            safe = jnp.minimum(pos, size - 1)
+            rows = [x[safe] for x in leaves]
+            table, slots, over = reinsert(table, safe, valid)
+            to = jnp.where(valid & ~over, slots, jnp.int32(size))
+            leaves = [x.at[to].set(r, mode="drop")
+                      for x, r in zip(leaves, rows)]
+            lost = lost + jnp.sum((valid & over).astype(jnp.int64))
+            return table, leaves, lost
+
+        n = jnp.minimum(n_movers - b * B, B)
+        return jax.lax.fori_loop(0, (n + K - 1) // K, put_back, carry)
+
+    table, leaves, lost = jax.lax.fori_loop(
+        0, (n_movers + B - 1) // B, batch,
+        (table, leaves, jnp.zeros((), jnp.int64)))
+    occ = occupied_of(table)
+    leaves = [
+        jnp.where(occ.reshape((size,) + (1,) * (x.ndim - 1)),
+                  x, jnp.asarray(f, x.dtype))
+        for x, f in zip(leaves, fills)]
+    return table, jax.tree.unflatten(treedef, leaves), lost
 
 
 def _empty_key_col(col_proto, size: int):
@@ -392,24 +554,7 @@ class HashTable:
 
     def reclaimed(self, dense=(), inits=()):
         """Give every tombstone back, at a cost that follows what was
-        retired: the live keys whose probe chain crosses a tombstone are
-        taken out and inserted again, ``RECLAIM_TILE`` at a time, and
-        nothing else moves.
-
-        Every index of a table-wide scatter or gather costs the v5e
-        ~66 ns, live or dropped (PERF.md §6), and ``rehashed`` +
-        ``permute_dense`` hand it the whole table once a state leaf.
-        Here the table-wide work is elementwise and two scans.  A live
-        key is a *mover* if it is off its home slot and a tombstone lies
-        before it in its run (the stretch of non-empty slots it sits
-        in): a superset of the keys a freed slot can bring nearer home,
-        and closed, because a key ahead of its run's first tombstone has
-        no freed slot on its chain.  All tombstones and movers become
-        empty at once; then the movers go back in, in the order of their
-        old slots counted from an empty one (so no run is cut), which
-        keeps each reinserted key at or before the last old slot of its
-        tile: the old slots' contents are read a tile ahead of anything
-        that could overwrite them, and no staging copy is needed.
+        retired (``_reclaim``, shared with ``TagTable``).
 
         ``dense`` is a tuple of pytrees of ``[size, ...]`` per-slot
         arrays that move with their keys; ``inits`` one fill value (what
@@ -420,78 +565,23 @@ class HashTable:
         bound (none, below the table's load limit).
         """
         size = self.size
-        K = min(RECLAIM_TILE, size)
-        idx = jnp.arange(size, dtype=jnp.int32)
         live = self.occupied
-        tomb = self.tombstone & ~live
-        empty = ~live & ~tomb
         home = (hash64_columns(self.key_cols) % np.uint64(size)).astype(
             jnp.int32)
 
-        def last_at_or_before(mask):
-            """Slot of the nearest ``mask`` slot at or before each slot,
-            around the table's end (then negative); none: below all."""
-            none = -2 * size - 2
-            at = _scan_slots(jax.lax.cummax, jnp.maximum,
-                             jnp.where(mask, idx, none), none)
-            return jnp.maximum(at, at[-1] - size)
+        def emptied(keep):
+            return HashTable(self.key_cols, keep,
+                             jnp.zeros((size,), jnp.bool_), size)
 
-        mover = live & (home != idx) & (
-            last_at_or_before(tomb) > last_at_or_before(empty))
-        # slots are counted from an empty one, or, in a table without,
-        # from a tombstone (then every displaced key is a mover)
-        origin = jnp.where(jnp.any(empty), jnp.argmax(empty),
-                           jnp.argmax(tomb)).astype(jnp.int32)
-        table = HashTable(self.key_cols, live & ~mover,
-                          jnp.zeros((size,), jnp.bool_), size)
-        leaves, treedef = jax.tree.flatten(tuple(dense))
-        fills = list(inits) or [0] * len(leaves)
-        if len(fills) != len(leaves):
-            raise ValueError("one fill value for each leaf of `dense`")
+        def reinsert(table, safe, valid):
+            # a cleared slot still holds its key columns
+            table, slots, _, over = table.lookup_or_insert(
+                [_gather_key(c, safe) for c in table.key_cols], valid)
+            return table, slots, over
 
-        # the movers' old slots, ascending from ``origin``: the k-th is
-        # where the running count of movers first reaches k (a binary
-        # search a batch: a sort or a ``top_k`` over the table would cost
-        # the chip's compiler half a minute, PERF.md §6)
-        B = min(RECLAIM_BATCH, size)
-        count = _scan_slots(
-            jax.lax.cumsum, jnp.add,
-            jnp.roll(mover, -origin).astype(jnp.int32), 0)
-        n_movers = count[-1]
-
-        def batch(b, carry):
-            at = jnp.searchsorted(
-                count, b * B + jnp.arange(1, B + 1, dtype=jnp.int32),
-                side="left", method="scan").astype(jnp.int32)
-            old = jnp.concatenate([
-                jnp.where(at < size, (at + origin) % size, size),
-                jnp.full((-B % K,), size, jnp.int32)])
-
-            def reinsert(t, carry):
-                table, leaves, lost = carry
-                pos = jax.lax.dynamic_slice(old, (t * K,), (K,))
-                valid = pos < size
-                safe = jnp.minimum(pos, size - 1)
-                rows = [x[safe] for x in leaves]
-                table, slots, _, over = table.lookup_or_insert(
-                    [_gather_key(c, safe) for c in table.key_cols], valid)
-                to = jnp.where(valid & ~over, slots, jnp.int32(size))
-                leaves = [x.at[to].set(r, mode="drop")
-                          for x, r in zip(leaves, rows)]
-                lost = lost + jnp.sum((valid & over).astype(jnp.int64))
-                return table, leaves, lost
-
-            n = jnp.minimum(n_movers - b * B, B)
-            return jax.lax.fori_loop(0, (n + K - 1) // K, reinsert, carry)
-
-        table, leaves, lost = jax.lax.fori_loop(
-            0, (n_movers + B - 1) // B, batch,
-            (table, leaves, jnp.zeros((), jnp.int64)))
-        leaves = [
-            jnp.where(table.occupied.reshape((size,) + (1,) * (x.ndim - 1)),
-                      x, jnp.asarray(f, x.dtype))
-            for x, f in zip(leaves, fills)]
-        return table, jax.tree.unflatten(treedef, leaves), lost
+        return _reclaim(size, live, self.tombstone & ~live, home,
+                        emptied, reinsert, lambda t: t.occupied,
+                        dense, inits)
 
     def gather_keys(self, slots: jnp.ndarray) -> tuple:
         """Key column values at ``slots`` (drop-sentinel aware gathers)."""
@@ -515,6 +605,12 @@ def pair_tag(hashes: jnp.ndarray, rank: jnp.ndarray) -> jnp.ndarray:
     sentinels.  The tag doubles as the slot hash (``tag % size``), so a
     probe costs ONE random gather per iteration."""
     return finish_tag(hash64_extend(hash64_partial([hashes]), rank))
+
+
+def tag_home(tags: jnp.ndarray, size: int) -> jnp.ndarray:
+    """Each tag's home slot in a table of ``size`` slots (a power of
+    two): its low bits."""
+    return (tags % np.uint64(size)).astype(jnp.int32)
 
 
 def finish_tag(state: jnp.ndarray) -> jnp.ndarray:
@@ -575,21 +671,18 @@ class TagTable:
                     insert: bool):
         """Generic one-gather probe over precomputed tags.
 
-        Returns ``(tags', slots, found, inserted, overflow)``."""
+        Returns ``(tags', slots, found, overflow, inserted)``."""
         PROBE_STATS["lookup_or_insert" if insert else "lookup"] += 1
         size = self.size
         cap = valid.shape[0]
-        row_idx = jnp.arange(cap, dtype=jnp.int32)
         sentinel = jnp.int32(size)
-        home = (tag_vals % np.uint64(size)).astype(jnp.int32)
         max_iters = min(size + 2, 1024)
 
-        def cond(carry):
-            _, _, done, _, _, iters = carry
-            return jnp.any(~done) & (iters < max_iters)
-
-        def body(carry):
-            tags, slots, done, inserted, off, iters = carry
+        def step(tags, rows, inputs):
+            done, slots, inserted, off = rows
+            tag_vals, home = inputs
+            width = done.shape[0]
+            row_idx = jnp.arange(width, dtype=jnp.int32)
             cand = (home + off) % size
             t = tags[cand]  # THE one random gather
             tomb = t == TOMB_TAG
@@ -600,37 +693,32 @@ class TagTable:
             done = done | hit
             if insert:
                 want = ~done & empty
-                m = 4 * cap
+                m = 4 * width
                 scratch_idx = cand % m
-                claim = jnp.full((m,), cap, jnp.int32).at[
+                claim = jnp.full((m,), width, jnp.int32).at[
                     jnp.where(want, scratch_idx, m)
-                ].min(jnp.where(want, row_idx, cap), mode="drop")
+                ].min(jnp.where(want, row_idx, width), mode="drop")
                 won = want & (claim[scratch_idx] == row_idx)
                 pos = jnp.where(won, cand, sentinel)
                 tags = tags.at[pos].set(tag_vals, mode="drop")
                 slots = jnp.where(won, cand, slots)
                 inserted = inserted | won
                 done = done | won
-                advance = ~done & ((~empty & ~match) | tomb)
             else:
-                miss = ~done & empty
-                done = done | miss
-                advance = ~done & ((~empty & ~match) | tomb)
+                done = done | (~done & empty)
+            advance = ~done & ((~empty & ~match) | tomb)
             off = jnp.where(advance, off + 1, off)
-            return tags, slots, done, inserted, off, iters + 1
+            return tags, (done, slots, inserted, off)
 
-        init = (
-            self.tags,
-            jnp.full((cap,), sentinel, jnp.int32),
+        rows = (
             ~valid,
+            jnp.full((cap,), sentinel, jnp.int32),
             jnp.zeros((cap,), jnp.bool_),
             jnp.zeros((cap,), jnp.int32),
-            jnp.int32(0),
         )
-        carry = body(init)
-        tags, slots, done, inserted, _, _ = jax.lax.while_loop(
-            cond, body, carry
-        )
+        home = tag_home(tag_vals, size)
+        tags, (done, slots, inserted, _), _ = _probe_loop(
+            step, self.tags, rows, (tag_vals, home), max_iters)
         overflow = ~done
         found = valid & done & ~inserted & (slots < size)
         return tags, slots, found, overflow, inserted
@@ -682,32 +770,29 @@ class TagTable:
           stranded entry from an earlier overflow; callers overwrite
           its payload and count the loss loudly);
         - ``overflow bool [cap]`` — probe bound exhausted;
-        - ``iters int32 ()`` — loop trips (device probe-effort counter).
+        - ``iters int32 ()`` — loop trips (device probe-effort counter);
+        - ``steps int32 ()`` — slots looked at, summed over the rows
+          (a row that resolves at its first candidate counts one).
         """
         PROBE_STATS["lookup_or_insert"] += 1
         size = self.size
         cap = valid.shape[0]
-        row_idx = jnp.arange(cap, dtype=jnp.int32)
         sentinel = jnp.int32(size)
-        # split hash: fold the 64-bit key hash once; re-finalize with
-        # the (varying) rank on phase switches only
-        base = hash64_partial([hashes])
 
-        def tag_of(r):
+        def tag_of(base, r):
             return finish_tag(hash64_extend(base, r))
 
         max_iters = min(2 * size + 4, 1024)
 
-        def cond(carry):
-            done = carry[2]
-            iters = carry[-1]
-            return jnp.any(~done) & (iters < max_iters)
-
-        def body(carry):
-            (tags, slots, done, inserted, existed, phase2, target,
-             target_tag, head_slot, off, iters) = carry
-            cand = ((target_tag % np.uint64(size)).astype(jnp.int32)
-                    + off) % size
+        def step(shared, rows, inputs):
+            tags, steps = shared
+            (done, slots, inserted, existed, phase2, target, target_tag,
+             head_slot, off) = rows
+            chunk_rank, base = inputs
+            width = done.shape[0]
+            row_idx = jnp.arange(width, dtype=jnp.int32)
+            steps = steps + jnp.sum(~done, dtype=jnp.int32)
+            cand = (tag_home(target_tag, size) + off) % size
             t = tags[cand]  # THE one random gather
             tomb = t == TOMB_TAG
             empty = t == EMPTY_TAG
@@ -737,7 +822,7 @@ class TagTable:
             new_target = jnp.where(sw_hit, new_rank, chunk_rank)
             target = jnp.where(switched, new_target, target)
             target_tag = jnp.where(
-                switched, tag_of(new_target), target_tag
+                switched, tag_of(base, new_target), target_tag
             )
             off = jnp.where(switched, 0, off)
 
@@ -750,11 +835,11 @@ class TagTable:
             # claims (same scratch-race as _probe): phase-1 rank-0 rows
             # claim the head; phase-2 rows claim their target entry
             want = ~done & ~switched & empty & (phase2 | (chunk_rank == 0))
-            m = 4 * cap
+            m = 4 * width
             scratch_idx = cand % m
-            claim = jnp.full((m,), cap, jnp.int32).at[
+            claim = jnp.full((m,), width, jnp.int32).at[
                 jnp.where(want, scratch_idx, m)
-            ].min(jnp.where(want, row_idx, cap), mode="drop")
+            ].min(jnp.where(want, row_idx, width), mode="drop")
             won = want & (claim[scratch_idx] == row_idx)
             pos = jnp.where(won, cand, sentinel)
             tags = tags.at[pos].set(target_tag, mode="drop")
@@ -764,41 +849,33 @@ class TagTable:
             done = done | won
             advance = ~done & ~switched & ((~empty & ~match) | tomb)
             off = jnp.where(advance, off + 1, off)
-            return (tags, slots, done, inserted, existed, phase2,
-                    target, target_tag, head_slot, off, iters + 1)
+            return (tags, steps), (done, slots, inserted, existed, phase2,
+                                   target, target_tag, head_slot, off)
 
-        init = (
-            self.tags,
-            jnp.full((cap,), sentinel, jnp.int32),
+        # split hash: fold the 64-bit key hash once; re-finalize with
+        # the (varying) rank on phase switches only
+        base = hash64_partial([hashes])
+        zeros = jnp.zeros((cap,), jnp.int32)
+        no = jnp.zeros((cap,), jnp.bool_)
+        rows = (
             ~valid,
-            jnp.zeros((cap,), jnp.bool_),
-            jnp.zeros((cap,), jnp.bool_),
-            jnp.zeros((cap,), jnp.bool_),
-            jnp.zeros((cap,), jnp.int32),
-            tag_of(jnp.zeros((cap,), jnp.int32)),
             jnp.full((cap,), sentinel, jnp.int32),
-            jnp.zeros((cap,), jnp.int32),
-            jnp.int32(0),
+            no, no, no,
+            zeros,
+            tag_of(base, zeros),
+            jnp.full((cap,), sentinel, jnp.int32),
+            zeros,
         )
-        # first round unrolled, as in _probe: most rows resolve both
-        # phases in a couple of rounds at sane load factors
-        carry = body(init)
-        (tags, slots, done, inserted, existed, _, target, _,
-         head_slot, _, iters) = jax.lax.while_loop(cond, body, carry)
+        (tags, steps), rows, iters = _probe_loop(
+            step, (self.tags, jnp.int32(0)), rows, (chunk_rank, base),
+            max_iters)
+        done, slots, inserted, existed, _, target, _, head_slot, _ = rows
         overflow = ~done
         table = TagTable(tags, size)
         return (table, slots, target, head_slot, inserted,
-                existed & valid, overflow, iters)
+                existed & valid, overflow, iters, steps)
 
     # -- maintenance ----------------------------------------------------
-    def clear_where(self, pred: jnp.ndarray) -> "TagTable":
-        """Bulk-evict slots where ``pred [size]`` (state cleaning);
-        cleared slots become tombstones so probe chains stay intact."""
-        dead = pred & self.occupied
-        return TagTable(
-            jnp.where(dead, TOMB_TAG, self.tags), self.size
-        )
-
     def clear_slots(self, slots: jnp.ndarray,
                     mask: jnp.ndarray) -> "TagTable":
         """Tombstone specific slots (e.g. un-claim on pool overflow)."""
@@ -807,13 +884,23 @@ class TagTable:
             self.tags.at[pos].set(TOMB_TAG, mode="drop"), self.size
         )
 
-    def rehashed(self) -> tuple["TagTable", jnp.ndarray]:
-        """Rebuild without tombstones; ``(fresh, moved int32 [size])``
-        maps old slot -> new slot (size sentinel for dead slots) so
-        callers permute their per-slot value arrays alongside."""
-        live = self.occupied
-        fresh = TagTable.create(self.size)
-        tags, new_slots, _, _, _ = fresh._probe_tags(
-            self.tags, live, insert=True
-        )
-        return TagTable(tags, self.size), new_slots
+    def reclaimed(self, dense=(), inits=()):
+        """``HashTable.reclaimed`` for packed tags: every tombstone
+        becomes empty and only the entries whose probe chain crossed one
+        are put back (``_reclaim``); ``dense`` per-slot leaves move with
+        them.  Returns ``(table, dense', lost)``."""
+        size = self.size
+        old = self.tags
+
+        def emptied(keep):
+            return TagTable(jnp.where(keep, old, EMPTY_TAG), size)
+
+        def reinsert(table, safe, valid):
+            tags, slots, _, over, _ = table._probe_tags(
+                old[safe], valid, insert=True)
+            return TagTable(tags, size), slots, over
+
+        return _reclaim(size, old >= np.uint64(2), old == TOMB_TAG,
+                        tag_home(old, size),
+                        emptied, reinsert, lambda t: t.occupied,
+                        dense, inits, TAG_RECLAIM_TILE)

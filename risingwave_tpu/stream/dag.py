@@ -37,6 +37,8 @@ import numpy as np
 from risingwave_tpu.stream.fragment import (
     COUNTER_ATTRS,
     Fragment,
+    JOIN_GAUGE_ATTRS,
+    JOIN_TALLY_ATTRS,
     WM_NONE,
     WM_SAFE_FLOOR,
     collect_counters,
@@ -142,6 +144,7 @@ class DagJob(BarrierLoop):
         self._spill_progs: dict = {}
         self.states = self._init_states()
         self.counter_labels: list[str] = []
+        self.counter_sides: dict[int, str] = {}
         self._rebuild()
 
     def _init_states(self):
@@ -176,6 +179,8 @@ class DagJob(BarrierLoop):
                 # (a self-join): enqueue() already fans out per side
                 if idx not in lst:
                     lst.append(idx)
+            if hasattr(getattr(node, "join", None), "scope"):
+                node.join.scope = f"HashJoin.{idx}"
         self._step_programs: dict[str, Any] = {}
         self._barrier_prog = None
         self._maintain_prog = None
@@ -417,7 +422,8 @@ class DagJob(BarrierLoop):
                 def body(states, k0):
                     local = jax.tree.map(lambda x: x[0], states)
                     new_states = list(local)
-                    chunk = reader.impl(k0[0], reader.cap)
+                    with jax.named_scope("gen"):
+                        chunk = reader.impl(k0[0], reader.cap)
                     self._propagate(
                         new_states, [(("source", src_name), chunk)]
                     )
@@ -451,7 +457,8 @@ class DagJob(BarrierLoop):
         if fused:
             # traceable source: generation fuses into the step program
             def fn(states, k0):
-                chunk = reader.impl(k0, reader.cap)
+                with jax.named_scope("gen"):
+                    chunk = reader.impl(k0, reader.cap)
                 new_states = list(states)
                 self._propagate(new_states, [(("source", src_name), chunk)])
                 return tuple(new_states)
@@ -593,9 +600,7 @@ class DagJob(BarrierLoop):
         def clean_tail(states):
             new_states = list(states)
             self._clean_joins(new_states)
-            labels, counters = self._collect_counters(new_states)
-            self.counter_labels = labels
-            return tuple(new_states), counters
+            return tuple(new_states), self._collect_counters(new_states)
 
         prog_cl = self._staged_prog(("clean_tail",), lambda: clean_tail)
         self.states, counters = prog_cl(self.states)
@@ -750,7 +755,9 @@ class DagJob(BarrierLoop):
                     for nm, k in pulls:
                         for rep in range(k):
                             base = k0s[nm] + (i * k + rep) * strides[nm]
-                            chunk = readers[nm].impl(base, readers[nm].cap)
+                            with jax.named_scope("gen"):
+                                chunk = readers[nm].impl(
+                                    base, readers[nm].cap)
                             self._propagate(
                                 new_states, [(("source", nm), chunk)]
                             )
@@ -770,7 +777,9 @@ class DagJob(BarrierLoop):
                     for si, (nm, k) in enumerate(pulls):
                         for rep in range(k):
                             b0 = base_cols[si][0, i * k + rep]
-                            chunk = readers[nm].impl(b0, readers[nm].cap)
+                            with jax.named_scope("gen"):
+                                chunk = readers[nm].impl(
+                                    b0, readers[nm].cap)
                             self._propagate(
                                 new_states, [(("source", nm), chunk)]
                             )
@@ -933,26 +942,33 @@ class DagJob(BarrierLoop):
             ref = node.input
 
     def _clean_joins(self, new_states: list) -> None:
-        """Watermark-driven join state cleaning (windowed joins): each
-        side is cleaned by the MIN watermark across both inputs — a
-        build row for window W serves the other side's future probes
-        (BinaryJob._clean_join_state, generalized to DAG refs)."""
+        """Watermark-driven join state cleaning: each side with a rule
+        (``HashJoinExecutor.clean_rule``: a window join key, a time band
+        between the sides) retires its rows below the MIN watermark
+        across the inputs its rules name, less the rule's lag — a build
+        row serves the other side's future probes.  The tombstones it
+        leaves are given back by the maintenance pass
+        (``_maintain_impl``), as the aggregates' are."""
         for idx, node in enumerate(self.nodes):
-            if not isinstance(node, JoinNode):
+            if not isinstance(node, JoinNode) \
+                    or not hasattr(node.join, "clean_rule"):
                 continue
             join = node.join
-            wms = []
-            ok = True
-            for side, ref in (("left", node.left), ("right", node.right)):
-                clean = getattr(join, f"{side}_clean", None)
-                if clean is None:
+            refs = {"left": node.left, "right": node.right}
+            rules = {side: join.clean_rule(side)
+                     for side in ("left", "right")}
+            wanted = set()
+            for side, rule in rules.items():
+                if rule is None:
                     continue
-                wm = self._upstream_wm(new_states, ref, clean[2])
-                if wm is None:
-                    ok = False
-                    break
-                wms.append(wm)
-            if not ok or not wms:
+                other = "right" if side == "left" else "left"
+                if rule.src_col is not None:
+                    wanted.add((side, rule.src_col))
+                if rule.other_src_col is not None:
+                    wanted.add((other, rule.other_src_col))
+            wms = [self._upstream_wm(new_states, refs[side], col)
+                   for side, col in sorted(wanted)]
+            if not wms or any(wm is None for wm in wms):
                 continue
             has_all = wms[0][1]
             min_wm = wms[0][0]
@@ -960,17 +976,12 @@ class DagJob(BarrierLoop):
                 has_all = has_all & has
                 min_wm = jnp.minimum(min_wm, val)
 
-            def do_clean(jstate, join=join, min_wm=min_wm):
-                for side in ("left", "right"):
-                    clean = getattr(join, f"{side}_clean", None)
-                    if clean is None:
-                        continue
-                    key_idx, lag, _ = clean
-                    jstate = join.clean_below(
-                        jstate, side, key_idx, min_wm - lag
-                    )
-                if hasattr(join, "maybe_rehash"):
-                    jstate = join.maybe_rehash(jstate)
+            def do_clean(jstate, join=join, min_wm=min_wm, rules=rules):
+                for side, rule in rules.items():
+                    if rule is not None:
+                        jstate = join.clean_below(
+                            jstate, side, min_wm - rule.lag_us
+                        )
                 return jstate
 
             new_states[idx] = jax.lax.cond(
@@ -978,7 +989,12 @@ class DagJob(BarrierLoop):
             )
 
     def _collect_counters(self, new_states: list):
+        """The barrier's counters vector; its labels, and for a join
+        side's tallies and levels the side, are left on the job
+        (``counter_labels``, ``counter_sides``) as the program is
+        traced."""
         labels: list[str] = []
+        sides: dict[int, str] = {}
         vals: list[jnp.ndarray] = []
         for idx, node in enumerate(self.nodes):
             if node is None:
@@ -1004,15 +1020,19 @@ class DagJob(BarrierLoop):
                 continue
             for side_name in ("left", "right"):
                 s = getattr(jstate, side_name)
-                for attr in COUNTER_ATTRS:
+                for attr in COUNTER_ATTRS + JOIN_TALLY_ATTRS \
+                        + JOIN_GAUGE_ATTRS:
                     if hasattr(s, attr):
+                        if attr not in COUNTER_ATTRS:
+                            sides[len(labels)] = side_name
                         labels.append(f"n{idx}.join.{side_name}.{attr}")
                         vals.append(getattr(s, attr).astype(jnp.int64)[None])
             labels.append(f"n{idx}.join.emit_overflow")
             vals.append(jstate.emit_overflow.astype(jnp.int64)[None])
-        counters = jnp.concatenate(vals) if vals \
+        self.counter_labels = labels
+        self.counter_sides = sides
+        return jnp.concatenate(vals) if vals \
             else jnp.zeros((0,), jnp.int64)
-        return labels, counters
 
     def _barrier_impl(self, states, epoch):
         new_states = list(states)
@@ -1022,9 +1042,7 @@ class DagJob(BarrierLoop):
         self._wm_all(new_states)
         self._flush_all(new_states, epoch)
         self._clean_joins(new_states)
-        labels, counters = self._collect_counters(new_states)
-        self.counter_labels = labels
-        return tuple(new_states), counters
+        return tuple(new_states), self._collect_counters(new_states)
 
     def _make_barrier_prog(self):
         if self.mesh is None:

@@ -40,6 +40,12 @@ TALLY_ATTRS = ("apply_chunks", "rep_rows", "rep_tiles",
 #: levels on the same vector, as the last maintenance pass found them:
 #: exported as gauges ``hash_agg_<attr>{job}``, skipped like the tallies
 GAUGE_ATTRS = ("live_groups", "tombstones", "table_slots")
+#: a join side's tallies and levels, under ``n<i>.join.<side>.<attr>`` on
+#: the DAG runtime's vector: ``hash_join_<attr>_total{job,side}`` and
+#: gauges ``hash_join_<attr>{job,side}``; skipped like the aggregate's
+JOIN_TALLY_ATTRS = ("insert_rows", "probe_steps", "emit_rows",
+                    "cleaned_rows", "reclaim_slots")
+JOIN_GAUGE_ATTRS = ("live_rows", "tombstones", "table_slots")
 
 
 def executor_scope(i: int, ex, phase: str):

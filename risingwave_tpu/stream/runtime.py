@@ -37,6 +37,7 @@ from risingwave_tpu.common.trace import GLOBAL_TRACE
 from risingwave_tpu.stream.fragment import (
     Fragment,
     GAUGE_ATTRS,
+    JOIN_GAUGE_ATTRS,
     TALLY_ATTRS,
 )
 from risingwave_tpu.stream.message import Barrier, BarrierKind
@@ -88,6 +89,9 @@ class BarrierLoop:
     upload_window: int = 4
     #: optional MetricsRegistry (the engine attaches its own)
     metrics = None
+    #: index in the counters vector -> side, for a join side's tallies
+    #: and levels (a runtime with joins sets it beside its labels)
+    counter_sides: dict[int, str] | None = None
     _ckpt_key = None
 
     def __init__(self, name: str, checkpoint_frequency: int = 1,
@@ -275,7 +279,8 @@ class BarrierLoop:
                                    job=self.name):
                 values = np.asarray(self._counters)
             residual = check_counter_values(
-                self.name, self.counter_labels, values, self.metrics
+                self.name, self.counter_labels, values, self.metrics,
+                self.counter_sides,
             )
             # residual pending beyond MAX_DRAIN_ROUNDS×emit_capacity
             # per barrier: pathological; finish draining with host loops
@@ -286,6 +291,7 @@ class BarrierLoop:
                 residual = check_counter_values(
                     self.name, self.counter_labels,
                     np.asarray(self._counters), self.metrics,
+                    self.counter_sides,
                 )
 
     def _commit_checkpoint(self, epoch_val) -> None:
@@ -492,7 +498,8 @@ class BarrierLoop:
 
 
 def check_counter_values(name: str, labels: list[str],
-                         values: np.ndarray, metrics=None) -> list[str]:
+                         values: np.ndarray, metrics=None,
+                         sides: dict[int, str] | None = None) -> list[str]:
     """Raise on error counters; return labels with residual pending.
 
     ``values`` is the host copy of a barrier program's counters vector;
@@ -501,18 +508,31 @@ def check_counter_values(name: str, labels: list[str],
     maintenance barrier read) before anything raises.  The tallies of
     ``fragment.TALLY_ATTRS`` count engagement, not lost rows: they go
     out as ``hash_agg_<kind>_total{job}``, the levels of
-    ``fragment.GAUGE_ATTRS`` as gauges ``hash_agg_<kind>{job}``, and
-    both are otherwise skipped, as ``.pending`` is.
+    ``fragment.GAUGE_ATTRS`` as gauges ``hash_agg_<kind>{job}``, a join
+    side's (``sides``: index in the vector -> side, from the runtime
+    that collected them) as ``hash_join_<kind>[_total]{job,side}``, and
+    all are otherwise skipped, as ``.pending`` is.
     """
     kinds = [label.rsplit(".", 1)[-1] for label in labels]
+    join_side = [(sides or {}).get(i) for i in range(len(labels))]
     if metrics is not None:
         sums: dict[str, int] = {}
         tallies: dict[str, int] = {}
-        for kind, v in zip(kinds, values):
-            if kind in TALLY_ATTRS + GAUGE_ATTRS:
+        joins: dict[tuple, int] = {}
+        for kind, side, v in zip(kinds, join_side, values):
+            if side is not None:
+                joins[kind, side] = joins.get((kind, side), 0) + int(v)
+            elif kind in TALLY_ATTRS + GAUGE_ATTRS:
                 tallies[kind] = tallies.get(kind, 0) + int(v)
             elif kind != "pending":
                 sums[kind] = sums.get(kind, 0) + int(v)
+        for (kind, side), v in joins.items():
+            if kind in JOIN_GAUGE_ATTRS:
+                metrics.set_gauge(f"hash_join_{kind}", v, job=name,
+                                  side=side)
+            else:
+                metrics.set_counter(f"hash_join_{kind}_total", v,
+                                    job=name, side=side)
         for kind, v in sums.items():
             metrics.set_gauge("maintenance_counter_rows", v,
                               job=name, kind=kind)
@@ -522,8 +542,8 @@ def check_counter_values(name: str, labels: list[str],
             else:
                 metrics.set_counter(f"hash_agg_{kind}_total", v, job=name)
     residual = []
-    for label, kind, v in zip(labels, kinds, values):
-        if kind in TALLY_ATTRS + GAUGE_ATTRS:
+    for label, kind, side, v in zip(labels, kinds, join_side, values):
+        if side is not None or kind in TALLY_ATTRS + GAUGE_ATTRS:
             continue
         if kind == "pending":
             if v > 0:

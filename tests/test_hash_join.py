@@ -6,7 +6,7 @@ from risingwave_tpu.common.chunk import Chunk
 from risingwave_tpu.common.types import DataType, Schema
 from risingwave_tpu.expr.node import col
 from risingwave_tpu.stream.fragment import Fragment
-from risingwave_tpu.stream.hash_join import HashJoinExecutor
+from risingwave_tpu.stream.hash_join import HashJoinExecutor, JoinClean
 from risingwave_tpu.stream.materialize import AppendOnlyMaterialize
 from risingwave_tpu.stream.dag import DagJob
 
@@ -141,7 +141,8 @@ def test_join_state_cleaning():
         + 1 10
         + 5 50
     """), "left")
-    st = j.clean_below(st, "left", 0, 3)  # drop keys < 3
+    j.left_clean = JoinClean(j.left_keys[0], 0, 0)
+    st = j.clean_below(st, "left", 3)  # drop keys < 3
     st, rows = _apply(j, st, _rc("""
         I I
         + 1 100
@@ -363,22 +364,24 @@ def test_pool_join_10x_skew_matches_brute_force():
 
 
 def test_pool_join_watermark_cleaning_bounds_state():
-    """clean_below on a pool side evicts whole keys (all their fused
-    (hash, rank) entries) in one mask; ranks stay consistent for
-    survivors and compaction reclaims the dead pool rows."""
+    """clean_below on a pool side retires the expired prefix of its
+    ring (here whole keys, all their fused (hash, rank) entries); ranks
+    stay consistent for survivors."""
     import jax.numpy as jnp
 
     j = _pool_join()
-    j.left_clean = (0, 0, 0)  # clean left keys below threshold
+    j.left_clean = JoinClean(j.left_keys[0], 0, 0)  # clean left keys below threshold
     st = j.init_state()
     lrows = [(k, 10 * k + i) for k in range(8) for i in range(5)]
     txt = "I I\n" + "\n".join(f"+ {k} {v}" for k, v in lrows)
     st, _ = j.apply(st, Chunk.from_pretty(txt, names=["k", "a"]), "left")
     assert int(st.left.table.count()) == 40
-    assert int(st.left.pool_len) == 40
+    assert int(st.left.head - st.left.tail) == 40
 
-    st = j.clean_below(st, "left", 0, 5)  # drop keys 0..4
+    st = j.clean_below(st, "left", 5)  # drop keys 0..4
     assert int(st.left.table.count()) == 15  # 3 keys x 5 rows remain
+    assert int(st.left.head - st.left.tail) == 15
+    assert int(st.left.cleaned_rows) == 25
 
     # survivors still join correctly (ranks intact)
     st, pending = j.apply_begin(st, _rc("""

@@ -21,7 +21,7 @@ import risingwave_tpu  # noqa: F401
 from risingwave_tpu.common.chunk import Chunk
 from risingwave_tpu.common.types import DataType, Schema
 from risingwave_tpu.expr.node import col
-from risingwave_tpu.stream.hash_join import HashJoinExecutor
+from risingwave_tpu.stream.hash_join import HashJoinExecutor, JoinClean
 
 from tests.test_join_matrix import fold
 
@@ -173,37 +173,47 @@ def test_bump_allocator_positions_are_contiguous():
     st = j.init_state()
     st, _ = j.apply(st, _chunk(L, [(5, i) for i in range(8)],
                                [0] * 8), "left")
-    assert int(st.left.pool_len) == 8
+    assert int(st.left.head) == 8
     # every entry's pool position is in [0, 8) and all are distinct
     occ = np.asarray(st.left.table.occupied)
     pos = np.asarray(st.left.pool_pos)[occ]
     assert sorted(pos.tolist()) == list(range(8))
     st, _ = j.apply(st, _chunk(L, [(6, i) for i in range(4)],
                                [0] * 4), "left")
-    assert int(st.left.pool_len) == 12
+    assert int(st.left.head) == 12
 
 
-def test_compaction_reclaims_cleaned_pool_rows():
-    """After watermark cleaning tombstones most keys, maintenance
-    compaction relocates the survivors to a dense prefix, resets the
-    bump cursor, and the join still produces exact results."""
+def test_ring_reuses_cleaned_pool_rows():
+    """After watermark cleaning retires most keys, the ring's tail has
+    moved past their rows, maintenance gives their table slots back,
+    the freed space takes new rows, and the join still produces exact
+    results."""
     j = HashJoinExecutor(
         L, R, [col("k")], [col("k")],
         table_size=64, out_capacity=64,
         left_storage="pool", right_storage="pool",
         left_pool_size=64, right_pool_size=64,
     )
-    j.left_clean = (0, 0, 0)
+    j.left_clean = JoinClean(j.left_keys[0], 0, 0)
     st = j.init_state()
     # fill 48/64 of the pool: cursor is past the 3/4 compaction gate
     lrows = [(k, 10 * k + i) for k in range(12) for i in range(4)]
     txt = "I I\n" + "\n".join(f"+ {k} {v}" for k, v in lrows)
     st, _ = j.apply(st, Chunk.from_pretty(txt, names=["k", "a"]), "left")
-    assert int(st.left.pool_len) == 48
-    st = j.clean_below(st, "left", 0, 10)  # keys 0..9 die (40 rows)
+    assert int(st.left.head) == 48
+    st = j.clean_below(st, "left", 10)  # keys 0..9 die (40 rows)
+    assert int(st.left.table.tombstone_count()) == 40
     st = j.maybe_rehash(st)
-    assert int(st.left.pool_len) == 8   # compacted to the survivors
+    assert int(st.left.head - st.left.tail) == 8   # the survivors
     assert int(st.left.table.count()) == 8
+    assert int(st.left.table.tombstone_count()) == 0
+    assert int(st.left.reclaim_slots) == 40
+    # 40 more rows wrap around the ring's end into the freed space
+    more = [(k, 10 * k + i) for k in range(20, 30) for i in range(4)]
+    txt = "I I\n" + "\n".join(f"+ {k} {v}" for k, v in more)
+    st, _ = j.apply(st, Chunk.from_pretty(txt, names=["k", "a"]), "left")
+    assert int(st.left.overflow) == 0
+    assert int(st.left.head - st.left.tail) == 48
     # survivors (keys 10, 11) still join exactly
     st, pending = j.apply_begin(
         st, _chunk(R, [(10, 500), (3, 600)], [0, 0]), "right"
@@ -232,4 +242,4 @@ def test_pool_overflow_is_loud_not_silent():
     rows = [(k, k) for k in range(24)]  # 24 rows > 16-slot pool
     st, _ = j.apply(st, _chunk(L, rows, [0] * 24), "left")
     assert int(st.left.overflow) == 24 - 16
-    assert int(st.left.pool_len) == 16
+    assert int(st.left.head) == 16

@@ -322,7 +322,8 @@ def test_reads_between_ticks_gather_the_views_blocks():
     want = [(int(price[starts == w].max()), int((starts == w).sum()))
             for w in np.unique(starts)]
     for got in reads:
-        assert got[:-1] == want[:len(got) - 1], (got, want)
+        # (a read before the view's first barrier holds no window)
+        assert got[:-1] == want[:max(len(got) - 1, 0)], (got, want)
         if got:
             hi, cnt = want[len(got) - 1]
             assert got[-1][0] <= hi and got[-1][1] <= cnt

@@ -114,9 +114,10 @@ _INSTRUCTION = re.compile(
     r"^\s*(?:ROOT )?(%[\w.\-]+) = (\([^=]*?\)|\S+) ([\w\-]+)\((.*)$")
 
 
-def _apply_scatter_widths(hlo: str, phase: str = "apply") -> dict[str, int]:
+def _apply_scatter_widths(hlo: str, phase: str = "apply",
+                          executor: str = "HashAgg") -> dict[str, int]:
     """Indices handed to every ``scatter`` of the compiled program whose
-    ``op_name`` lies under a ``HashAgg.<i>/<phase>`` scope, by
+    ``op_name`` lies under a ``<executor>.<i>/<phase>`` scope, by
     instruction.  A scatter's operands are N arrays, the indices, N
     updates."""
     shape_of, scatters = {}, []
@@ -127,7 +128,7 @@ def _apply_scatter_widths(hlo: str, phase: str = "apply") -> dict[str, int]:
         name, shape, op, rest = m.groups()
         shape_of[name] = shape
         if op == "scatter" and re.search(
-                rf'op_name="[^"]*/HashAgg\.\d+/{phase}/', rest):
+                rf'op_name="[^"]*/{executor}\.\d+/{phase}/', rest):
             scatters.append((name, rest))
     out = {}
     for name, rest in scatters:
@@ -204,6 +205,21 @@ def test_q8_step_person(jobs, one_chip):
 def test_q8_barrier(jobs, one_chip):
     job = jobs("q8")
     _compile(job._make_barrier_prog(), one_chip, job.states, EPOCH)
+
+
+def test_q8_maintain(jobs, one_chip):
+    """The reclaim of both pool sides' tag tables at 2^22 slots: under
+    ``HashJoin.<i>/reclaim`` no scatter is handed the table, only one
+    tile of movers; the rows sit in a ring and are not touched (PERF.md
+    §6, PR 35)."""
+    from risingwave_tpu.state import hash_table
+
+    job = jobs("q8")
+    _, hlo = _compile(job._make_maintain_prog(), one_chip, job.states,
+                      text=True)
+    widths = _apply_scatter_widths(hlo, "reclaim", "HashJoin")
+    assert len(widths) >= 4, widths
+    assert max(widths.values()) <= hash_table.TAG_RECLAIM_TILE, widths
 
 
 def test_int64_cumsum_in_loop(one_chip):
