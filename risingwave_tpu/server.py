@@ -90,14 +90,18 @@ def _render_trace(query: str) -> bytes:
     return json.dumps({"role": GLOBAL_TRACE.role, "spans": spans}).encode()
 
 
-def _handshake(role: str, **fields) -> None:
+def _handshake(role: str, metrics, **fields) -> None:
     """The one JSON line every role prints once it is up: ports and
-    ids, whether the native codec loaded (built here if need be), and
+    ids, whether the native codec loaded (built and checked here if
+    need be) and which crc32c loop this CPU gets from it (also the
+    gauge ``codec_crc32c_impl{impl}`` of the role's ``metrics``), and
     what JAX sees.  The meta never asks for devices and the serving
     replica never imports jax — they say only that much (``fields``)."""
-    from risingwave_tpu.storage.codec import native_available
+    from risingwave_tpu.storage.codec import crc32c_impl, native_available
 
-    line = {"role": role, **fields, "native_codec": native_available()}
+    line = {"role": role, **fields, "native_codec": native_available(),
+            "crc32c_impl": crc32c_impl()}
+    metrics.set_gauge("codec_crc32c_impl", 1, impl=line["crc32c_impl"])
     if role in ("single", "compute"):
         import jax
 
@@ -155,7 +159,8 @@ class SingleNode:
         root runs from asking for the engine lock (``tick.lock_wait``
         is what reads and scrapes take out of every tick) to
         ``Engine.tick`` returning."""
-        if not self.engine.jobs:
+        eng = self.engine
+        if not eng.jobs or all(map(eng.ingest_waits, eng.jobs)):
             return  # nothing to drive: no barrier, no tree
         with GLOBAL_TRACE.root("tick", "tick",
                                metrics=self.engine.metrics):
@@ -242,7 +247,8 @@ def _run_meta(args) -> None:
     if args.metrics_port:
         _start_metrics_http(meta.metrics.render_prometheus,
                             args.host, args.metrics_port)
-    _handshake("meta", pgwire_port=args.port, rpc_port=meta.rpc_port,
+    _handshake("meta", meta.metrics, pgwire_port=args.port,
+               rpc_port=meta.rpc_port,
                metrics_port=args.metrics_port or None,
                backend_initialized=meta.state()["backend_initialized"])
 
@@ -296,7 +302,8 @@ def _run_compute(args) -> None:
     if args.metrics_port:
         _start_metrics_http(worker.engine.metrics.render_prometheus,
                             args.host, args.metrics_port)
-    _handshake("compute", worker_id=worker.worker_id, port=worker.port,
+    _handshake("compute", worker.engine.metrics,
+               worker_id=worker.worker_id, port=worker.port,
                metrics_port=args.metrics_port or None)
     try:
         _wait_for_sigint()
@@ -321,7 +328,8 @@ def _run_serving(args) -> None:
                             args.host, args.metrics_port)
     # the engine-free contract: tests parse this line and assert jax
     # never loaded
-    _handshake("serving", replica_id=replica.replica_id,
+    _handshake("serving", replica.metrics,
+               replica_id=replica.replica_id,
                port=replica.port,
                metrics_port=args.metrics_port or None,
                jax_loaded="jax" in sys.modules)
@@ -430,7 +438,7 @@ def main() -> None:
     if args.metrics_port:
         _start_metrics_http(node.render_metrics,
                             args.host, args.metrics_port)
-    _handshake("single", pgwire_port=args.port,
+    _handshake("single", node.engine.metrics, pgwire_port=args.port,
                metrics_port=args.metrics_port or None)
     try:
         _wait_for_sigint()

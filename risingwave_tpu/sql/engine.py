@@ -446,15 +446,24 @@ class Engine:
                 1, int(self.system_params.get("chunks_per_barrier"))
             )
             for _ in range(4096):
-                pending = 0
+                pending, stuck = 0, []
                 for job in self.jobs:
                     srcs = job.sources.values() \
                         if hasattr(job, "sources") else [job.source]
-                    for s in srcs:
-                        if hasattr(s, "pending"):
-                            pending += s.pending()
+                    n = sum(s.pending() for s in srcs
+                            if hasattr(s, "pending"))
+                    pending += n
+                    if n and job.ingest_hold is not None:
+                        stuck.append(f"{job.name}: {job.ingest_hold}")
                 if pending == 0:
                     break
+                if stuck:
+                    # these will not drain: say so now, not 4096
+                    # empty ticks later
+                    raise RuntimeError(
+                        f"FLUSH cannot drain ({pending} rows pending): "
+                        "ingest held at a view's high-water mark ("
+                        + "; ".join(stuck) + ")")
                 self.tick(barriers=1, chunks_per_barrier=cpb)
             else:
                 raise RuntimeError(
@@ -2180,6 +2189,8 @@ class Engine:
             rows = 0
             for _ in range(barriers):
                 for job in self.jobs:
+                    if self.ingest_waits(job, chunks_per_barrier):
+                        continue
                     job.write_stall_hook = stall_hook
                     rows += self._job_barrier(job, chunks_per_barrier,
                                               cadence)
@@ -2194,6 +2205,20 @@ class Engine:
                 self._export_checkpoint_gauges(job)
             sp.set(rows=rows, epoch=max(
                 (j.sealed_epoch for j in self.jobs), default=0))
+
+    def ingest_waits(self, job, chunks_per_barrier: int | None = None
+                     ) -> bool:
+        """Back-pressure at a view's high-water mark
+        (``BarrierLoop.ingest_hold``): a barrier that would bring the
+        job chunks waits, so no key is dropped for want of a slot and
+        the view stays what its source rows make it; a barrier that
+        brings none (``chunks_per_barrier = 0``, the orderly stop)
+        crosses, and its maintenance pass lifts the hold once the view
+        has room."""
+        if chunks_per_barrier is None:
+            chunks_per_barrier = int(
+                self.system_params.get("chunks_per_barrier"))
+        return chunks_per_barrier > 0 and job.ingest_hold is not None
 
     def _barrier_cadence(self) -> tuple[int, int, int, int]:
         """(checkpoint_frequency, maintenance interval, snapshot

@@ -39,6 +39,19 @@ pipeline it (stream/checkpoint.py):
 - ``commit()`` — npz/meta encode, object-store writes, manifest bump,
   GC, digest-cache advance.
 
+The epoch object.  ``commit`` writes two objects under two keys:
+``<job>/epoch_<n>.npz``, a ZIP of stored (uncompressed) ``.npy``
+members (``leaf_<i>`` for a full, ``r_<leaf>_<first element>`` a dirty
+run of a delta) that ``np.load`` reads and ``testzip()`` passes, and
+``epoch_<n>.meta``, a pickle.  The manifest records the crc32c of both,
+computed over the bytes before the put (``storage/codec.py``: the CPU's
+instruction where it has one), and every read verifies them.  The
+object is laid out once (``encode_npz``) in a buffer the committing
+thread keeps (``_Arena``; ``CheckpointStore._encode`` owns one a
+thread) and ``put`` is handed a view of it: one copy of the payload,
+one CRC-32 pass (the ZIP's, a member), one crc32c pass, no allocation
+that scales with the object after the buffer's first growth.
+
 ``save()`` remains the synchronous composition of both.  A manifest
 lock serializes commits across jobs (one engine hosts several jobs,
 each with its own uploader thread, over ONE manifest file).
@@ -56,6 +69,7 @@ import json
 import os
 import pickle
 import threading
+import zipfile
 from typing import Any
 
 import jax
@@ -63,6 +77,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from risingwave_tpu.common.trace import GLOBAL_TRACE
+from risingwave_tpu.storage.codec import crc32c_impl
 from risingwave_tpu.storage.digest import (
     DEFAULT_BLOCK_ELEMS,
     digest_leaves,
@@ -96,6 +111,82 @@ def _dirty_runs(leaf_dirty: np.ndarray, rows: int, m: int, block: int):
         for b, e in zip(edges[::2], edges[1::2]):
             yield (r * m + int(b) * block,
                    r * m + min(int(e) * block, m), int(e - b))
+
+
+class _Arena:
+    """The seekable file ``zipfile`` writes an epoch object into: one
+    byte buffer that is KEPT between objects, so an object's bytes are
+    copied once, into memory that is already mapped.  (``np.savez``
+    into a fresh ``BytesIO`` copied every array twice more — numpy's
+    ``tobytes()`` chunks, ``getvalue()`` — into buffers grown by
+    reallocation: 70 MB of page faults a commit.)"""
+
+    def __init__(self):
+        self._buf = np.empty(0, np.uint8)
+        self._pos = self._end = 0
+
+    def start(self, capacity: int) -> None:
+        """Begin a new object; ``capacity`` is a guess at its size (a
+        wrong one costs a copy, once)."""
+        self._pos = self._end = 0
+        self._reserve(capacity)
+
+    def _reserve(self, n: int) -> None:
+        if n > self._buf.size:
+            grown = np.empty(max(n, self._buf.size * 3 // 2), np.uint8)
+            grown[:self._end] = self._buf[:self._end]
+            self._buf = grown
+
+    def write(self, data) -> int:
+        src = np.frombuffer(data, np.uint8)
+        end = self._pos + src.size
+        self._reserve(end)
+        self._buf[self._pos:end] = src
+        self._pos = end
+        self._end = max(self._end, end)
+        return src.size
+
+    def seek(self, offset: int) -> int:
+        """From the start: all ``zipfile`` asks for when it writes."""
+        self._pos = offset
+        return offset
+
+    def tell(self) -> int:
+        return self._pos
+
+    def flush(self) -> None:
+        pass
+
+    def view(self) -> memoryview:
+        """The object written since ``start``: a view, valid until the
+        next ``start`` on this arena."""
+        return memoryview(self._buf)[:self._end]
+
+
+def encode_npz(arena: _Arena, payload: dict[str, np.ndarray]) -> memoryview:
+    """``payload`` as ``np.savez`` would write it — a ZIP of stored
+    ``<name>.npy`` members, each with its CRC-32, that ``np.load``
+    reads and ``testzip()`` passes — laid out in ``arena``: every
+    array's bytes are copied once, to their place in the object, and
+    read once more by zlib's CRC-32 as the format demands."""
+    arena.start(sum(a.nbytes for a in payload.values())
+                + sum(2 * len(k) + 512 for k in payload) + 256)
+    with zipfile.ZipFile(arena, "w", zipfile.ZIP_STORED,
+                         allowZip64=True) as zf:
+        for name, a in payload.items():
+            if a.dtype.hasobject:  # its bytes are pointers
+                raise TypeError(f"{name}: object arrays have no place "
+                                "in a checkpoint")
+            with zf.open(name + ".npy", "w", force_zip64=True) as member:
+                np.lib.format.write_array_header_1_0(member, {
+                    "descr": np.lib.format.dtype_to_descr(a.dtype),
+                    "fortran_order": False, "shape": a.shape,
+                })
+                # the one copy (a strided or 0-d array is made
+                # contiguous first: one more, of that array alone)
+                member.write(
+                    np.ascontiguousarray(a).reshape(-1).view(np.uint8))
+    return arena.view()
 
 
 class CheckpointStore:
@@ -132,6 +223,13 @@ class CheckpointStore:
         #: serializes manifest read-modify-write + digest-cache updates
         #: across uploader threads (several jobs share one manifest)
         self._lock = threading.RLock()
+        #: ``.arena``: the buffer a committing thread lays its epoch
+        #: objects out in (``encode_npz``).  One a thread, because one
+        #: thread encodes and puts one object at a time and the store
+        #: is shared by several jobs' uploaders; it grows to the
+        #: largest object that thread has written and goes with the
+        #: thread, or with the store
+        self._encode = threading.local()
 
     def _abs(self, key: str) -> str:
         """Filesystem path for a key when the backend is local (the
@@ -422,10 +520,14 @@ class CheckpointStore:
         cache — the durable commit point the uploader acks."""
         job_name, epoch, kind = prep["job"], prep["epoch"], prep["kind"]
         key = f"{job_name}/epoch_{epoch}"
-        with GLOBAL_TRACE.span("ckpt_commit.encode", job=job_name):
-            buf = io.BytesIO()
-            np.savez(buf, **prep["payload"])
-            npz_bytes = buf.getvalue()
+        with GLOBAL_TRACE.span("ckpt_commit.encode",
+                               job=job_name) as span:
+            arena = getattr(self._encode, "arena", None)
+            if arena is None:
+                arena = self._encode.arena = _Arena()
+            # a view of this thread's arena: good until this thread's
+            # next commit, and the puts below are done by then
+            npz_bytes = encode_npz(arena, prep["payload"])
             meta_bytes = pickle.dumps({
                 "treedef": prep["treedef"],
                 "source_state": prep["source_state"],
@@ -436,6 +538,8 @@ class CheckpointStore:
             # put corrupted in flight — or on disk later — mismatches
             # on read and the typed CheckpointCorruption fires)
             crc = {"npz": crc32c(npz_bytes), "meta": crc32c(meta_bytes)}
+            span.set(bytes=len(npz_bytes) + len(meta_bytes),
+                     impl=crc32c_impl())
         with self._manifest_txn():
             with GLOBAL_TRACE.span("ckpt_commit.put", job=job_name,
                                    bytes=len(npz_bytes) + len(meta_bytes)):

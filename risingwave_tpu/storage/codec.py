@@ -2,15 +2,32 @@
 
 The C++ library (native/rwtpu_codec.cpp) implements the hot host-side
 loops: memcomparable scalar encoding, varint block encode/decode,
-crc32c.  Built on first use with g++ and cached beside the source; a
-pure-numpy fallback keeps the storage layer functional without a
-toolchain — a failed build or load is logged once with its reason and
-``native_available()`` says false.
+crc32c.  A pure-numpy fallback keeps the storage layer functional
+without a toolchain — a failed build or load is logged once with its
+reason and ``native_available()`` says false.
+
+How the library is built, named and checked.  It is built on first use
+with ``g++ -O3 -shared -fPIC`` — no ``-march``, no ISA flag: a library
+built on one x86-64 host must run on any other, and the crc32c loop is
+picked at load from what the running CPU reports
+(``crc32c_impl()``: ``"hw"`` the CPU's instruction, ``"slice8"`` the
+portable table loop, ``"python"`` no library at all).  It is cached
+beside the source as ``librwtpu_codec-<hash>.so``, the hash being of
+the source's CONTENT: a library left by another source, however new
+its mtime, is never opened (``native/*.so`` is git-ignored, so a copied
+working tree carries whatever was built last).  Whatever is opened
+answers a known-answer check of every function's symbol and of
+``rw_crc32c`` before anyone uses it; one that fails is rebuilt once,
+then given up for the fallback.  The fallback's crc32c is a table loop
+in Python, ~10 MB/s: it refuses more than ``PY_CRC32C_MAX`` bytes with
+``NativeCodecRequired`` — a server must not silently spend minutes a
+checkpoint.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sys
@@ -22,11 +39,101 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)
 )))
 _SRC = os.path.join(_REPO_ROOT, "native", "rwtpu_codec.cpp")
-_SO = os.path.join(_REPO_ROOT, "native", "librwtpu_codec.so")
+
+#: crc32c known answers (Castagnoli, reflected; RFC 3720 B.4)
+CRC32C_KNOWN = (
+    (b"123456789", 0xE3069283),
+    (bytes(32), 0x8A9136AA),
+    (b"\xff" * 32, 0x62A8AB43),
+)
+#: the most the Python crc32c takes on: a couple of seconds' worth
+PY_CRC32C_MAX = 16 << 20
 
 _lock = threading.Lock()
 _lib = None
 _native_failed = False
+
+
+class NativeCodecRequired(RuntimeError):
+    """The native library is missing and the input is too large for
+    the Python fallback to finish in seconds."""
+
+
+def _so_path(src: str, lib_dir: str) -> str:
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(lib_dir, f"librwtpu_codec-{digest}.so")
+
+
+def _build(src: str, so: str) -> None:
+    # several roles may start on one checkout: build under a name of
+    # this process's own and rename into place, so nobody ever loads a
+    # half-written file
+    tmp = f"{so}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(["g++", "-O3", "-shared", "-fPIC", src, "-o", tmp],
+                       check=True, capture_output=True)
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+
+
+def _bind(lib) -> None:
+    """Declare every function (a missing symbol raises here) and hold
+    ``rw_crc32c`` to its known answers."""
+    u8p = ctypes.POINTER(ctypes.c_uint8)
+    i64p = ctypes.POINTER(ctypes.c_int64)
+    f64p = ctypes.POINTER(ctypes.c_double)
+    lib.mc_encode_i64.argtypes = [i64p, ctypes.c_int64, u8p]
+    lib.mc_decode_i64.argtypes = [u8p, ctypes.c_int64, i64p]
+    lib.mc_encode_f64.argtypes = [f64p, ctypes.c_int64, u8p]
+    lib.mc_decode_f64.argtypes = [u8p, ctypes.c_int64, f64p]
+    lib.block_encode.argtypes = [u8p, i64p, u8p, i64p,
+                                 ctypes.c_int64, u8p, ctypes.c_int64]
+    lib.block_encode.restype = ctypes.c_int64
+    lib.block_scan.argtypes = [u8p, ctypes.c_int64, i64p, i64p, i64p]
+    lib.block_scan.restype = ctypes.c_int64
+    lib.block_decode.argtypes = [u8p, ctypes.c_int64, u8p, i64p,
+                                 u8p, i64p]
+    lib.block_decode.restype = ctypes.c_int64
+    lib.rw_crc32c.argtypes = [u8p, ctypes.c_int64]
+    lib.rw_crc32c.restype = ctypes.c_uint32
+    lib.rw_crc32c_impl.argtypes = []
+    lib.rw_crc32c_impl.restype = ctypes.c_char_p
+    lib.rw_crc32c_with.argtypes = [ctypes.c_char_p, u8p, ctypes.c_int64,
+                                   ctypes.POINTER(ctypes.c_int32)]
+    lib.rw_crc32c_with.restype = ctypes.c_uint32
+    for data, want in CRC32C_KNOWN:
+        arr = np.frombuffer(data, np.uint8)
+        got = int(lib.rw_crc32c(_u8(arr), len(arr)))
+        if got != want:
+            raise RuntimeError(
+                f"rw_crc32c({data[:9]!r}..) = {got:#x}, not {want:#x}")
+
+
+def open_library(src: str, lib_dir: str):
+    """The library of ``src``, from ``lib_dir`` if the one there is of
+    this source and answers right, else built there (once: a library
+    that still fails after its rebuild raises)."""
+    import _ctypes
+
+    so = _so_path(src, lib_dir)
+    for rebuilt in (False, True):
+        if rebuilt or not os.path.exists(so):
+            _build(src, so)
+        lib = None
+        try:
+            lib = ctypes.CDLL(so)
+            _bind(lib)
+            return lib
+        except (OSError, AttributeError, RuntimeError):
+            if rebuilt:
+                raise
+            if lib is not None:
+                # or the loader hands the same mapping back for the
+                # rebuilt file of the same name
+                _ctypes.dlclose(lib._handle)
 
 
 def _load():
@@ -37,41 +144,7 @@ def _load():
         if _lib is not None or _native_failed:
             return _lib
         try:
-            if (not os.path.exists(_SO)
-                    or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-                # several roles may start on one checkout: build under
-                # a name of this process's own and rename into place,
-                # so nobody ever loads a half-written file
-                tmp = f"{_SO}.{os.getpid()}.tmp"
-                try:
-                    subprocess.run(
-                        ["g++", "-O3", "-shared", "-fPIC", _SRC,
-                         "-o", tmp],
-                        check=True, capture_output=True,
-                    )
-                    os.replace(tmp, _SO)
-                finally:
-                    if os.path.exists(tmp):
-                        os.unlink(tmp)
-            lib = ctypes.CDLL(_SO)
-            u8p = ctypes.POINTER(ctypes.c_uint8)
-            i64p = ctypes.POINTER(ctypes.c_int64)
-            f64p = ctypes.POINTER(ctypes.c_double)
-            lib.mc_encode_i64.argtypes = [i64p, ctypes.c_int64, u8p]
-            lib.mc_decode_i64.argtypes = [u8p, ctypes.c_int64, i64p]
-            lib.mc_encode_f64.argtypes = [f64p, ctypes.c_int64, u8p]
-            lib.mc_decode_f64.argtypes = [u8p, ctypes.c_int64, f64p]
-            lib.block_encode.argtypes = [u8p, i64p, u8p, i64p,
-                                         ctypes.c_int64, u8p, ctypes.c_int64]
-            lib.block_encode.restype = ctypes.c_int64
-            lib.block_scan.argtypes = [u8p, ctypes.c_int64, i64p, i64p, i64p]
-            lib.block_scan.restype = ctypes.c_int64
-            lib.block_decode.argtypes = [u8p, ctypes.c_int64, u8p, i64p,
-                                         u8p, i64p]
-            lib.block_decode.restype = ctypes.c_int64
-            lib.rw_crc32c.argtypes = [u8p, ctypes.c_int64]
-            lib.rw_crc32c.restype = ctypes.c_uint32
-            _lib = lib
+            _lib = open_library(_SRC, os.path.dirname(_SRC))
         except Exception as e:
             _native_failed = True
             reason = getattr(e, "stderr", None) or e
@@ -84,6 +157,14 @@ def _load():
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def crc32c_impl() -> str:
+    """Which loop ``crc32c`` runs in this process: ``"hw"`` (the CPU's
+    crc32c instruction), ``"slice8"`` (the library's table loop) or
+    ``"python"`` (no library)."""
+    lib = _load()
+    return lib.rw_crc32c_impl().decode() if lib is not None else "python"
 
 
 def _u8(a: np.ndarray):
@@ -217,18 +298,51 @@ def block_decode(data: bytes):
     return keys, ko, vals, vo
 
 
-def crc32c(data: bytes) -> int:
+def crc32c(data) -> int:
+    """crc32c of any contiguous buffer of bytes."""
     lib = _load()
     arr = np.frombuffer(data, np.uint8)
     if lib is not None:
-        return int(lib.rw_crc32c(_u8(np.ascontiguousarray(arr)), len(arr)))
-    # fallback: python crc32c (slow but correct)
-    poly = 0x82F63B78
+        return int(lib.rw_crc32c(_u8(arr), len(arr)))
+    return crc32c_py(arr)
+
+
+def crc32c_with(impl: str, data) -> int | None:
+    """crc32c by one named loop of the library (``"hw"``, ``"slice8"``,
+    ``"bytewise"``: the definition, a table lookup a byte); None where
+    this CPU or this build lacks it."""
+    lib = _load()
+    if lib is None:
+        return None
+    arr = np.frombuffer(data, np.uint8)
+    ok = ctypes.c_int32()
+    got = lib.rw_crc32c_with(impl.encode(), _u8(arr), len(arr),
+                             ctypes.byref(ok))
+    return int(got) if ok.value else None
+
+
+_PY_CRC_TABLE: list[int] = []
+
+
+def crc32c_py(data) -> int:
+    """The fallback: a table lookup a byte, in Python (~10 MB/s)."""
+    data = bytes(data)
+    if len(data) > PY_CRC32C_MAX:
+        raise NativeCodecRequired(
+            f"crc32c of {len(data)} bytes without the native codec "
+            f"(the Python loop takes on {PY_CRC32C_MAX}): the library "
+            f"did not build or load, see this process's stderr")
+    if not _PY_CRC_TABLE:
+        table = []
+        for c in range(256):
+            for _ in range(8):
+                c = (0x82F63B78 ^ (c >> 1)) if c & 1 else (c >> 1)
+            table.append(c)
+        _PY_CRC_TABLE[:] = table
+    table = _PY_CRC_TABLE
     c = 0xFFFFFFFF
     for b in data:
-        c ^= b
-        for _ in range(8):
-            c = (poly ^ (c >> 1)) if c & 1 else (c >> 1)
+        c = table[(c ^ b) & 0xFF] ^ (c >> 8)
     return c ^ 0xFFFFFFFF
 
 

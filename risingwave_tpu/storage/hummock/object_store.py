@@ -113,7 +113,14 @@ class ObjectStore:
     faults: StoreFaults | None = None
 
     # -- interface ------------------------------------------------------
-    def put(self, key: str, data: bytes) -> None:
+    def put(self, key: str, data) -> None:
+        """Store ``data`` under ``key``.  ``data`` is ``bytes`` or a
+        view (``memoryview``) of a buffer its owner overwrites once
+        this returns — ``CheckpointStore.commit`` hands in a view of
+        the epoch buffer it keeps.  So an implementation reads all of
+        it before it returns and keeps no reference: one that queues,
+        uploads in parts or retries later takes ``bytes(data)`` first,
+        or it lands bytes the manifest's crc32c no longer matches."""
         raise NotImplementedError
 
     def get(self, key: str) -> bytes:

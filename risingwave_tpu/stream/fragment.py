@@ -46,6 +46,11 @@ GAUGE_ATTRS = ("live_groups", "tombstones", "table_slots")
 JOIN_TALLY_ATTRS = ("insert_rows", "probe_steps", "emit_rows",
                     "cleaned_rows", "reclaim_slots")
 JOIN_GAUGE_ATTRS = ("live_rows", "tombstones", "table_slots")
+#: a pk-keyed view's levels, from ``MaterializeExecutor.levels`` (no
+#: field of its state: a checkpoint keeps its leaves): gauges
+#: ``materialize_<attr>{job}``, and what ``BarrierLoop`` holds a job's
+#: ingest by before the view overflows
+VIEW_GAUGE_ATTRS = ("used_slots", "view_slots")
 
 
 def executor_scope(i: int, ex, phase: str):
@@ -75,6 +80,10 @@ def collect_counters(executors, states):
             if hasattr(ex, "pending_flush"):
                 labels.append(f"{ex}.pending")
                 vals.append(ex.pending_flush(st).astype(jnp.int64))
+            if hasattr(ex, "levels"):
+                for attr, v in zip(VIEW_GAUGE_ATTRS, ex.levels(st)):
+                    labels.append(f"{ex}.{attr}")
+                    vals.append(v.astype(jnp.int64))
     vec = jnp.stack(vals) if vals else jnp.zeros((0,), jnp.int64)
     return labels, vec
 

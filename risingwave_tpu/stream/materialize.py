@@ -156,6 +156,14 @@ class MaterializeExecutor(Executor):
         return MvState(table, values, state.overflow + n_over), chunk
 
     # -- maintenance ----------------------------------------------------
+    def levels(self, state: MvState):
+        """``fragment.VIEW_GAUGE_ATTRS`` on the barrier's counters
+        vector: the slots no new key can claim (a row's, or a
+        tombstone's until the next rehash) and the table's slots."""
+        used = state.table.occupied | state.table.tombstone
+        return (jnp.sum(used.astype(jnp.int32)),
+                jnp.asarray(self.table_size, jnp.int32))
+
     def maybe_rehash(self, state: MvState) -> MvState:
         """Rebuild the pk table once tombstones dominate (traceable:
         lax.cond on the device tombstone count, no host readback)."""

@@ -25,6 +25,7 @@ here, ``DagJob``, ``ShardedStreamingJob``) are device programs + hooks.
 
 from __future__ import annotations
 
+import sys
 from dataclasses import dataclass
 from typing import Any
 
@@ -39,8 +40,15 @@ from risingwave_tpu.stream.fragment import (
     GAUGE_ATTRS,
     JOIN_GAUGE_ATTRS,
     TALLY_ATTRS,
+    VIEW_GAUGE_ATTRS,
 )
 from risingwave_tpu.stream.message import Barrier, BarrierKind
+
+#: a pk-keyed view holds its job's ingest (``BarrierLoop.ingest_hold``)
+#: once its used slots, and what they have grown by between two
+#: maintenance passes at most, pass this share of its table: before a
+#: key finds no slot and the barrier raises with rows dropped
+VIEW_HIGH_WATER = 7 / 8
 
 
 @dataclass
@@ -75,7 +83,10 @@ class BarrierLoop:
     the upload ACKS.  Without a durable store, seal and commit coincide
     (the shadow IS the commit).  The loop stalls only when the uploader
     falls more than ``upload_window`` epochs behind — the checkpoint
-    analog of the L0-depth write stall.
+    analog of the L0-depth write stall.  A pk-keyed view that nears the
+    end of its table sets ``ingest_hold`` at the maintenance pass that
+    sees it (``_hold_at_high_water``); whoever drives the loop lets the
+    barriers that would bring chunks wait (``Engine.ingest_waits``).
 
     Hooks a runtime provides: ``_cross_barrier``, ``_run_maintain``,
     ``counter_labels``, ``_init_states``, ``run_chunk``; where it
@@ -123,6 +134,15 @@ class BarrierLoop:
         self.committed_epoch: int = 0
         self.sealed_epoch = 0
         self.paused = False
+        #: why this job takes no chunk (a view at its high-water mark,
+        #: by the last maintenance pass), else None.  The driver of the
+        #: loop (``Engine.tick``) lets a barrier that brings chunks
+        #: wait; one that brings none crosses, and the next pass looks
+        #: again
+        self.ingest_hold: str | None = None
+        #: view label -> (used slots at the last pass, most they grew
+        #: by between two passes)
+        self._view_levels: dict[str, tuple[int, int]] = {}
         #: counters vector from the last barrier program (device array;
         #: read back once per maintenance interval)
         self._counters = None
@@ -282,6 +302,7 @@ class BarrierLoop:
                 self.name, self.counter_labels, values, self.metrics,
                 self.counter_sides,
             )
+            self._hold_at_high_water(values)
             # residual pending beyond MAX_DRAIN_ROUNDS×emit_capacity
             # per barrier: pathological; finish draining with host loops
             for _ in range(64):
@@ -293,6 +314,34 @@ class BarrierLoop:
                     np.asarray(self._counters), self.metrics,
                     self.counter_sides,
                 )
+
+    def _hold_at_high_water(self, values: np.ndarray) -> None:
+        """Set or lift ``ingest_hold`` from the views' levels on the
+        counters vector (``fragment.VIEW_GAUGE_ATTRS``)."""
+        at = dict(zip(self.counter_labels, values))
+        hold = None
+        for label, used in at.items():
+            view, _, attr = label.rpartition(".")
+            if attr != VIEW_GAUGE_ATTRS[0]:
+                continue
+            used = int(used)
+            slots = int(at[f"{view}.{VIEW_GAUGE_ATTRS[1]}"])
+            # a first look (a new or recovered job) knows no growth
+            before, grew = self._view_levels.get(view, (used, 0))
+            grew = max(grew, used - before)
+            self._view_levels[view] = (used, grew)
+            if used + grew > VIEW_HIGH_WATER * slots:
+                hold = (f"{view}: {used} of {slots} slots used, "
+                        f"{grew} more a maintenance pass")
+        if hold is not None and self.ingest_hold is None:
+            print(f"{self.name}: ingest held at the view's high-water "
+                  f"mark ({hold}); barriers that bring chunks wait — "
+                  "increase table/bucket capacity",
+                  file=sys.stderr, flush=True)
+        self.ingest_hold = hold
+        if self.metrics is not None:
+            self.metrics.set_gauge("stream_ingest_held",
+                                   int(hold is not None), job=self.name)
 
     def _commit_checkpoint(self, epoch_val) -> None:
         """Seal one snapshot epoch: spill drain + sink delivery + the
@@ -454,6 +503,10 @@ class BarrierLoop:
         checkpoints live under ``ckpt_key`` — a partition's lineage,
         not the job name."""
         self._counters = None
+        # the rewound view's levels are the next maintenance pass's to
+        # read: a hold of the state that is gone must not outlive it
+        self.ingest_hold = None
+        self._view_levels.clear()
         if self._uploader is not None:
             self._uploader.drain(raise_error=False)
             self._process_upload_acks()
@@ -519,11 +572,14 @@ def check_counter_values(name: str, labels: list[str],
         sums: dict[str, int] = {}
         tallies: dict[str, int] = {}
         joins: dict[tuple, int] = {}
+        views: dict[str, int] = {}
         for kind, side, v in zip(kinds, join_side, values):
             if side is not None:
                 joins[kind, side] = joins.get((kind, side), 0) + int(v)
             elif kind in TALLY_ATTRS + GAUGE_ATTRS:
                 tallies[kind] = tallies.get(kind, 0) + int(v)
+            elif kind in VIEW_GAUGE_ATTRS:
+                views[kind] = views.get(kind, 0) + int(v)
             elif kind != "pending":
                 sums[kind] = sums.get(kind, 0) + int(v)
         for (kind, side), v in joins.items():
@@ -536,6 +592,8 @@ def check_counter_values(name: str, labels: list[str],
         for kind, v in sums.items():
             metrics.set_gauge("maintenance_counter_rows", v,
                               job=name, kind=kind)
+        for kind, v in views.items():
+            metrics.set_gauge(f"materialize_{kind}", v, job=name)
         for kind, v in tallies.items():
             if kind in GAUGE_ATTRS:
                 metrics.set_gauge(f"hash_agg_{kind}", v, job=name)
@@ -543,7 +601,8 @@ def check_counter_values(name: str, labels: list[str],
                 metrics.set_counter(f"hash_agg_{kind}_total", v, job=name)
     residual = []
     for label, kind, side, v in zip(labels, kinds, join_side, values):
-        if side is not None or kind in TALLY_ATTRS + GAUGE_ATTRS:
+        if side is not None \
+                or kind in TALLY_ATTRS + GAUGE_ATTRS + VIEW_GAUGE_ATTRS:
             continue
         if kind == "pending":
             if v > 0:

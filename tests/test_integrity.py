@@ -192,6 +192,34 @@ def test_checkpoint_repair_lineage_truncates(tmp_path):
     assert store.load("j")[0] == 3
 
 
+@pytest.mark.parametrize("mode", ["bit_flip", "truncate"])
+@pytest.mark.parametrize("local", [True, False],
+                         ids=["local_fs", "in_mem"])
+def test_checkpoint_put_damaged_in_flight_is_caught(tmp_path, mode, local):
+    """``commit`` hands ``put`` a view of the buffer it keeps, and the
+    manifest the crc32c of the bytes BEFORE the put: a put that lands
+    damaged bytes (the fault hook copies the view, only when armed)
+    mismatches on read, and the buffer's next object is intact."""
+    from risingwave_tpu.storage.checkpoint_store import CheckpointStore
+    from risingwave_tpu.storage.hummock.object_store import (
+        LocalFsObjectStore,
+    )
+
+    faults = StoreFaults(seed=3)
+    faults.fail("put", substr="epoch_2.npz", mode=mode, times=1)
+    obj = LocalFsObjectStore(str(tmp_path), faults=faults) if local \
+        else InMemObjectStore(faults=faults)
+    store = CheckpointStore(str(tmp_path), keep_epochs=8, full_interval=1,
+                            object_store=obj)
+    _save_epochs(store, "j", 3)
+    assert faults.injected_corruptions == 1
+    assert [e for e, _ in store.verify_job("j")["corrupt"]] == [2]
+    with pytest.raises(CheckpointCorruption):
+        store.load("j", 2)
+    for e in (1, 3):
+        assert int(np.asarray(store.load("j", e)[1]["b"])[0]) == e
+
+
 # -- deterministic corruption faults ------------------------------------
 def test_store_faults_bit_flip_and_truncate_deterministic():
     def run():

@@ -193,7 +193,8 @@ def test_entry_points_import_without_a_backend():
 
 def test_single_node_handshake_and_config_json(tmp_path):
     """The default role prints one JSON handshake line like the others
-    — with the device it sees and whether the native codec loaded — and
+    — with the device it sees, whether the native codec loaded and
+    which crc32c loop it picked (``/metrics`` says that too) — and
     honours ``--config-json``; SIGINT stops it in order, exit code 0."""
     import json
     import os
@@ -201,14 +202,17 @@ def test_single_node_handshake_and_config_json(tmp_path):
     import socket
     import subprocess
     import sys
+    import urllib.request
 
-    with socket.socket() as s:
+    with socket.socket() as s, socket.socket() as s2:
         s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]
+        s2.bind(("127.0.0.1", 0))
+        port, mport = s.getsockname()[1], s2.getsockname()[1]
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.Popen(
         [sys.executable, "-m", "risingwave_tpu.server", "--port",
-         str(port), "--data-dir", str(tmp_path), "--config-json",
+         str(port), "--metrics-port", str(mport),
+         "--data-dir", str(tmp_path), "--config-json",
          json.dumps({"streaming": {"chunk_size": 256},
                      "state": {"join_pool_size": 1 << 12}})],
         cwd=root, stdout=subprocess.PIPE, text=True)
@@ -217,6 +221,11 @@ def test_single_node_handshake_and_config_json(tmp_path):
         assert hs["role"] == "single" and hs["pgwire_port"] == port
         assert hs["platform"] == "cpu" and hs["device_count"] >= 1
         assert hs["native_codec"] is True
+        assert hs["crc32c_impl"] in ("hw", "slice8")
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{mport}/metrics", timeout=60) as r:
+            assert ('codec_crc32c_impl{impl="%s"} 1' % hs["crc32c_impl"]
+                    in r.read().decode().splitlines())
         c = MiniPgClient("127.0.0.1", port)
         c.query("CREATE TABLE t (k BIGINT, v BIGINT)")
         c.query("CREATE MATERIALIZED VIEW m AS "

@@ -75,6 +75,33 @@ def test_sst_write_read_scan(tmp_path):
     assert [k for k, _ in got] == keys[1000:1010]
 
 
+@pytest.mark.parametrize("writer,reader", [
+    ("bytewise", None), (None, "bytewise"), ("slice8", "bytewise"),
+])
+def test_sst_checksums_do_not_depend_on_the_crc32c_loop(
+        tmp_path, monkeypatch, writer, reader):
+    """An SST written under one crc32c loop (the parent's was a table
+    lookup a byte) verifies under another, block trailers, index and
+    bloom hashes: None is the loop this CPU gets."""
+    def use(impl):
+        if impl is None:
+            monkeypatch.undo()
+        else:
+            monkeypatch.setattr(
+                codec, "crc32c", lambda b: codec.crc32c_with(impl, b))
+
+    keys = [f"{i:06d}".encode() for i in range(3000)]
+    vals = [f"v{i}".encode() * 3 for i in range(3000)]
+    path = str(tmp_path / "t.sst")
+    use(writer)
+    write_sst(path, keys, vals, block_bytes=512)
+    use(reader)
+    r = SstReader(path)
+    assert r.get(b"002999") == vals[2999]
+    assert r.get(b"zzz") is None
+    assert list(r.scan(b"000000", b"999999")) == list(zip(keys, vals))
+
+
 def test_sst_merge_scan_newest_wins(tmp_path):
     old = str(tmp_path / "old.sst")
     new = str(tmp_path / "new.sst")
