@@ -23,10 +23,10 @@ Mechanics here (cluster/meta_service drives the protocol):
    scatters the donor's per-slot arrays at the claimed slots.
 
 Eligible state shapes: ``HashAggExecutor`` (prims / row_count / prev
-snapshot / emitted / dirty / minput buckets — everything slot-aligned)
-and ``MaterializeExecutor`` (pk table + dense value columns).  The
-engine's eligibility gate guarantees no DISTINCT dedup tables and an
-empty spill ring; both are asserted loudly here anyway.
+snapshot / emitted / dirty — everything slot-aligned) and
+``MaterializeExecutor`` (pk table + dense value columns).  The
+engine's eligibility gate guarantees no DISTINCT dedup or materialised-
+input tables and an empty spill ring; asserted loudly here anyway.
 
 Exchange-lite (round 14) extends the same contract to partitioned
 JOIN jobs and MV-on-MV DAGs: ``partition_sites`` walks a ``DagJob``'s
@@ -128,10 +128,6 @@ def slice_partition_states(executors, states, vnodes,
                 "prev_row_count": np.asarray(st.prev_row_count)[idx],
                 "dirty": np.asarray(st.dirty)[idx],
                 "emitted": np.asarray(st.emitted)[idx],
-                "minput_vals": [np.asarray(v)[idx]
-                                for v in st.minput_vals],
-                "minput_occ": [np.asarray(o)[idx]
-                               for o in st.minput_occ],
             }
         elif isinstance(ex, MaterializeExecutor):
             take = _entry_mask(st.table, vnodes, n_vnodes)
@@ -171,8 +167,6 @@ def clear_vnodes(executors, states, vnodes, n_vnodes: int):
                 prev_row_count=jnp.where(stale, 0, st.prev_row_count),
                 dirty=st.dirty & ~stale,
                 emitted=st.emitted & ~stale,
-                minput_occ=tuple(o & ~stale[:, None]
-                                 for o in st.minput_occ),
             )
         elif isinstance(ex, MaterializeExecutor):
             vn = vnodes_of_ints(
@@ -425,14 +419,6 @@ def transplant(executors, states, slices: dict[int, dict]):
                     _to_dev(sl["dirty"]), mode="drop"),
                 emitted=st.emitted.at[slots].set(
                     _to_dev(sl["emitted"]), mode="drop"),
-                minput_vals=tuple(
-                    mv.at[slots].set(_to_dev(v), mode="drop")
-                    for mv, v in zip(st.minput_vals, sl["minput_vals"])
-                ),
-                minput_occ=tuple(
-                    mo.at[slots].set(_to_dev(o), mode="drop")
-                    for mo, o in zip(st.minput_occ, sl["minput_occ"])
-                ),
             )
         else:
             values = tuple(

@@ -38,7 +38,19 @@ class StorageConfig:
 @dataclass
 class StateConfig:
     """capacity knobs for device state tables (the planner's sizes;
-    ``sql.planner.PlannerConfig`` is this plus the chunk capacity)."""
+    ``sql.planner.PlannerConfig`` is this plus the chunk capacity).
+
+    What the planner can bound it sizes itself and reads no key for
+    (ROADMAP D8; ``sql/planner.py`` ``_agg_sizes`` and ``resolve_join``):
+    an aggregate by a window column over an aggregate's output (a group
+    a window the watermark has not closed), the materialised input of a
+    min/max over a retractable input (a slot an input row), a join side
+    fed by an aggregate (a slot a group).  The join's three stores are
+    picked from the plan, not from here: append-only -> the ring
+    (``join_pool_size``); retractable with its join key a proper part
+    of its stream key, against a side that holds a row a key -> keyed
+    by the stream key; any other retractable side -> dense buckets
+    (``join_*_table_size`` x ``join_*_bucket_cap``)."""
 
     agg_table_size: int = 1 << 16
     agg_emit_capacity: int = 4096
@@ -53,7 +65,8 @@ class StateConfig:
     #: sides — replaces dense [size, bucket] buckets so hot keys have
     #: no per-key cap (ref JoinHashMap's unbounded per-key rows)
     join_pool_size: int = 1 << 16
-    #: force dense per-key bucket storage even for append-only sides.
+    #: force dense per-key bucket storage for every side (a veto: it
+    #: never selects a store, the plan's shape does).
     #: Pool sides bound emission drains by the POOL size, which makes
     #: `max_windows` large; on deep multiway plans (TPC-H q8/q9) the
     #: drain while_loop bodies then embed the downstream subgraph and
@@ -65,10 +78,10 @@ class StateConfig:
     topn_emit_capacity: int = 1024
     mv_table_size: int = 1 << 16
     mv_ring_size: int = 1 << 20
-    #: per-group value capacity for retractable min/max (ref minput.rs)
-    minput_bucket_cap: int = 64
-    #: dedup-table size per DISTINCT agg call (None = agg_table_size);
-    #: sized for groups x distinct values, not groups
+    #: size of an aggregate's counted (group, value) tables: the dedup
+    #: table of a DISTINCT call and the materialised input of a min/max
+    #: over a retractable input (ref distinct.rs, minput.rs).  None =
+    #: agg_table_size; sized for groups x distinct values, not groups
     distinct_table_size: int | None = None
     #: overflow-row ring capacity for non-windowed aggs (None = 4x
     #: chunk_capacity; 0 disables spill-to-host — overflow is then a

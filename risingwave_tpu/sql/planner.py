@@ -80,6 +80,17 @@ class PlannedInput:
     #: the filter's time column (its own schema's position): what the
     #: runtime looks for upstream (``DagJob._upstream_wm``)
     wm_src_col: "int | None" = None
+    #: how far (us) the source's watermark trails its newest event
+    wm_delay: "int | None" = None
+    #: sizes the planner can state (ROADMAP D8).  ``max_rows``: an upper
+    #: bound on the rows this input's changelog holds live (an
+    #: aggregate's output: its table's slots).  ``live_keys``: column
+    #: position -> an upper bound on the distinct values of that column
+    #: live at a time (a window column: the windows the watermark has
+    #: not closed, (size + delay) / slide, and two for the barrier in
+    #: flight)
+    max_rows: "int | None" = None
+    live_keys: "dict[int, int] | None" = None
 
 
 @dataclass
@@ -891,6 +902,8 @@ class Planner:
                 if entry.stream_key else None,
                 wm_lags={wm_col: 0} if wm_col is not None else None,
                 wm_src_col=wm_col,
+                wm_delay=entry.watermark[1]
+                if entry.watermark is not None else None,
             )
         if isinstance(from_, (ast.Tumble, ast.Hop)):
             if read_cols is not None:
@@ -918,18 +931,24 @@ class Planner:
             scope = Scope(hop.out_schema, quals)
             # window_start is addressable by the window alias OR the
             # underlying table name (postgres-ish leniency)
-            wm_lags = None
+            wm_lags = live_keys = None
             if inner.wm_lags is not None \
                     and inner.wm_lags.get(ts_idx) is not None:
                 lag = inner.wm_lags[ts_idx]
                 n_in = len(inner.schema)  # window_start, window_end follow
                 wm_lags = {**inner.wm_lags, n_in: lag + size,
                            n_in + 1: lag}
+                if inner.wm_delay is not None:
+                    open_windows = -(-(lag + size + inner.wm_delay)
+                                     // slide) + 2
+                    live_keys = {n_in: open_windows,
+                                 n_in + 1: open_windows}
             return PlannedInput(
                 inner.reader, inner.executors + [hop], scope,
                 hop.out_schema, inner.watermark_col, size,
                 inner.append_only, window_slide=slide,
                 wm_lags=wm_lags, wm_src_col=inner.wm_src_col,
+                wm_delay=inner.wm_delay, live_keys=live_keys,
             )
         raise PlanError(f"unsupported FROM clause {from_!r}")
 
@@ -1288,15 +1307,60 @@ class Planner:
         return any(walk(i.expr) for i in select.items
                    if not isinstance(i.expr, ast.Star))
 
+    @staticmethod
+    def _canonical_groups(group_asts: list, scope: Scope) -> list:
+        """GROUP BY's keys in the order of the columns they name, where
+        all are bare columns: ``GROUP BY a, b`` and ``GROUP BY b, a``
+        are one relation, and planned alike they are one subplan
+        (``_share_subplans``)."""
+        b = Binder(scope)
+        try:
+            bound = [b.bind(g) for g in group_asts]
+        except BindError:
+            return group_asts
+        if not all(isinstance(e, InputRef) for e in bound):
+            return group_asts
+        order = sorted(range(len(bound)), key=lambda i: bound[i].index)
+        return [group_asts[i] for i in order]
+
+    def _agg_sizes(self, pin: PlannedInput, group_by) -> tuple[int, int]:
+        """(group-table slots, materialised-input slots) of an
+        aggregate over ``pin`` (ROADMAP D8).  The group table is
+        ``agg_table_size`` unless the input is a subquery's output and
+        every key is a column whose live values the planner can bound
+        (``PlannedInput.live_keys``: an aggregate by window over an
+        aggregate's output holds a group a window the watermark has not
+        closed): then four times the bound, at least 64.  A materialised-input table holds at most
+        one (group, value) pair an input row, so the input's
+        ``max_rows`` where known, else the group table's size."""
+        cfg = self.config
+        size = cfg.agg_table_size
+        if pin.reader is None and pin.live_keys and all(
+                isinstance(e, InputRef) and e.index in pin.live_keys
+                for _, e in group_by):
+            groups = 1
+            for _, e in group_by:
+                groups *= pin.live_keys[e.index]
+            size = min(size, max(64, 1 << (4 * groups - 1).bit_length()))
+        minput = cfg.distinct_table_size
+        if minput is None:
+            minput = cfg.agg_table_size if pin.max_rows is None \
+                else 1 << (pin.max_rows - 1).bit_length()
+        return size, minput
+
     def _plan_agg(self, select: ast.Select, scope: Scope,
                   pin: PlannedInput, eowc: bool = False,
-                  extra_out: "list | None" = None):
+                  extra_out: "list | None" = None,
+                  canonical: bool = False):
         """Plan the aggregation chain; with ``extra_out`` (AST exprs in
         the input scope, aggregates allowed) their values are appended
         to the output as hidden columns and their positions returned
-        as a 4th element (the dynamic-filter LHS hook)."""
+        as a 4th element (the dynamic-filter LHS hook).  ``canonical``
+        (a FROM subquery) puts the group keys in column order."""
         cfg = self.config
         group_asts = list(select.group_by)
+        if canonical:
+            group_asts = self._canonical_groups(group_asts, scope)
         in_binder = Binder(scope)
         group_by = []
         for gi, ga in enumerate(group_asts):
@@ -1338,6 +1402,17 @@ class Planner:
                 elif (isinstance(ga, ast.ColumnRef)
                         and ga.name == "window_end"):
                     wm_idx, lag = ki, 0  # closes when wm >= window_end
+        wm_src = pin.watermark_col
+        if wm_idx is None and pin.reader is None and pin.wm_lags \
+                and pin.wm_src_col is not None:
+            # a subquery's output column that carries a watermark (a
+            # window column of the aggregate below): the groups it
+            # closes are final, so they are cleaned like a window's
+            for ki, (_, ge) in enumerate(group_by):
+                if isinstance(ge, InputRef) and ge.index in pin.wm_lags:
+                    wm_idx, lag = ki, pin.wm_lags[ge.index]
+                    wm_src = pin.wm_src_col
+                    break
         if eowc and wm_idx is None:
             raise PlanError(
                 "EMIT ON WINDOW CLOSE needs GROUP BY window_start over a "
@@ -1378,20 +1453,25 @@ class Planner:
                     agg_calls[ci] = dataclasses.replace(
                         a, kind=f"{a.kind}_str"
                     )
+        table_size, minput_size = self._agg_sizes(pin, group_by)
         agg = HashAggExecutor(
             scope.schema, group_by, agg_calls,
-            table_size=cfg.agg_table_size,
-            emit_capacity=cfg.agg_emit_capacity,
+            table_size=table_size,
+            # a table the planner sized below the configuration's
+            # cannot emit more groups a round than it has slots
+            emit_capacity=min(cfg.agg_emit_capacity, table_size)
+            if table_size < cfg.agg_table_size else cfg.agg_emit_capacity,
             watermark_group_idx=wm_idx,
             watermark_lag=lag,
-            watermark_src_col=pin.watermark_col,
+            watermark_src_col=wm_src,
             emit_on_window_close=eowc,
             # retractable inputs (join outputs, cascades over
             # retractable MVs) switch min/max to materialized-input
             # state (ref minput.rs) instead of crash-on-delete
             retractable_input=not pin.append_only,
-            minput_bucket_cap=cfg.minput_bucket_cap,
-            distinct_table_size=cfg.distinct_table_size,
+            minput_table_size=minput_size,
+            distinct_table_size=cfg.distinct_table_size
+            or cfg.agg_table_size,
             # spill-to-host for UNBOUNDED key spaces (no watermark
             # cleaning): overflow rows divert to the host tier instead
             # of erroring.  Windowed aggs keep overflow-as-error — their
@@ -1403,7 +1483,7 @@ class Planner:
                         if wm_idx is None and not eowc else 0),
         )
         agg.spill_table_size = (cfg.agg_spill_table_size
-                                or cfg.agg_table_size * 8)
+                                or table_size * 8)
         execs.append(agg)
 
         # post-projection over agg output: group keys + agg results
@@ -1447,7 +1527,8 @@ class Planner:
         return execs, post.out_schema, pk_pos
 
     def _try_pane_agg(self, select: ast.Select, scope: Scope,
-                      pin: PlannedInput, execs: list, eowc: bool):
+                      pin: PlannedInput, execs: list, eowc: bool,
+                      canonical: bool = False):
         """Sliding-window (HOP) aggregation via PANES — stream slicing.
 
         The naive hop plan expands every event into size/slide window
@@ -1494,6 +1575,8 @@ class Planner:
 
         # bind group keys + items exactly as _plan_agg would
         group_asts = list(select.group_by)
+        if canonical:
+            group_asts = self._canonical_groups(group_asts, scope)
         in_binder = Binder(scope)
         group_by: list = []
         ws_key_pos = None
@@ -1579,7 +1662,9 @@ class Planner:
             watermark_lag=size,
             watermark_src_col=pin.watermark_col,
             retractable_input=True,
-            minput_bucket_cap=max(cfg.minput_bucket_cap, 2 * k),
+            # at most k live pane partials a window
+            minput_table_size=cfg.distinct_table_size
+            or cfg.agg_table_size,
         )
         execs2: list = [pane_agg, expand, final_agg]
 
@@ -1857,9 +1942,26 @@ class Planner:
             has_agg = bool(inner.group_by) or self._has_agg(inner)
             pk_positions: list[int] = []
             if has_agg:
-                execs2, out_schema, pk_positions = self._plan_agg(
-                    inner, scope, iinfo
-                )
+                # a hopping-window aggregate takes the pane rewrite the
+                # linear path has, where the window node is this
+                # subquery's own (made by the resolve above, nothing
+                # else reads it yet)
+                pane = None
+                prep = nodes[ref[1]] if ref[0] == "node" else None
+                if isinstance(prep, FragNode) and ref[1] == len(nodes) - 1 \
+                        and prep.fragment.executors == iinfo.executors:
+                    trial = list(iinfo.executors) + execs
+                    pane = self._try_pane_agg(
+                        inner, scope, iinfo, trial, False, canonical=True)
+                if pane is not None:
+                    n_prep = len(iinfo.executors)
+                    nodes[ref[1]] = FragNode(
+                        Fragment(trial[:n_prep]), prep.input)
+                    execs2, out_schema, pk_positions = pane
+                else:
+                    execs2, out_schema, pk_positions = self._plan_agg(
+                        inner, scope, iinfo, canonical=True
+                    )
                 execs.extend(execs2)
                 append_only = False
             else:
@@ -1885,7 +1987,8 @@ class Planner:
             # where the subquery aggregates) keeps that column's
             # watermark: changes to come carry values at or above it
             wm_lags: dict[int, int] = {}
-            if iinfo.wm_lags and not inner_dyn \
+            live_keys: dict[int, int] = {}
+            if not inner_dyn \
                     and not any(isinstance(i.expr, ast.Star)
                                 for i in inner.items):
                 for pos, item in enumerate(inner.items):
@@ -1895,14 +1998,23 @@ class Planner:
                         src = scope.resolve(item.expr.name, item.expr.table)
                     except BindError:
                         continue
-                    if src in iinfo.wm_lags:
+                    if src in (iinfo.wm_lags or ()):
                         wm_lags[pos] = iinfo.wm_lags[src]
+                    if src in (iinfo.live_keys or ()):
+                        live_keys[pos] = iinfo.live_keys[src]
+            # an aggregate's output holds a row a group: its table's
+            # slots bound it; a projection keeps its input's bound
+            max_rows = next(
+                (ex.table_size for ex in reversed(execs)
+                 if isinstance(ex, HashAggExecutor)), iinfo.max_rows)
             info = PlannedInput(
                 None, [], Scope.of(out_schema, sq.alias), out_schema,
                 None, None, append_only,
                 stream_key=pk_positions or None,
                 wm_lags=wm_lags or None,
                 wm_src_col=iinfo.wm_src_col if wm_lags else None,
+                wm_delay=iinfo.wm_delay,
+                max_rows=max_rows, live_keys=live_keys or None,
             )
             return ref, info
 
@@ -2091,26 +2203,95 @@ class Planner:
                     "next round"
                 )
 
+            # a side's store, from what the planner sees of its
+            # changelog (hash_join.py, module docstring): append-only ->
+            # the ring ("pool"); retractable, its join key a proper part
+            # of its stream key (many rows a key) while the other side
+            # holds at most a row a key (its stream key within its join
+            # key: few rows probe) -> "keyed" by the stream key; every
+            # other retractable side the dense buckets.
+            # ``join_force_dense`` can only veto (conformance runs).
+            def key_cols(keys) -> "set | None":
+                if all(isinstance(k, InputRef) for k in keys):
+                    return {k.index for k in keys}
+                return None
+
+            def storage(pin, keys, other, other_keys) -> str:
+                if cfg.join_force_dense:
+                    return "dense"
+                if pin.append_only:
+                    return "pool"
+                mine, theirs = key_cols(keys), key_cols(other_keys)
+                if join_type == "inner" and mine is not None \
+                        and theirs is not None and pin.stream_key \
+                        and mine < set(pin.stream_key) \
+                        and not other.append_only and other.stream_key \
+                        and set(other.stream_key) <= theirs:
+                    return "keyed"
+                return "dense"
+
+            def sizes(pin, keys, store, table_size, bucket_cap):
+                """(table slots, bucket depth) of a retractable side:
+                the configuration's where it names them; else, where the
+                planner can bound the side's rows (``max_rows``: an
+                aggregate's output), a slot a row for a keyed side, and
+                for a dense side whose stream key lies within its join
+                key (a row a key) a slot a key and buckets of four (a
+                chunk's delete and insert of one key, twice over)."""
+                cols = key_cols(keys)
+                if pin.max_rows is None or pin.append_only:
+                    return table_size, bucket_cap
+                bound = 1 << max(pin.max_rows - 1, 63).bit_length()
+                if store == "keyed":
+                    return table_size or bound, bucket_cap
+                if cols is not None and pin.stream_key \
+                        and set(pin.stream_key) <= cols:
+                    return table_size or bound, bucket_cap or 4
+                return table_size, bucket_cap
+
+            left_store = storage(left, left_keys, right, right_keys)
+            right_store = storage(right, right_keys, left, left_keys)
+            left_size, left_bucket = sizes(
+                left, left_keys, left_store,
+                cfg.join_left_table_size, cfg.join_left_bucket_cap)
+            right_size, right_bucket = sizes(
+                right, right_keys, right_store,
+                cfg.join_right_table_size, cfg.join_right_bucket_cap)
             join = HashJoinExecutor(
                 left.schema, right.schema, left_keys, right_keys,
                 table_size=cfg.join_table_size,
                 bucket_cap=cfg.join_bucket_cap,
                 out_capacity=cfg.join_out_capacity,
-                left_table_size=cfg.join_left_table_size,
-                right_table_size=cfg.join_right_table_size,
-                left_bucket_cap=cfg.join_left_bucket_cap,
-                right_bucket_cap=cfg.join_right_bucket_cap,
+                left_table_size=left_size,
+                right_table_size=right_size,
+                left_bucket_cap=left_bucket,
+                right_bucket_cap=right_bucket,
                 join_type=join_type,
-                # append-only sides take the degree-adaptive shared
-                # pool (no per-key cap for hot-skew keys); retractable
-                # sides need delete-by-value and keep dense buckets
-                left_storage="pool" if left.append_only
-                and not cfg.join_force_dense else "dense",
-                right_storage="pool" if right.append_only
-                and not cfg.join_force_dense else "dense",
+                left_storage=left_store,
+                right_storage=right_store,
                 left_pool_size=cfg.join_pool_size,
                 right_pool_size=cfg.join_pool_size,
+                left_row_key=left.stream_key,
+                right_row_key=right.stream_key,
             )
+            if residual and join_type == "inner" \
+                    and "pool" not in (left_store, right_store):
+                # the executor applies a non-equality conjunct where it
+                # stages pairs: a change emits the pairs that qualify
+                # before and after it, not the key's every row.  Behind
+                # a pool side (addressed by rank) it stays a filter.
+                inner_scope = Scope(
+                    join.out_schema,
+                    tuple(left.scope.qualifiers)
+                    + tuple(right.scope.qualifiers))
+                pred = None
+                for conj in residual:
+                    e = Binder(inner_scope).bind(conj)
+                    pred = e if pred is None else pred & e
+                join.residual = pred
+                in_join, residual = residual, []
+            else:
+                in_join = []
             # the join's OUTPUT schema carries the pad nullability;
             # semi/anti joins emit only the preserved side's columns
             if join.is_semi or join.is_anti:
@@ -2144,9 +2325,33 @@ class Planner:
                 # conjuncts stay behind as post-join filters)
                 self._band_cleaning(
                     join, left, right,
-                    residual + list(where_conjs),
+                    in_join + residual + list(where_conjs),
                     both,
                 )
+                # so does an equality of two watermarked columns (a
+                # window column of an aggregate on each side, Nexmark
+                # q5): a row is dead once neither side can change at
+                # its key any more
+                if left.wm_lags and right.wm_lags \
+                        and left.wm_src_col is not None \
+                        and right.wm_src_col is not None:
+                    for lk, rk in zip(left_keys, right_keys):
+                        if not (isinstance(lk, InputRef)
+                                and isinstance(rk, InputRef)
+                                and lk.index in left.wm_lags
+                                and rk.index in right.wm_lags):
+                            continue
+                        lag = max(left.wm_lags[lk.index],
+                                  right.wm_lags[rk.index])
+                        if join.left_clean is None:
+                            join.left_clean = JoinClean(
+                                lk, lag, left.wm_src_col,
+                                right.wm_src_col)
+                        if join.right_clean is None:
+                            join.right_clean = JoinClean(
+                                rk, lag, right.wm_src_col,
+                                left.wm_src_col)
+                        break
             nodes.append(JoinNode(join, lref, rref))
             ref = ("node", len(nodes) - 1)
             if residual:
@@ -2322,9 +2527,132 @@ class Planner:
                 group_topn=gtn,
             )
         nodes.append(FragNode(Fragment(post_execs), root_ref))
-        return DagPlan(
+        return self._share_subplans(DagPlan(
             sources, nodes, len(nodes) - 1, len(post_execs) - 1
-        )
+        ))
+
+    # -- shared subplans ---------------------------------------------------
+    def _share_subplans(self, plan: DagPlan) -> DagPlan:
+        """Plan once what a statement states twice (upstream shares the
+        subplan): two fragment nodes that read the same input through
+        the same executors keep ONE copy of their common prefix — one
+        state in the checkpoint, one pass over the input — and each its
+        own suffix.  Nexmark q5 names ``count(*) ... GROUP BY auction,
+        window_start`` under two aliases; its window node and its
+        aggregate are planned once and feed both the max and the join's
+        left side."""
+        from risingwave_tpu.stream.dag import FragNode, JoinNode
+
+        order = list(plan.nodes)
+        mv_ex = order[plan.mv_node].fragment.executors[plan.mv_index]
+
+        def deref(ref):
+            return order[ref[1]] if ref[0] == "node" else ref
+
+        # refs become the node objects themselves while nodes move
+        for n in list(order):
+            if isinstance(n, FragNode):
+                n.input = deref(n.input)
+            else:
+                n.left, n.right = deref(n.left), deref(n.right)
+
+        def redirect(old, new) -> None:
+            for n in order:
+                if isinstance(n, FragNode):
+                    if n.input is old:
+                        n.input = new
+                else:
+                    if n.left is old:
+                        n.left = new
+                    if n.right is old:
+                        n.right = new
+
+        def share_one() -> bool:
+            frags = [n for n in order if isinstance(n, FragNode)]
+            for ai, a in enumerate(frags):
+                for b in frags[ai + 1:]:
+                    if not (a.input is b.input or a.input == b.input):
+                        continue
+                    xa, xb = a.fragment.executors, b.fragment.executors
+                    n = 0
+                    while n < min(len(xa), len(xb)) \
+                            and self._exec_eq(xa[n], xb[n]):
+                        n += 1
+                    if n == 0:
+                        continue
+                    if n < len(xa):
+                        shared = FragNode(Fragment(xa[:n]), a.input)
+                        order.insert(order.index(a), shared)
+                        a.fragment, a.input = Fragment(xa[n:]), shared
+                    else:
+                        shared = a
+                    if n < len(xb):
+                        b.fragment, b.input = Fragment(xb[n:]), shared
+                    else:
+                        redirect(b, shared)
+                        order.remove(b)
+                    return True
+            return False
+
+        while share_one():
+            pass
+        at = {id(n): i for i, n in enumerate(order)}
+
+        def ref_of(x):
+            return x if isinstance(x, tuple) else ("node", at[id(x)])
+
+        for n in order:
+            if isinstance(n, FragNode):
+                n.input = ref_of(n.input)
+            else:
+                n.left, n.right = ref_of(n.left), ref_of(n.right)
+        mv_node, mv_index = next(
+            (i, n.fragment.executors.index(mv_ex))
+            for i, n in enumerate(order)
+            if isinstance(n, FragNode) and mv_ex in n.fragment.executors)
+        return DagPlan(plan.sources, order, mv_node, mv_index)
+
+    @staticmethod
+    def _exec_eq(a, b) -> bool:
+        """Two executors that do the same to the same input (the kinds a
+        FROM subquery is planned from; anything else is its own)."""
+        from risingwave_tpu.stream.executor import HopWindowExecutor
+        eq = Planner._expr_eq
+        if type(a) is not type(b) or a.in_schema != b.in_schema:
+            return False
+        if isinstance(a, ProjectExecutor):
+            return len(a.exprs) == len(b.exprs) and all(
+                na == nb and eq(ea, eb)
+                for (na, ea), (nb, eb) in zip(a.exprs, b.exprs))
+        if isinstance(a, FilterExecutor):
+            return eq(a.predicate, b.predicate)
+        if isinstance(a, WatermarkFilterExecutor):
+            return (a.ts_col, a.delay_us) == (b.ts_col, b.delay_us)
+        if isinstance(a, HopWindowExecutor):
+            return (a.ts_col, a.slide_us, a.size_us) \
+                == (b.ts_col, b.slide_us, b.size_us)
+        if isinstance(a, HashAggExecutor):
+            ka, kb = dict(a._ctor_kwargs), dict(b._ctor_kwargs)
+            ga, gb = ka.pop("group_by"), kb.pop("group_by")
+            ca, cb = ka.pop("aggs"), kb.pop("aggs")
+
+            def opt_eq(x, y):
+                return (x is None and y is None) or (
+                    x is not None and y is not None and eq(x, y))
+
+            return ka == kb and len(ga) == len(gb) and len(ca) == len(cb) \
+                and all(na == nb and eq(ea, eb)
+                        for (na, ea), (nb, eb) in zip(ga, gb)) \
+                and all((x.kind, x.alias, x.distinct)
+                        == (y.kind, y.alias, y.distinct)
+                        and opt_eq(x.arg, y.arg)
+                        and opt_eq(x.filter, y.filter)
+                        for x, y in zip(ca, cb)) \
+                and (a.table_size, a.minput_table_size,
+                     a.distinct_table_size, a.spill_ring) \
+                == (b.table_size, b.minput_table_size,
+                    b.distinct_table_size, b.spill_ring)
+        return False
 
     @staticmethod
     def _named_columns(*roots) -> "set | None":

@@ -351,12 +351,12 @@ class DagJob(BarrierLoop):
 
     def _apply_join_windowed(self, new_states: list, idx: int, chunk,
                              side: str, enqueue) -> None:
-        """Drive a join with WINDOWED emission: window 0 propagates via
-        the normal traversal; any further windows (high-amplification
-        probes) drain through the downstream subgraph inside a device
-        ``while_loop`` — matches dropped by a fixed out buffer in the
-        old design now always reach downstream (ref hash_join.rs
-        chunk-sized yielding under amplification)."""
+        """Drive a join with WINDOWED emission: the chunk's emission
+        windows (one, or many under a high-amplification probe) drain
+        through the downstream subgraph inside a device ``while_loop``
+        — matches dropped by a fixed out buffer in the old design now
+        always reach downstream (ref hash_join.rs chunk-sized yielding
+        under amplification)."""
         node = self.nodes[idx]
         join = node.join
         if not hasattr(join, "apply_begin"):
@@ -370,31 +370,34 @@ class DagJob(BarrierLoop):
         if not self._consumers.get(("node", idx)):
             return  # terminal join: emissions have no consumers
         build_rows = join.build_rows_of(new_states[idx], side)
-        # window 0 propagates directly (NOT via the inbox) so windows
-        # stay in emission order downstream — a +pair in window 0 must
-        # land before its -pair in window 1
-        first, probe_bound = join.emit_window(
-            build_rows, pending, jnp.int32(0), side
-        )
-        new_states[idx] = new_states[idx]._replace(
-            emit_overflow=new_states[idx].emit_overflow + probe_bound
-        )
-        self._propagate(new_states, [(("node", idx), first)])
         max_w = join.max_windows(chunk.capacity)
         if max_w <= 1:
+            # one window holds whatever a chunk can stage: it propagates
+            # directly (NOT via the inbox), in emission order
+            first, probe_bound = join.emit_window(
+                build_rows, pending, jnp.int32(0), side
+            )
+            new_states[idx] = new_states[idx]._replace(
+                emit_overflow=new_states[idx].emit_overflow + probe_bound
+            )
+            self._propagate(new_states, [(("node", idx), first)])
             return
 
-        # sharded: the loop body may contain collectives (downstream
-        # exchanges), so every shard must run the same trip count —
-        # bound by the max pending across shards (extra windows emit
-        # empty chunks, which are harmless)
+        # windows leave in emission order — a +pair in window 0 must
+        # land before its -pair in window 1 — from ONE loop whose first
+        # round is window 0, so the program holds the join's downstream
+        # subgraph once.  Sharded: the loop body may contain collectives
+        # (downstream exchanges), so every shard must run the same trip
+        # count — bound by the max pending across shards (extra windows
+        # emit empty chunks, which are harmless)
         total = pending.total
         if self.mesh is not None:
             total = axis_max(total, self.AXIS)
 
         def cond(carry):
             sts, w = carry
-            return (w * join.out_capacity < total) & (w < max_w)
+            return (w == 0) | ((w * join.out_capacity < total)
+                               & (w < max_w))
 
         def body(carry):
             sts, w = carry
@@ -409,7 +412,7 @@ class DagJob(BarrierLoop):
             return tuple(lst), w + 1
 
         sts, _ = jax.lax.while_loop(
-            cond, body, (tuple(new_states), jnp.int32(1))
+            cond, body, (tuple(new_states), jnp.int32(0))
         )
         new_states[:] = list(sts)
 
@@ -826,17 +829,39 @@ class DagJob(BarrierLoop):
         return rows
 
     # -- barrier program ------------------------------------------------
-    def _flush_node(self, new_states: list, idx: int, epoch) -> None:
+    def _flush_node(self, new_states: list, idx: int, epoch,
+                    after_watermarks: bool = False) -> None:
         """Flush one fragment node; emissions cross downstream nodes.
-        Drains on device while the node reports pending output."""
+        Drains on device while the node reports pending output, in ONE
+        loop whose first round is the flush itself: the program holds
+        the node's downstream subgraph once, not once for the flush and
+        once more for its drain.
+
+        ``after_watermarks`` is the barrier's second pass, for what the
+        new watermark closed (EOWC).  A node whose flushing executors
+        all say what they have pending (``pending_flush``) runs it only
+        while something is pending, as ``Fragment._drain_impl`` does;
+        where none of them emits on window close, nothing can be, so
+        the first pass takes both passes' rounds and the second is not
+        compiled at all."""
         node = self.nodes[idx]
         frag = node.fragment
-        st, outs = frag._flush_impl(new_states[idx], epoch)
-        new_states[idx] = st
-        for out in outs:
-            self._propagate(new_states, [(("node", idx), out)])
-        if not frag.has_pending_protocol():
+        flushing = [ex for ex in frag.executors if ex.emits_on_flush]
+        says_pending = all(hasattr(ex, "pending_flush") for ex in flushing)
+        eowc = any(getattr(ex, "emit_on_window_close", False)
+                   for ex in flushing)
+        one_pass = says_pending and not eowc
+        if after_watermarks and (one_pass or not flushing):
             return
+        if not frag.has_pending_protocol():
+            st, outs = frag._flush_impl(new_states[idx], epoch)
+            new_states[idx] = st
+            for out in outs:
+                self._propagate(new_states, [(("node", idx), out)])
+            return
+        rounds = frag.MAX_DRAIN_ROUNDS + 1
+        if one_pass:
+            rounds *= 2
 
         def _more(states_idx):
             # sharded: the drain body may cross exchanges (collectives),
@@ -850,7 +875,7 @@ class DagJob(BarrierLoop):
 
         def cond(carry):
             sts, it, more = carry
-            return more & (it < frag.MAX_DRAIN_ROUNDS)
+            return more & (it < rounds)
 
         def body(carry):
             sts, it, _ = carry
@@ -861,16 +886,21 @@ class DagJob(BarrierLoop):
                 self._propagate(lst, [(("node", idx), out)])
             return tuple(lst), it + 1, _more(lst[idx])
 
+        # the first pass always flushes (a flush is more than its
+        # pending rows: it takes the extremes again, moves ``prev``);
+        # the pass after the watermarks only what is pending
+        first = _more(new_states[idx]) if after_watermarks and says_pending \
+            else jnp.asarray(True)
         sts, _, _ = jax.lax.while_loop(
-            cond, body,
-            (tuple(new_states), jnp.int32(0), _more(new_states[idx])),
+            cond, body, (tuple(new_states), jnp.int32(0), first),
         )
         new_states[:] = list(sts)
 
-    def _flush_all(self, new_states: list, epoch) -> None:
+    def _flush_all(self, new_states: list, epoch,
+                   after_watermarks: bool = False) -> None:
         for idx, node in enumerate(self.nodes):
             if isinstance(node, FragNode):
-                self._flush_node(new_states, idx, epoch)
+                self._flush_node(new_states, idx, epoch, after_watermarks)
 
     def _node_watermarks(self, new_states: list, idx: int):
         """(Watermark, has) pairs produced by a fragment node's wm
@@ -1040,7 +1070,7 @@ class DagJob(BarrierLoop):
         # watermarks advance, then a second flush pass emits rows the
         # new watermark closed (EOWC) at THIS barrier
         self._wm_all(new_states)
-        self._flush_all(new_states, epoch)
+        self._flush_all(new_states, epoch, after_watermarks=True)
         self._clean_joins(new_states)
         return tuple(new_states), self._collect_counters(new_states)
 

@@ -30,21 +30,26 @@ WM_NONE = np.iinfo(np.int64).min
 #: enough above INT64_MIN that `value - lag` cannot wrap
 WM_SAFE_FLOOR = -(1 << 62)
 
-#: per-executor-state scalar counters surfaced to maintenance checks
-COUNTER_ATTRS = ("inconsistency", "overflow", "emit_overflow")
+#: per-executor-state scalar counters surfaced to maintenance checks; an
+#: aggregate counts its three stores apart (``overflow`` the group
+#: table), so that the barrier's error names the one that was full
+COUNTER_ATTRS = ("inconsistency", "overflow", "emit_overflow",
+                 "minput_overflow", "distinct_overflow")
 #: running tallies that ride the same vector (``AggState``): how often a
 #: mechanism engaged, not rows lost — maintenance exports them as
 #: ``hash_agg_<attr>_total`` and neither sums nor raises on them
 TALLY_ATTRS = ("apply_chunks", "rep_rows", "rep_tiles",
-               "reclaim_passes", "reclaim_slots")
+               "reclaim_passes", "reclaim_slots",
+               "minput_changes", "flush_rounds")
 #: levels on the same vector, as the last maintenance pass found them:
 #: exported as gauges ``hash_agg_<attr>{job}``, skipped like the tallies
-GAUGE_ATTRS = ("live_groups", "tombstones", "table_slots")
+GAUGE_ATTRS = ("live_groups", "tombstones", "table_slots",
+               "minput_live_values")
 #: a join side's tallies and levels, under ``n<i>.join.<side>.<attr>`` on
 #: the DAG runtime's vector: ``hash_join_<attr>_total{job,side}`` and
 #: gauges ``hash_join_<attr>{job,side}``; skipped like the aggregate's
-JOIN_TALLY_ATTRS = ("insert_rows", "probe_steps", "emit_rows",
-                    "cleaned_rows", "reclaim_slots")
+JOIN_TALLY_ATTRS = ("insert_rows", "delete_rows", "probe_steps",
+                    "emit_rows", "cleaned_rows", "reclaim_slots")
 JOIN_GAUGE_ATTRS = ("live_rows", "tombstones", "table_slots")
 #: a pk-keyed view's levels, from ``MaterializeExecutor.levels`` (no
 #: field of its state: a checkpoint keeps its leaves): gauges

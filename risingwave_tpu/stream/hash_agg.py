@@ -26,15 +26,22 @@ changes, Delete if its row count reaches zero) mirror
 min/max over APPEND-ONLY inputs are monotone monoids (one scatter-min/
 max per chunk).  Over RETRACTABLE inputs (``retractable_input=True``)
 they switch to a **materialized-input state** — the reference's
-``minput.rs`` (src/stream/src/executor/aggregate/minput.rs) re-imagined
-for slot-aligned HBM: each such aggregate owns a ``[table_size,
-minput_bucket_cap]`` value multi-map aligned to the group table's
-slots (no second key table).  Inserts claim free bucket positions by
-rank, deletes clear value-equal entries by rank, and the aggregate's
-``[size]`` prim array becomes a flush-time CACHE recomputed from the
-bucket for dirty groups — so the prev-snapshot / U-pair machinery is
-untouched.  Bucket overflow is counted loudly (raise at maintenance),
-the analog of the reference's bounded cache + state-table fallback.
+``minput.rs`` (src/stream/src/executor/aggregate/minput.rs) as a counted
+table: each such aggregate owns a ``HashTable`` keyed by *(group keys...,
+value)* with a multiplicity a slot, the layout a DISTINCT call's dedup
+table has (``_counted_update`` serves both).  An insert is a
+find-or-claim and a +1, a retraction a lookup and a -1, and a value
+whose multiplicity reaches zero gives its slot up: the work of a chunk
+follows the chunk and the memory the distinct values held, whatever a
+group holds (seven groups of 15,000 values or 100,000 groups of eleven).
+The aggregate's ``[size]`` prim array is a flush-time CACHE: when a
+chunk has changed the table, the next flush takes the extreme of every
+group again in one masked pass over the table's slots (a scatter-min/max
+into the group slots each value remembers), so any value may be
+retracted, the current extreme included, and the prev-snapshot / U-pair
+machinery is untouched.  The table is cleaned with its groups, reclaimed
+like them, and a full one counts ``minput_overflow`` loudly (raise at
+maintenance).
 """
 
 from __future__ import annotations
@@ -99,11 +106,15 @@ class AggState(NamedTuple):
     inconsistency: jnp.ndarray  # int64 scalar
     #: latest watermark received (EOWC emission; INT64_MIN = none)
     wm: jnp.ndarray             # int64 scalar
-    #: materialized-input values per retractable min/max agg (ref
-    #: minput.rs): ([size, B] values, [size, B] occupied) pairs,
-    #: slot-aligned with ``table``
-    minput_vals: tuple = ()
-    minput_occ: tuple = ()
+    #: materialized input of each retractable min/max agg (ref
+    #: minput.rs): a table keyed (group keys..., value), the value's
+    #: multiplicity int64 [M] and the group-table slot it belongs to
+    #: int32 [M] (kept through ``maybe_rehash``); ``minput_stale`` says
+    #: a chunk changed a table since the prim caches were last taken
+    minput_tables: tuple = ()
+    minput_counts: tuple = ()
+    minput_gslot: tuple = ()
+    minput_stale: jnp.ndarray = ()
     #: per-DISTINCT-call dedup state (ref distinct.rs dedup tables):
     #: a hash table keyed (group keys..., arg) and an int64 [size]
     #: row-count per key — 0↔nonzero transitions drive the agg update
@@ -124,6 +135,14 @@ class AggState(NamedTuple):
     apply_chunks: jnp.ndarray = ()
     rep_rows: jnp.ndarray = ()
     rep_tiles: jnp.ndarray = ()
+    #: values put into or taken out of materialised input, and the
+    #: flush calls that had a group to emit (a barrier's drain rounds)
+    minput_changes: jnp.ndarray = ()
+    flush_rounds: jnp.ndarray = ()
+    #: rows lost to a full materialised-input / distinct dedup table
+    #: (``fragment.COUNTER_ATTRS``: the barrier raises, naming the store)
+    minput_overflow: jnp.ndarray = ()
+    distinct_overflow: jnp.ndarray = ()
     #: tallies of ``maybe_rehash``: passes that found a tombstone in the
     #: group table, and the tombstones they gave back
     reclaim_passes: jnp.ndarray = ()
@@ -132,6 +151,8 @@ class AggState(NamedTuple):
     #: reclaimed (``fragment.GAUGE_ATTRS``): its fullest in the barrier
     live_groups: jnp.ndarray = ()
     tombstones: jnp.ndarray = ()
+    #: distinct (group, value) pairs the materialised-input tables held
+    minput_live_values: jnp.ndarray = ()
     #: the group table's size, so that a job's levels (summed over its
     #: aggregates) read as a share of its tables
     table_slots: jnp.ndarray = ()
@@ -187,6 +208,37 @@ def _interleave(old, new):
     )
 
 
+def _counted_update(table: HashTable, counts, key_cols, eligible, signs):
+    """Fold a chunk's +1/-1 rows into a counted table (key ->
+    multiplicity): the dedup table of a DISTINCT call and the
+    materialised input of a retractable min/max.  A row finds or claims
+    its key's slot, the slot's count moves by the row's sign, and a key
+    whose count is no longer positive gives the slot up (a tombstone
+    until the next reclaim).  A key's net change decides, so the order
+    of a chunk's rows does not matter.
+
+    Returns ``(table, counts, slots, ok, n0, n1, n_over, n_bad)``:
+    ``ok`` the eligible rows that found a slot, ``n0`` / ``n1`` their
+    key's count before and after the chunk, ``n_over`` rows the table
+    was full for, ``n_bad`` rows whose key went below zero (a delete of
+    what was never inserted: the consistency_error! analog)."""
+    table, slots, ins, over = table.lookup_or_insert(key_cols, eligible)
+    size = table.size
+    n_over = jnp.sum((over & eligible).astype(jnp.int64))
+    ok = eligible & ~over
+    safe = jnp.minimum(slots, size - 1)
+    # a claimed slot may be a reclaimed one: its count is stale
+    counts = counts.at[
+        jnp.where(ins, slots, jnp.int32(size))].set(0, mode="drop")
+    n0 = counts[safe]
+    counts = counts.at[jnp.where(ok, safe, jnp.int32(size))].add(
+        jnp.where(ok, signs.astype(jnp.int64), 0), mode="drop")
+    n1 = counts[safe]
+    n_bad = jnp.sum((ok & (n1 < 0)).astype(jnp.int64))
+    table = table.clear_slots(slots, ok & (n1 <= 0))
+    return table, counts, slots, ok, n0, n1, n_over, n_bad
+
+
 class HashAggExecutor(Executor):
     """GROUP BY aggregation over a device hash table."""
 
@@ -207,7 +259,7 @@ class HashAggExecutor(Executor):
         watermark_src_col: int | None = None,
         emit_on_window_close: bool = False,
         retractable_input: bool = False,
-        minput_bucket_cap: int = 64,
+        minput_table_size: int | None = None,
         distinct_table_size: int | None = None,
         spill_ring: int = 0,
     ):
@@ -224,7 +276,6 @@ class HashAggExecutor(Executor):
             watermark_src_col=watermark_src_col,
             emit_on_window_close=emit_on_window_close,
             retractable_input=retractable_input,
-            minput_bucket_cap=minput_bucket_cap,
         )
         #: EOWC (ref emit_on_window_close plan property): flush emits
         #: only CLOSED windows as final append-only rows and evicts them
@@ -259,9 +310,11 @@ class HashAggExecutor(Executor):
         for ai, a in enumerate(self.aggs):
             for ps in a.spec().states:
                 self._prim_specs.append((ai, ps))
-        #: retractable min/max via materialized-input buckets (ref
-        #: minput.rs); their prim arrays become flush-time caches
-        self.minput_bucket_cap = minput_bucket_cap
+        #: retractable min/max via materialized input (ref minput.rs);
+        #: their prim arrays become flush-time caches.  The tables hold
+        #: distinct (group, value) pairs: ``minput_table_size`` slots
+        #: each (None = the group table's size)
+        self.minput_table_size = minput_table_size or table_size
         self._minput_aggs: list[int] = [
             ai for ai, a in enumerate(self.aggs)
             if retractable_input and a.kind in ("min", "max")
@@ -271,6 +324,12 @@ class HashAggExecutor(Executor):
             pi for pi, (ai, _) in enumerate(self._prim_specs)
             if ai in self._minput_aggs
         }
+        #: the cache prim of each materialised-input aggregate
+        self._minput_prim = [
+            next(pi for pi, (ai, _) in enumerate(self._prim_specs)
+                 if ai == agg_idx)
+            for agg_idx in self._minput_aggs
+        ]
         #: DISTINCT calls with their own counted dedup tables (ref
         #: distinct.rs); min/max are distinct-insensitive and handled
         #: as plain calls
@@ -343,6 +402,13 @@ class HashAggExecutor(Executor):
             p = NCol(p, jnp.zeros((1,), jnp.bool_))
         return self._key_protos() + [p]
 
+    def _minput_protos(self, agg_idx: int) -> list:
+        """Key prototypes of a materialised-input table: (group
+        keys..., value), the value bare (a NULL argument is no input)."""
+        f = self.aggs[agg_idx].arg.return_field(self.in_schema)
+        return self._key_protos() + [
+            jnp.zeros((1,), f.data_type.physical_dtype)]
+
     def init_state(self) -> AggState:
         size = self.table_size
         table = HashTable.create(self._key_protos(), size)
@@ -354,7 +420,7 @@ class HashAggExecutor(Executor):
                 out.append(jnp.full((size,), ps.init(st_dt), st_dt))
             return tuple(out)
 
-        B = self.minput_bucket_cap
+        M = self.minput_table_size
         return AggState(
             table=table,
             # prev_prims must be INDEPENDENT buffers (donation forbids
@@ -368,14 +434,17 @@ class HashAggExecutor(Executor):
             overflow=jnp.zeros((), jnp.int64),
             inconsistency=jnp.zeros((), jnp.int64),
             wm=jnp.asarray(np.iinfo(np.int64).min, jnp.int64),
-            minput_vals=tuple(
-                jnp.zeros((size, B), self._input_dtype(ai))
+            minput_tables=tuple(
+                HashTable.create(self._minput_protos(ai), M)
                 for ai in self._minput_aggs
             ),
-            minput_occ=tuple(
-                jnp.zeros((size, B), jnp.bool_)
-                for ai in self._minput_aggs
+            minput_counts=tuple(
+                jnp.zeros((M,), jnp.int64) for _ in self._minput_aggs
             ),
+            minput_gslot=tuple(
+                jnp.full((M,), size, jnp.int32) for _ in self._minput_aggs
+            ),
+            minput_stale=jnp.zeros((), jnp.bool_),
             distinct_tables=tuple(
                 HashTable.create(self._distinct_protos(ai),
                                  self.distinct_table_size)
@@ -396,10 +465,15 @@ class HashAggExecutor(Executor):
             apply_chunks=jnp.zeros((), jnp.int64),
             rep_rows=jnp.zeros((), jnp.int64),
             rep_tiles=jnp.zeros((), jnp.int64),
+            minput_changes=jnp.zeros((), jnp.int64),
+            flush_rounds=jnp.zeros((), jnp.int64),
+            minput_overflow=jnp.zeros((), jnp.int64),
+            distinct_overflow=jnp.zeros((), jnp.int64),
             reclaim_passes=jnp.zeros((), jnp.int64),
             reclaim_slots=jnp.zeros((), jnp.int64),
             live_groups=jnp.zeros((), jnp.int64),
             tombstones=jnp.zeros((), jnp.int64),
+            minput_live_values=jnp.zeros((), jnp.int64),
             table_slots=jnp.asarray(size, jnp.int64),
         )
 
@@ -616,33 +690,12 @@ class HashAggExecutor(Executor):
                 fm = filter_mask(a, agg_idx)
                 if fm is not None:
                     eligible = eligible & fm
-                dt, dslots, dins, dover = d_tables[di].lookup_or_insert(
-                    key_cols + [acol], eligible
-                )
-                d_tables[di] = dt
-                size_d = dt.size
-                n_over_d = n_over_d + jnp.sum(
-                    (dover & eligible).astype(jnp.int64)
-                )
-                eligible = eligible & ~dover
-                safe_d = jnp.minimum(dslots, size_d - 1)
-                cnt = d_counts[di]
-                # reclaimed (tombstoned→reused) slots carry stale counts
-                cnt = cnt.at[
-                    jnp.where(dins, dslots, jnp.int32(size_d))
-                ].set(0, mode="drop")
-                contrib = jnp.where(eligible,
-                                    signs.astype(jnp.int64), 0)
-                delta = jnp.zeros((size_d,), jnp.int64).at[safe_d].add(
-                    jnp.where(eligible, contrib, 0)
-                )
-                n0 = cnt[safe_d]
-                n1 = n0 + delta[safe_d]
-                # deletes of never-inserted values drive a count
-                # negative — the consistency_error! analog
-                n_bad_d = n_bad_d + jnp.sum(
-                    (eligible & (n1 < 0)).astype(jnp.int64)
-                )
+                (d_tables[di], d_counts[di], dslots, eligible, n0, n1,
+                 over_d, bad_d) = _counted_update(
+                    d_tables[di], d_counts[di], key_cols + [acol],
+                    eligible, signs)
+                n_over_d = n_over_d + over_d
+                n_bad_d = n_bad_d + bad_d
                 first = eligible & (
                     _rank_by(dslots.astype(jnp.uint64), eligible) == 0
                 )
@@ -652,18 +705,6 @@ class HashAggExecutor(Executor):
                     - (n0 > 0).astype(jnp.int64),
                     0,
                 )
-                d_counts[di] = cnt.at[
-                    jnp.where(eligible, safe_d, jnp.int32(size_d))
-                ].add(contrib, mode="drop")
-                # a (group, value) whose count retracted to 0 frees its
-                # slot (tombstone) — churning retractable inputs must
-                # not accumulate dead keys (ref distinct.rs deletes
-                # count-0 dedup rows)
-                died = jnp.zeros((size_d,), jnp.bool_).at[
-                    jnp.where(first & (n1 <= 0) & (n0 > 0), safe_d,
-                              jnp.int32(size_d))
-                ].set(True, mode="drop")
-                d_tables[di] = d_tables[di].clear_where(died)
         for pi, (agg_idx, ps) in enumerate(self._prim_specs):
             a = self.aggs[agg_idx]
             if pi in self._cache_prims:
@@ -716,10 +757,9 @@ class HashAggExecutor(Executor):
                     )
             segs[pi] = seg
         if perm is None:
-            prims, row_count, dirty, minput_occ = self._scatter_groups(
+            prims, row_count, dirty = self._scatter_groups(
                 state.prims, state.row_count, state.dirty,
-                state.minput_occ, slots, inserted, segs,
-                signs.astype(jnp.int64),
+                slots, inserted, segs, signs.astype(jnp.int64),
             )
         else:
             seg_signs = segmented_sum(s_signs.astype(jnp.int64), start_pos)
@@ -735,24 +775,25 @@ class HashAggExecutor(Executor):
                     seg_signs[pos],
                 )
 
-            prims, row_count, dirty, minput_occ = jax.lax.fori_loop(
-                0, n_tiles, scatter_tile, (
-                    state.prims, state.row_count, state.dirty,
-                    state.minput_occ,
-                ),
+            prims, row_count, dirty = jax.lax.fori_loop(
+                0, n_tiles, scatter_tile,
+                (state.prims, state.row_count, state.dirty),
             )
 
         # materialized-input updates (retractable min/max): every row
-        # lands in its group's value bucket — per-row slots come from
-        # the per-row probe (CPU) or from each row's representative
-        # (TPU)
-        minput_vals = list(state.minput_vals)
-        minput_occ = list(minput_occ)
+        # moves the count of its (group, value) pair and leaves the
+        # pair its group's slot — per-row slots come from the per-row
+        # probe (CPU) or from each row's representative (TPU)
+        minput_tables = list(state.minput_tables)
+        minput_counts = list(state.minput_counts)
+        minput_gslot = list(state.minput_gslot)
         n_over_mi = jnp.zeros((), jnp.int64)
         n_miss_mi = jnp.zeros((), jnp.int64)
+        n_changes = jnp.zeros((), jnp.int64)
         if self._minput_aggs:
             if perm is None:
                 row_slots = slots
+                row_keys = key_cols
                 row_ok = valid & (row_slots < self.table_size)
             else:
                 # segments whose representative overflowed keep the
@@ -761,13 +802,14 @@ class HashAggExecutor(Executor):
                 row_slots = jnp.where(
                     s_valid, rep_slot[row_rep], self.table_size
                 )
+                row_keys = s_keys
                 row_ok = row_slots < self.table_size
             for mi, agg_idx in enumerate(self._minput_aggs):
                 a = self.aggs[agg_idx]
                 if agg_idx not in arg_cache:
                     arg_cache[agg_idx] = a.arg.eval(chunk)
                 vcol, vnull = split_col(arg_cache[agg_idx])
-                v_sorted = vcol if perm is None else gather_key(vcol, perm)
+                v_rows = vcol if perm is None else gather_key(vcol, perm)
                 active = row_ok & (s_signs != 0)
                 if vnull is not None:
                     active = active & ~(
@@ -776,14 +818,17 @@ class HashAggExecutor(Executor):
                 fm = filter_mask(a, agg_idx)
                 if fm is not None:
                     active = active & (fm if perm is None else fm[perm])
-                vals, occ, over, miss = self._minput_update(
-                    minput_vals[mi], minput_occ[mi], row_slots,
-                    v_sorted, s_signs, active,
-                )
-                minput_vals[mi] = vals
-                minput_occ[mi] = occ
+                (minput_tables[mi], minput_counts[mi], mslots, ok, _, _,
+                 over, miss) = _counted_update(
+                    minput_tables[mi], minput_counts[mi],
+                    list(row_keys) + [v_rows], active, s_signs)
+                M = self.minput_table_size
+                minput_gslot[mi] = minput_gslot[mi].at[
+                    jnp.where(ok, mslots, jnp.int32(M))
+                ].set(row_slots, mode="drop")
                 n_over_mi = n_over_mi + over
                 n_miss_mi = n_miss_mi + miss
+                n_changes = n_changes + jnp.sum(ok.astype(jnp.int64))
 
         n_bad = jnp.zeros((), jnp.int64)
         if any(not a.spec().retractable and ai not in self._minput_aggs
@@ -794,11 +839,16 @@ class HashAggExecutor(Executor):
             prims=prims,
             row_count=row_count,
             dirty=dirty,
-            overflow=state.overflow + n_over + n_over_mi + n_over_d,
+            overflow=state.overflow + n_over,
+            minput_overflow=state.minput_overflow + n_over_mi,
+            distinct_overflow=state.distinct_overflow + n_over_d,
             inconsistency=state.inconsistency + n_bad + n_miss_mi
             + n_bad_d,
-            minput_vals=tuple(minput_vals),
-            minput_occ=tuple(minput_occ),
+            minput_tables=tuple(minput_tables),
+            minput_counts=tuple(minput_counts),
+            minput_gslot=tuple(minput_gslot),
+            minput_stale=state.minput_stale | (n_changes > 0),
+            minput_changes=state.minput_changes + n_changes,
             distinct_tables=tuple(d_tables),
             distinct_counts=tuple(d_counts),
             spill_rows=spill_rows,
@@ -809,8 +859,8 @@ class HashAggExecutor(Executor):
             rep_tiles=state.rep_tiles + n_tiles,
         ), None
 
-    def _scatter_groups(self, prims, row_count, dirty, minput_occ, slots,
-                        inserted, segs, seg_signs):
+    def _scatter_groups(self, prims, row_count, dirty, slots, inserted,
+                        segs, seg_signs):
         """Fold one update per entry of ``slots`` into the per-slot
         state arrays (``size`` = dropped), at whatever width ``slots``
         has: the chunk's on the CPU, one tile's on the chip."""
@@ -832,11 +882,7 @@ class HashAggExecutor(Executor):
         row_count = row_count.at[ins_pos].set(0, mode="drop")
         row_count = row_count.at[slots].add(seg_signs, mode="drop")
         dirty = dirty.at[slots].set(True, mode="drop")
-        # a reclaimed slot starts with empty materialized-input buckets
-        minput_occ = tuple(
-            occ.at[ins_pos].set(False, mode="drop") for occ in minput_occ
-        )
-        return tuple(prims), row_count, dirty, minput_occ
+        return tuple(prims), row_count, dirty
 
     def reconstructible_from_rows(self) -> bool:
         """True when the agg's full state round-trips through its own
@@ -911,72 +957,6 @@ class HashAggExecutor(Executor):
             **self._ctor_kwargs,
         )
 
-    def _minput_update(self, vals, occ, row_slots, v_sorted, s_signs,
-                       active):
-        """Apply one chunk's (sorted) rows to a value bucket multi-map.
-
-        Same rank-claim/rank-clear mechanics as the join's bucketed
-        multi-map (hash_join._update_side), specialized to one scalar
-        value column keyed by the group slot."""
-        from risingwave_tpu.stream.hash_join import (
-            _group_totals,
-            _rank_by,
-        )
-
-        B = occ.shape[1]
-        size = self.table_size
-        is_ins = active & (s_signs > 0)
-        is_del = active & (s_signs < 0)
-        # in-chunk annihilation on (slot, value): a +v/-v pair inside
-        # one chunk must cancel (the delete pass only sees pre-chunk
-        # state)
-        pair_h = hash64_columns([
-            row_slots.astype(jnp.int64),
-            v_sorted,
-        ])
-        ins_rank_h = _rank_by(pair_h, is_ins)
-        del_rank_h = _rank_by(pair_h, is_del)
-        n_ins_h = _group_totals(pair_h, is_ins)
-        n_del_h = _group_totals(pair_h, is_del)
-        is_ins = is_ins & ~(ins_rank_h < n_del_h)
-        is_del = is_del & ~(del_rank_h < n_ins_h)
-
-        safe = jnp.minimum(row_slots, size - 1)
-        # deletes: clear the rank-th value-equal occupied entry
-        del_rank = _rank_by(pair_h, is_del)
-        occ_rows = occ[safe]
-        val_match = occ_rows & (vals[safe] == v_sorted[:, None])
-        match_rank = jnp.cumsum(val_match, axis=1) - 1
-        clear_onehot = val_match & (match_rank == del_rank[:, None]) & \
-            is_del[:, None]
-        any_clear = jnp.any(clear_onehot, axis=1)
-        miss = jnp.sum((is_del & ~any_clear).astype(jnp.int64))
-        j_clear = jnp.argmax(clear_onehot, axis=1).astype(jnp.int32)
-        flat_clear = jnp.where(
-            any_clear, safe * B + j_clear, jnp.int32(size * B)
-        )
-        occ = occ.reshape(-1).at[flat_clear].set(
-            False, mode="drop"
-        ).reshape(size, B)
-        # inserts: claim the rank-th free position of the slot's bucket
-        ins_rank = _rank_by(row_slots.astype(jnp.uint64), is_ins)
-        free = ~occ[safe]
-        free_rank = jnp.cumsum(free, axis=1) - 1
-        take = free & (free_rank == ins_rank[:, None]) & is_ins[:, None]
-        got = jnp.any(take, axis=1)
-        j_take = jnp.argmax(take, axis=1).astype(jnp.int32)
-        flat_take = jnp.where(
-            got, safe * B + j_take, jnp.int32(size * B)
-        )
-        occ = occ.reshape(-1).at[flat_take].set(
-            True, mode="drop"
-        ).reshape(size, B)
-        vals = vals.reshape(-1).at[flat_take].set(
-            v_sorted, mode="drop"
-        ).reshape(size, B)
-        over = jnp.sum((is_ins & ~got).astype(jnp.int64))
-        return vals, occ, over, miss
-
     # ------------------------------------------------------------------
     def _outputs(self, prims: tuple, row_count, slots):
         """Per-emitted-slot output columns from the state arrays."""
@@ -998,35 +978,38 @@ class HashAggExecutor(Executor):
             cols.append(out)
         return cols
 
-    def _refresh_minput_caches(self, state: AggState, slots,
-                               safe) -> AggState:
-        """Recompute retractable min/max outputs for the emitted slots
-        from their materialized-input buckets (the prim array is just a
-        cache of this reduction)."""
+    def _refresh_minput_caches(self, state: AggState) -> AggState:
+        """Take the retractable min/max of every group again from the
+        materialised input, if a chunk changed it since the last time:
+        one masked pass over each table's slots, a live value folded
+        into the group slot it remembers (the prim array is just a cache
+        of this reduction)."""
         if not self._minput_aggs:
             return state
-        prims = list(state.prims)
-        for mi, agg_idx in enumerate(self._minput_aggs):
-            pi = next(p for p, (ai, _) in enumerate(self._prim_specs)
-                      if ai == agg_idx)
-            mode = self.aggs[agg_idx].kind
-            vals = state.minput_vals[mi][safe]     # [cap, B]
-            occ = state.minput_occ[mi][safe]
-            dt = vals.dtype
-            if jnp.issubdtype(dt, jnp.floating):
-                ident = jnp.asarray(
-                    jnp.inf if mode == "min" else -jnp.inf, dt
-                )
-            else:
-                info = jnp.iinfo(dt)
-                ident = jnp.asarray(
-                    info.max if mode == "min" else info.min, dt
-                )
-            masked = jnp.where(occ, vals, ident)
-            red = masked.min(axis=1) if mode == "min" \
-                else masked.max(axis=1)
-            prims[pi] = prims[pi].at[slots].set(red, mode="drop")
-        return state._replace(prims=tuple(prims))
+        size = self.table_size
+
+        def refresh(prims):
+            prims = list(prims)
+            for mi, pi in enumerate(self._minput_prim):
+                mt = state.minput_tables[mi]
+                p = prims[pi]
+                live = mt.occupied & (state.minput_counts[mi] > 0)
+                at = jnp.where(live, state.minput_gslot[mi],
+                               jnp.int32(size))
+                ps = self._prim_specs[pi][1]
+                base = jnp.full((size,), ps.init(p.dtype), p.dtype)
+                vals = mt.key_cols[-1].astype(p.dtype)
+                if self.aggs[self._minput_aggs[mi]].kind == "min":
+                    prims[pi] = base.at[at].min(vals, mode="drop")
+                else:
+                    prims[pi] = base.at[at].max(vals, mode="drop")
+            return tuple(prims)
+
+        with jax.named_scope("extreme"):
+            prims = jax.lax.cond(
+                state.minput_stale, refresh, lambda p: p, state.prims)
+        return state._replace(
+            prims=prims, minput_stale=jnp.zeros((), jnp.bool_))
 
     def flush(self, state: AggState, epoch):
         if self.emit_on_window_close:
@@ -1036,7 +1019,7 @@ class HashAggExecutor(Executor):
         slots = mask_indices(state.dirty, cap, size)
         slot_live = slots < size
         safe = jnp.minimum(slots, size - 1)
-        state = self._refresh_minput_caches(state, slots, safe)
+        state = self._refresh_minput_caches(state)
 
         old_nonempty = state.prev_row_count[safe] > 0
         new_nonempty = state.row_count[safe] > 0
@@ -1078,6 +1061,8 @@ class HashAggExecutor(Executor):
             prev_prims=prev_prims,
             prev_row_count=prev_row_count,
             emitted=emitted,
+            flush_rounds=state.flush_rounds
+            + jnp.any(slot_live).astype(jnp.int64),
         ), out
 
     def _closed_mask(self, state: AggState) -> jnp.ndarray:
@@ -1096,6 +1081,7 @@ class HashAggExecutor(Executor):
         """Emit final rows for closed windows; evict them (ref EOWC)."""
         cap = self.emit_capacity
         size = self.table_size
+        state = self._refresh_minput_caches(state)
         closed = self._closed_mask(state)
         slots = mask_indices(closed, cap, size)
         slot_live = slots < size
@@ -1116,10 +1102,18 @@ class HashAggExecutor(Executor):
             slot_live, mode="drop"
         )
         table = state.table.clear_where(emitted_mask)
+        # an evicted group's materialised input goes with it
+        gone = jnp.concatenate([emitted_mask, jnp.zeros((1,), jnp.bool_)])
         return state._replace(
             table=table,
             row_count=jnp.where(emitted_mask, 0, state.row_count),
             dirty=state.dirty & ~emitted_mask,
+            minput_tables=tuple(
+                mt.clear_where(gone[g])
+                for mt, g in zip(state.minput_tables, state.minput_gslot)
+            ),
+            flush_rounds=state.flush_rounds
+            + jnp.any(slot_live).astype(jnp.int64),
         ), out
 
     def pending_dirty(self, state: AggState) -> jnp.ndarray:
@@ -1160,54 +1154,80 @@ class HashAggExecutor(Executor):
         Traceable: the decision is a ``lax.cond`` on the device-resident
         tombstone count, so maintenance never reads back to the host."""
         tombs = state.table.tombstone_count()
+        size = self.table_size
         state = state._replace(
             live_groups=state.table.count().astype(jnp.int64),
             tombstones=tombs.astype(jnp.int64),
+            minput_live_values=sum(
+                (mt.count().astype(jnp.int64)
+                 for mt in state.minput_tables), jnp.zeros((), jnp.int64)),
         )
 
         def reclaim(state: AggState) -> AggState:
             inits = [ps.init(p.dtype)
                      for (_, ps), p in zip(self._prim_specs, state.prims)]
             dense = (state.prims, state.row_count, state.dirty,
-                     state.prev_prims, state.prev_row_count, state.emitted,
-                     state.minput_vals, state.minput_occ)
-            fills = inits + [0, False] + inits + [0, False] + [0] * len(
-                state.minput_vals) + [False] * len(state.minput_occ)
-            table, (prims, row_count, dirty, prev_prims, prev_row_count,
-                    emitted, minput_vals, minput_occ), lost = \
-                state.table.reclaimed(dense, fills)
+                     state.prev_prims, state.prev_row_count, state.emitted)
+            fills = inits + [0, False] + inits + [0, False]
+            if self._minput_aggs:
+                # where each group came from, so that the materialised
+                # input's values can follow their group
+                dense += (jnp.arange(size, dtype=jnp.int32),)
+                fills += [size]
+            table, dense, lost = state.table.reclaimed(dense, fills)
+            (prims, row_count, dirty, prev_prims, prev_row_count,
+             emitted) = dense[:6]
+            gslot = state.minput_gslot
+            if self._minput_aggs:
+                now_at = jnp.full((size + 1,), size, jnp.int32).at[
+                    dense[6]].set(jnp.arange(size, dtype=jnp.int32),
+                                  mode="drop")
+                gslot = tuple(now_at[g] for g in gslot)
             return state._replace(
                 table=table, prims=prims, row_count=row_count, dirty=dirty,
                 prev_prims=prev_prims, prev_row_count=prev_row_count,
-                emitted=emitted, minput_vals=minput_vals,
-                minput_occ=minput_occ, overflow=state.overflow + lost,
+                emitted=emitted, minput_gslot=gslot,
+                overflow=state.overflow + lost,
                 reclaim_passes=state.reclaim_passes + 1,
                 reclaim_slots=state.reclaim_slots + tombs,
             )
 
         state = jax.lax.cond(tombs > 0, reclaim, lambda s: s, state)
 
-        # distinct dedup tables reclaim independently (their own keys)
-        def reclaim_d(dt, cnt):
-            dt, (cnt,), lost = dt.reclaimed((cnt,))
-            return dt, cnt, lost
+        # the counted tables (distinct dedup, materialised input)
+        # reclaim independently: their own keys, their own tombstones
+        def reclaim_counted(args):
+            t, dense = args
+            # a count is 0 in a slot never used, a group slot ``size``
+            return t.reclaimed(dense, [0] + [size] * (len(dense) - 1))
 
-        d_tables = []
-        d_counts = []
-        overflow = state.overflow
-        for dt, cnt in zip(state.distinct_tables, state.distinct_counts):
-            dt, cnt, lost = jax.lax.cond(
-                dt.tombstone_count() > 0, reclaim_d,
-                lambda dt, cnt: (dt, cnt, jnp.zeros((), jnp.int64)),
-                dt, cnt,
-            )
-            d_tables.append(dt)
-            d_counts.append(cnt)
-            overflow = overflow + lost
+        def reclaim_all(tables, denses):
+            out_t, out_d = [], []
+            lost = jnp.zeros((), jnp.int64)
+            for t, dense in zip(tables, denses):
+                t, dense, n = jax.lax.cond(
+                    t.tombstone_count() > 0, reclaim_counted,
+                    lambda a: (a[0], a[1], jnp.zeros((), jnp.int64)),
+                    (t, dense),
+                )
+                out_t.append(t)
+                out_d.append(dense)
+                lost = lost + n
+            return out_t, out_d, lost
+
+        d_tables, d_dense, lost_d = reclaim_all(
+            state.distinct_tables, [(c,) for c in state.distinct_counts])
+        m_tables, m_dense, lost_m = reclaim_all(
+            state.minput_tables,
+            list(zip(state.minput_counts, state.minput_gslot)))
         return state._replace(
             distinct_tables=tuple(d_tables),
-            distinct_counts=tuple(d_counts),
-            overflow=overflow,
+            distinct_counts=tuple(d[0] for d in d_dense),
+            distinct_overflow=state.distinct_overflow + lost_d,
+            minput_tables=tuple(m_tables),
+            minput_counts=tuple(d[0] for d in m_dense),
+            minput_gslot=tuple(d[1] for d in m_dense),
+            minput_overflow=state.minput_overflow + lost_m,
         )
 
     # ------------------------------------------------------------------
@@ -1222,26 +1242,31 @@ class HashAggExecutor(Executor):
         if key_null is not None:
             stale = stale & ~key_null  # NULL keys are never below a wm
         table = state.table.clear_where(stale)
-        # distinct dedup keys carry the same group-key prefix: evict
-        # their (group, value) rows with the window too
-        d_tables = []
-        d_counts = []
-        for dt, cnt in zip(state.distinct_tables, state.distinct_counts):
-            k, kn = split_col(dt.key_cols[key_col_idx])
-            stale_d = dt.occupied & (k < threshold)
-            if kn is not None:
-                stale_d = stale_d & ~kn
-            d_tables.append(dt.clear_where(stale_d))
-            d_counts.append(jnp.where(stale_d, 0, cnt))
+        # the counted tables' keys carry the same group-key prefix:
+        # evict their (group, value) rows with the window too
+        def clean_counted(tables, counts):
+            out_t, out_c = [], []
+            for t, cnt in zip(tables, counts):
+                k, kn = split_col(t.key_cols[key_col_idx])
+                stale_t = t.occupied & (k < threshold)
+                if kn is not None:
+                    stale_t = stale_t & ~kn
+                out_t.append(t.clear_where(stale_t))
+                out_c.append(jnp.where(stale_t, 0, cnt))
+            return tuple(out_t), tuple(out_c)
+
+        d_tables, d_counts = clean_counted(
+            state.distinct_tables, state.distinct_counts)
+        m_tables, m_counts = clean_counted(
+            state.minput_tables, state.minput_counts)
         return state._replace(
             table=table,
             row_count=jnp.where(stale, 0, state.row_count),
             dirty=state.dirty & ~stale,
             prev_row_count=jnp.where(stale, 0, state.prev_row_count),
             emitted=state.emitted & ~stale,
-            minput_occ=tuple(
-                o & ~stale[:, None] for o in state.minput_occ
-            ),
-            distinct_tables=tuple(d_tables),
-            distinct_counts=tuple(d_counts),
+            distinct_tables=d_tables,
+            distinct_counts=d_counts,
+            minput_tables=m_tables,
+            minput_counts=m_counts,
         )

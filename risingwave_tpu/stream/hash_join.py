@@ -43,6 +43,31 @@ codes, but pads land in the transitions section rather than physically
 adjacent to their replacement pair — every consumer in this codebase is
 slot-keyed or sign-based, so only the op *codes* carry the pairing.
 
+**Three stores for a side**, picked by the planner from what it sees of
+the side's changelog (``sql/planner.py`` ``resolve_join``):
+
+- *pool* (``PoolSideState``): an append-only side.  A ring of rows in
+  arrival order behind one ``(key-hash, rank)`` tag table; no cap a key.
+- *keyed* (``KeyedSideState``): a retractable side of an inner join whose
+  join key is part of its stream key, so that many rows share a key
+  (Nexmark q5: 110 k ``(auction, window)`` counts under seven window
+  starts).  One row a slot of a ``HashTable`` over the stream key: a
+  retraction finds its row in O(1) by what identifies it, and the other
+  side's change at key *w* finds the rows of *w* by a masked pass over
+  the slots (``key == w``, then the residual predicate), compacted into
+  the emission windows; nothing is ``[chunk, bucket]``.  The cost of a
+  probe is the table, so the planner takes this store only where the
+  other side holds at most a row a key and so sends few rows.
+- *dense* (``SideState``): every other retractable side, the
+  ``[size, bucket_cap]`` buckets described above.
+
+**A residual predicate** (a non-equality conjunct of an inner join's ON)
+is applied where pairs are staged: a build row counts as a match only if
+the predicate holds for (probe row, build row), so a change emits the
+pairs that qualify before and after it and not the key's every row.
+Dense and keyed build sides take it; behind a pool side it stays a
+filter after the join.
+
 State cleaning is per ROW: a side's ``clean`` rule names an event-time
 expression of its rows, and a row retires once that value falls below
 the watermark less a lag.  A window key that is part of the join key
@@ -50,7 +75,7 @@ the watermark less a lag.  A window key that is part of the join key
 ``L.ts BETWEEN R.ts - c AND R.ts``) are both cases of it.  A pool side
 is a RING in arrival order and retires the longest expired prefix of
 it, a tile at a time, so the cost follows what was retired; a dense
-side masks its buckets.  ``reclaim`` then gives the tombstoned table
+side masks its buckets, a keyed side its slots.  ``reclaim`` then gives the tombstoned table
 slots back (``HashTable.reclaimed`` / ``TagTable.reclaimed``).
 """
 
@@ -62,7 +87,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from risingwave_tpu.common.chunk import Chunk, NCol, StrCol, split_col
+import dataclasses
+
+from risingwave_tpu.common.chunk import (
+    Chunk,
+    NCol,
+    StrCol,
+    conform_col,
+    split_col,
+)
 from risingwave_tpu.common.hash import hash64_columns
 
 
@@ -87,8 +120,10 @@ from risingwave_tpu.state.hash_table import (
     TOMB_TAG,
     HashTable,
     TagTable,
+    _scan_slots,
     _scatter_key,
     gather_key,
+    keys_equal,
 )
 
 
@@ -103,6 +138,34 @@ def _empty_store(f: Field, size: int, bucket: int):
     if f.nullable:
         return NCol(col, jnp.zeros((size, bucket), jnp.bool_))
     return col
+
+
+def _flat_store(f: Field, n: int):
+    """Zeroed ``[n]`` storage for one column of a side's rows."""
+    if f.data_type.is_string:
+        col = StrCol(
+            jnp.zeros((n, f.str_width), jnp.uint8),
+            jnp.zeros((n,), jnp.int32),
+        )
+    else:
+        col = jnp.zeros((n,), f.data_type.physical_dtype)
+    if f.nullable:
+        return NCol(col, jnp.zeros((n,), jnp.bool_))
+    return col
+
+
+def _input_refs(e) -> set:
+    """Positions of the input columns an expression reads."""
+    if isinstance(e, InputRef):
+        return {e.index}
+    out: set = set()
+    if dataclasses.is_dataclass(e):
+        for f in dataclasses.fields(e):
+            v = getattr(e, f.name)
+            for x in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(x, Expr):
+                    out |= _input_refs(x)
+    return out
 
 
 def _pool_capacity(rows: tuple) -> int:
@@ -231,6 +294,32 @@ class SideState(NamedTuple):
     inconsistency: jnp.ndarray
     # -- tallies and levels (fragment.JOIN_TALLY_ATTRS / JOIN_GAUGE_ATTRS)
     insert_rows: jnp.ndarray
+    delete_rows: jnp.ndarray
+    probe_steps: jnp.ndarray
+    emit_rows: jnp.ndarray
+    cleaned_rows: jnp.ndarray
+    reclaim_slots: jnp.ndarray
+    live_rows: jnp.ndarray
+    tombstones: jnp.ndarray
+    table_slots: jnp.ndarray
+
+
+class KeyedSideState(NamedTuple):
+    """A retractable side stored by what identifies a row: ONE row a
+    slot of a ``HashTable`` over the side's stream-key columns, the
+    row's columns in ``[size]`` stores beside it.  A change finds its
+    slot in one probe (the last change of a chunk to a slot decides what
+    the slot holds); the other side finds a join key's rows by a masked
+    pass over the slots (module docstring)."""
+
+    table: HashTable       # over the stream-key columns
+    rows: tuple            # [size] stores, one per input column
+    overflow: jnp.ndarray  # int64 — rows the table was full for
+    #: deletes of a row the side does not hold (ref consistency_error!)
+    inconsistency: jnp.ndarray
+    # -- tallies and levels (fragment.JOIN_TALLY_ATTRS / JOIN_GAUGE_ATTRS)
+    insert_rows: jnp.ndarray
+    delete_rows: jnp.ndarray
     probe_steps: jnp.ndarray
     emit_rows: jnp.ndarray
     cleaned_rows: jnp.ndarray
@@ -275,8 +364,8 @@ class PoolSideState(NamedTuple):
       ``clean`` needs to find a retiring row's entries, and when.
 
     Append-only sides only (the bench/windowed-join shape): deletes
-    would need value→rank search; retractable sides keep the dense
-    bucket layout.
+    would need value→rank search; a retractable side is a
+    ``KeyedSideState`` or a dense ``SideState`` (module docstring).
     """
 
     table: TagTable        # packed (key-hash, rank) tags -> entry slot
@@ -396,6 +485,8 @@ class HashJoinExecutor:
         right_storage: str = "dense",
         left_pool_size: int | None = None,
         right_pool_size: int | None = None,
+        left_row_key: Sequence[int] | None = None,
+        right_row_key: Sequence[int] | None = None,
     ):
         if join_type not in JOIN_TYPES:
             raise ValueError(f"unknown join type {join_type!r}")
@@ -415,12 +506,23 @@ class HashJoinExecutor:
         self.left_table_size = left_table_size or table_size
         self.right_table_size = right_table_size or table_size
         self.out_capacity = out_capacity
-        #: per-side storage: "dense" [size, B] buckets (general; caps
-        #: hot keys) or "pool" shared-row-pool (degree-adaptive;
-        #: append-only sides)
-        if left_storage not in ("dense", "pool") \
-                or right_storage not in ("dense", "pool"):
-            raise ValueError("storage must be 'dense' or 'pool'")
+        #: per-side storage (module docstring): "dense" [size, B]
+        #: buckets (general; caps hot keys), "pool" shared row ring
+        #: (degree-adaptive; append-only sides) or "keyed" one row a
+        #: slot by the side's stream key ``*_row_key`` (retractable
+        #: sides with hot keys; inner joins)
+        for storage, row_key in ((left_storage, left_row_key),
+                                 (right_storage, right_row_key)):
+            if storage not in ("dense", "pool", "keyed"):
+                raise ValueError(
+                    "storage must be 'dense', 'pool' or 'keyed'")
+            if storage == "keyed" and (join_type != "inner"
+                                       or not row_key):
+                raise ValueError(
+                    "a keyed side needs an inner join and the columns "
+                    "that identify its rows")
+        self.left_row_key = tuple(left_row_key or ())
+        self.right_row_key = tuple(right_row_key or ())
         self.left_storage = left_storage
         self.right_storage = right_storage
         self.left_pool_size = left_pool_size or (
@@ -457,6 +559,11 @@ class HashJoinExecutor:
         #: nexmark q8; a time band between the sides, nexmark q7)
         self.left_clean: JoinClean | None = None
         self.right_clean: JoinClean | None = None
+        #: a predicate over the OUTPUT schema (left ++ right) a pair has
+        #: to meet beside the key equality (inner joins; not behind a
+        #: pool side): the planner sets it, ``apply_begin`` applies it
+        #: where it stages pairs
+        self.residual: Expr | None = None
         #: prefix of this join's ``jax.named_scope``s in a device
         #: profile (``/insert``, ``/probe``, ``/emit``, ``/clean``,
         #: ``/reclaim``); the DAG runtime appends the node index
@@ -496,7 +603,22 @@ class HashJoinExecutor:
             occupied=jnp.zeros((size, bucket), jnp.bool_),
             count=jnp.zeros((size,), jnp.int32),
             overflow=_zero64(), inconsistency=_zero64(),
-            insert_rows=_zero64(), probe_steps=_zero64(),
+            insert_rows=_zero64(), delete_rows=_zero64(),
+            probe_steps=_zero64(),
+            emit_rows=_zero64(), cleaned_rows=_zero64(),
+            reclaim_slots=_zero64(), live_rows=_zero64(),
+            tombstones=_zero64(), table_slots=jnp.int64(size),
+        )
+
+    def _keyed_side_state(self, schema: Schema, row_key: Sequence[int],
+                          size: int) -> KeyedSideState:
+        return KeyedSideState(
+            table=HashTable.create(
+                [_flat_store(schema[i], 1) for i in row_key], size),
+            rows=tuple(_flat_store(f, size) for f in schema),
+            overflow=_zero64(), inconsistency=_zero64(),
+            insert_rows=_zero64(), delete_rows=_zero64(),
+            probe_steps=_zero64(),
             emit_rows=_zero64(), cleaned_rows=_zero64(),
             reclaim_slots=_zero64(), live_rows=_zero64(),
             tombstones=_zero64(), table_slots=jnp.int64(size),
@@ -504,18 +626,6 @@ class HashJoinExecutor:
 
     def _pool_side_state(self, schema: Schema, keys: Sequence[Expr],
                          size: int, pool: int) -> PoolSideState:
-        def flat_store(f: Field):
-            if f.data_type.is_string:
-                col = StrCol(
-                    jnp.zeros((pool, f.str_width), jnp.uint8),
-                    jnp.zeros((pool,), jnp.int32),
-                )
-            else:
-                col = jnp.zeros((pool,), f.data_type.physical_dtype)
-            if f.nullable:
-                return NCol(col, jnp.zeros((pool,), jnp.bool_))
-            return col
-
         # ONE fused tag table: live entries == live ring rows, plus the
         # heads that outlive their rank-0 row.  It has the ring's size
         # unless the side's table size asks for more slots (a ring that
@@ -525,7 +635,7 @@ class HashJoinExecutor:
             table=TagTable.create(size),
             count=jnp.zeros((size,), jnp.int32),
             pool_pos=jnp.zeros((size,), jnp.int32),
-            rows=tuple(flat_store(f) for f in schema),
+            rows=tuple(_flat_store(f, pool) for f in schema),
             row_hash=jnp.zeros((pool,), jnp.uint64),
             row_rank=jnp.zeros((pool,), jnp.int32),
             row_clean=jnp.zeros((pool,), jnp.int64),
@@ -540,27 +650,27 @@ class HashJoinExecutor:
     def storage_of(self, side: str) -> str:
         return self.left_storage if side == "left" else self.right_storage
 
+    def _init_side(self, side: str):
+        left = side == "left"
+        schema = self.left_schema if left else self.right_schema
+        keys = self.left_keys if left else self.right_keys
+        size = self.left_table_size if left else self.right_table_size
+        storage = self.storage_of(side)
+        if storage == "pool":
+            return self._pool_side_state(
+                schema, keys, size,
+                self.left_pool_size if left else self.right_pool_size)
+        if storage == "keyed":
+            return self._keyed_side_state(
+                schema, self.left_row_key if left else self.right_row_key,
+                size)
+        return self._side_state(
+            schema, keys,
+            self.left_bucket_cap if left else self.right_bucket_cap, size)
+
     def init_state(self) -> JoinState:
-        if self.left_storage == "pool":
-            left = self._pool_side_state(
-                self.left_schema, self.left_keys,
-                self.left_table_size, self.left_pool_size,
-            )
-        else:
-            left = self._side_state(
-                self.left_schema, self.left_keys, self.left_bucket_cap,
-                self.left_table_size,
-            )
-        if self.right_storage == "pool":
-            right = self._pool_side_state(
-                self.right_schema, self.right_keys,
-                self.right_table_size, self.right_pool_size,
-            )
-        else:
-            right = self._side_state(
-                self.right_schema, self.right_keys, self.right_bucket_cap,
-                self.right_table_size,
-            )
+        left = self._init_side("left")
+        right = self._init_side("right")
         return JoinState(
             left=left, right=right,
             emit_overflow=jnp.zeros((), jnp.int64),
@@ -676,6 +786,58 @@ class HashJoinExecutor:
             inconsistency=side.inconsistency + n_missing,
             insert_rows=side.insert_rows
             + jnp.sum(got.astype(jnp.int64)),
+            delete_rows=side.delete_rows
+            + jnp.sum(any_clear.astype(jnp.int64)),
+        )
+
+    def _update_side_keyed(self, side: KeyedSideState, chunk: Chunk,
+                           keys: Sequence[Expr], schema: Schema,
+                           row_key: Sequence[int]) -> KeyedSideState:
+        """Apply the chunk's inserts/deletes to a keyed side: every row
+        finds or claims the slot of its stream key in ONE probe, and the
+        LAST row of the chunk at a slot decides what the slot holds (an
+        insert its values, a delete nothing), as the view's upsert
+        does.  A delete of a key the side did not hold is counted."""
+        size = side.table.size
+        cap = chunk.capacity
+        _, null_keys = _null_stripped_keys([e.eval(chunk) for e in keys])
+        signs = chunk.signs()
+        touch = chunk.valid & (signs != 0)
+        if null_keys is not None:
+            touch = touch & ~null_keys  # a NULL join key matches nothing
+        pk = [conform_col(chunk.columns[i], schema[i].nullable, cap)
+              for i in row_key]
+        table, slots, claimed, over = side.table.lookup_or_insert(pk, touch)
+        ok = touch & ~over
+        with jax.named_scope(f"{self.scope}/delete"):
+            # the chunk's rows by slot, in arrival order within a slot
+            by_slot = jnp.where(ok, slots, jnp.int32(size))
+            order = jnp.argsort(by_slot, stable=True)
+            s_slot = by_slot[order]
+            last = jnp.zeros((cap,), jnp.bool_).at[order].set(
+                jnp.concatenate([s_slot[1:] != s_slot[:-1],
+                                 jnp.ones((1,), jnp.bool_)]))
+            put = ok & last & (signs > 0)
+            drop = ok & last & (signs < 0)
+            # the row that claimed a fresh slot is the first of its key
+            # in the chunk: a delete there has nothing to delete
+            missing = ok & claimed & (signs < 0)
+            table = table.clear_slots(slots, drop)
+        at = jnp.where(put, slots, jnp.int32(size))
+        rows = tuple(
+            _scatter_key(store, at, conform_col(col, f.nullable, cap), size)
+            for store, col, f in zip(side.rows, chunk.columns, schema)
+        )
+        n_missing = jnp.sum(missing.astype(jnp.int64))
+        return side._replace(
+            table=table, rows=rows,
+            overflow=side.overflow
+            + jnp.sum((over & touch).astype(jnp.int64)),
+            inconsistency=side.inconsistency + n_missing,
+            insert_rows=side.insert_rows
+            + jnp.sum((ok & (signs > 0)).astype(jnp.int64)),
+            delete_rows=side.delete_rows
+            + jnp.sum((ok & (signs < 0)).astype(jnp.int64)) - n_missing,
         )
 
     def _update_side_pool(self, side: PoolSideState, chunk: Chunk,
@@ -795,6 +957,84 @@ class HashJoinExecutor:
         cap = safe_slots.shape[0]
         return h.reshape(cap, side.occupied.shape[1])
 
+    # -- the residual predicate and the keyed probe ----------------------
+    def _pair_chunk(self, probe_cols, build_cols, side: str, n: int):
+        """A chunk over the OUTPUT schema for evaluating the residual:
+        the arriving side's columns and the build side's, each ``[n]``;
+        a column the predicate does not read is left out (None)."""
+        cols = (tuple(probe_cols) + tuple(build_cols)) if side == "left" \
+            else (tuple(build_cols) + tuple(probe_cols))
+        return Chunk(cols, jnp.zeros((n,), jnp.int8),
+                     jnp.ones((n,), jnp.bool_), self._out_schema)
+
+    def _residual_holds(self, pair_chunk: Chunk) -> jnp.ndarray:
+        v, null = split_col(self.residual.eval(pair_chunk))
+        return v if null is None else v & ~null
+
+    def _residual_split(self, side: str):
+        """(positions the residual reads among the probe side's columns,
+        among the build side's)."""
+        n_left = len(self.left_schema)
+        refs = _input_refs(self.residual)
+        lrefs = {i for i in refs if i < n_left}
+        rrefs = {i - n_left for i in refs if i >= n_left}
+        return (lrefs, rrefs) if side == "left" else (rrefs, lrefs)
+
+    def _residual_grid(self, probe_cols, build: SideState, safe,
+                       side: str) -> jnp.ndarray:
+        """bool [cap, B]: the residual for each (probe row, entry of
+        the bucket its key found) of a dense build side."""
+        cap = safe.shape[0]
+        B = build.occupied.shape[1]
+        prefs, brefs = self._residual_split(side)
+        flat = lambda x: x.reshape((cap * B,) + x.shape[2:])
+        pcols = [
+            jax.tree.map(lambda x: jnp.repeat(x, B, axis=0), c)
+            if i in prefs else None for i, c in enumerate(probe_cols)]
+        bcols = [
+            jax.tree.map(flat, _gather_bucket(store, safe))
+            if i in brefs else None for i, store in enumerate(build.rows)]
+        ok = self._residual_holds(
+            self._pair_chunk(pcols, bcols, side, cap * B))
+        return ok.reshape(cap, B)
+
+    def _keyed_matcher(self, probe_cols, key_cols, build: KeyedSideState,
+                       side: str):
+        """``hit_of(r) -> bool [size]``: the slots of a keyed build side
+        that probe row ``r`` pairs with (join keys equal, the residual
+        holds): one masked pass over the slots."""
+        size = build.table.size
+        bschema = self.right_schema if side == "left" else self.left_schema
+        bkeys = self.right_keys if side == "left" else self.left_keys
+        stored = Chunk(build.rows, jnp.zeros((size,), jnp.int8),
+                       build.table.occupied, bschema)
+        bkey_cols, _ = _null_stripped_keys([e.eval(stored) for e in bkeys])
+        if self.residual is not None:
+            prefs, brefs = self._residual_split(side)
+            bcols = [c if i in brefs else None
+                     for i, c in enumerate(build.rows)]
+
+        def row_of(col, r):
+            # row r of a column, shaped to broadcast against [size]
+            return jax.tree.map(lambda x: x[r][None], col)
+
+        def hit_of(r):
+            hit = build.table.occupied
+            for bk, pk in zip(bkey_cols, key_cols):
+                hit = hit & keys_equal(bk, row_of(pk, r))
+            if self.residual is not None:
+                pcols = [
+                    jax.tree.map(
+                        lambda x: jnp.broadcast_to(
+                            x[r], (size,) + x.shape[1:]), c)
+                    if i in prefs else None
+                    for i, c in enumerate(probe_cols)]
+                hit = hit & self._residual_holds(
+                    self._pair_chunk(pcols, bcols, side, size))
+            return hit
+
+        return hit_of
+
     # -- output-centric windowed emission --------------------------------
     def apply_begin(self, state: JoinState, chunk: Chunk, side: str):
         """Update own-side state and stage the emission space.
@@ -822,6 +1062,14 @@ class HashJoinExecutor:
                     own, chunk, keys, own_clean,
                     key_cols=key_cols, null_keys=null_keys, h=probe_hash,
                 )
+            elif self.storage_of(side) == "keyed":
+                own2 = self._update_side_keyed(
+                    own, chunk, keys,
+                    self.left_schema if side == "left"
+                    else self.right_schema,
+                    self.left_row_key if side == "left"
+                    else self.right_row_key,
+                )
             else:
                 own2 = self._update_side(own, chunk, keys)
 
@@ -831,8 +1079,32 @@ class HashJoinExecutor:
             joinable = active if null_keys is None else active & ~null_keys
 
             # probe the build (other) side: per-row key slot + live rows
-            if self.storage_of("right" if side == "left" else "left") \
-                    == "pool":
+            build_storage = self.storage_of(
+                "right" if side == "left" else "left")
+            if build_storage == "keyed":
+                # keyed build side: one masked pass over its slots a
+                # probe row (the planner takes this store where the
+                # probing side sends few rows)
+                bsize = other.table.size
+                sel = mask_indices(joinable, cap, cap)
+                hit_of = self._keyed_matcher(
+                    chunk.columns, key_cols, other, side)
+
+                def count_row(i, m):
+                    r = sel[i]
+                    return m.at[r].set(jnp.sum(hit_of(r), dtype=jnp.int32))
+
+                n_probe = jnp.sum(joinable, dtype=jnp.int32)
+                m = jax.lax.fori_loop(
+                    0, n_probe, count_row, jnp.zeros((cap,), jnp.int32))
+                safe = jnp.zeros((cap,), jnp.int32)
+                probe_over = jnp.zeros((), jnp.int64)
+                rank_to_idx = jnp.zeros((cap, 1), jnp.int32)
+                base = jnp.zeros((cap,), jnp.int32)
+                own2 = own2._replace(
+                    probe_steps=own2.probe_steps
+                    + n_probe.astype(jnp.int64) * bsize)
+            elif build_storage == "pool":
                 # pool build side: ONE fused-table probe of the key's HEAD
                 # entry (hash, 0) yields its degree; rows are addressed at
                 # emission time by (key-hash, rank)
@@ -851,6 +1123,9 @@ class HashJoinExecutor:
                 )
                 safe = jnp.minimum(slots, bsize - 1)
                 occ = other.occupied[safe] & found[:, None]        # [cap, B]
+                if self.residual is not None:
+                    occ = occ & self._residual_grid(
+                        chunk.columns, other, safe, side)
                 m = jnp.sum(occ, axis=1).astype(jnp.int32)
                 # rank -> bucket index of the k-th live row (occupied
                 # first, stable: bool sort of the occupancy bitmap only)
@@ -1008,7 +1283,47 @@ class HashJoinExecutor:
 
         build_rows, build_index = build_rows
         probe_bound = jnp.int64(0)
-        if build_index is not None:
+        if isinstance(build_index, KeyedSideState):
+            # keyed build side: pair j of probe row r is the j-th slot
+            # r's masked pass finds.  The window's pairs belong to a
+            # few consecutive probe rows (those with a match): one pass
+            # each, its running count searched for the ranks wanted
+            bsize = build_index.table.size
+            keys = self.left_keys if side == "left" else self.right_keys
+            schema = self.left_schema if side == "left" \
+                else self.right_schema
+            key_cols, _ = _null_stripped_keys([
+                e.eval(Chunk(p.probe_cols, jnp.zeros((cap,), jnp.int8),
+                             jnp.ones((cap,), jnp.bool_), schema))
+                for e in keys])
+            hit_of = self._keyed_matcher(
+                p.probe_cols, key_cols, build_index, side)
+            has = p.m > 0
+            nz_rank = jnp.cumsum(has, dtype=jnp.int32) - 1
+            nz_sel = mask_indices(has, cap, cap)
+            n_in = jnp.sum(in_pairs, dtype=jnp.int32)
+            first = jnp.argmax(in_pairs).astype(jnp.int32)
+            k_lo = nz_rank[r[first]]
+            k_hi = nz_rank[r[jnp.maximum(first + n_in - 1, 0)]]
+
+            def locate(t, bslot):
+                rk = nz_sel[jnp.minimum(k_lo + t, cap - 1)]
+                seen = _scan_slots(
+                    jax.lax.cumsum, jnp.add,
+                    hit_of(rk).astype(jnp.int32), 0)
+                at = jnp.searchsorted(
+                    seen, j + 1, side="left", method="scan")
+                return jnp.where(in_pairs & (r == rk),
+                                 at.astype(jnp.int32), bslot)
+
+            bslot = jax.lax.fori_loop(
+                0, jnp.where(n_in > 0, k_hi - k_lo + 1, 0), locate,
+                jnp.zeros((out_cap,), jnp.int32))
+            bslot = jnp.minimum(bslot, bsize - 1)
+
+            def build_val(store):
+                return jax.tree.map(lambda x: x[bslot], store)
+        elif build_index is not None:
             # pool build side: ONE vectorized (key-hash, rank) fused-
             # table lookup resolves every build row this window needs;
             # the entry's pool_pos value addresses the bump-allocated
@@ -1119,6 +1434,8 @@ class HashJoinExecutor:
         build = state.right if side == "left" else state.left
         if isinstance(build, PoolSideState):
             return build.rows, (build.table, build.pool_pos)
+        if isinstance(build, KeyedSideState):
+            return build.rows, build
         return build.rows, None
 
     # ------------------------------------------------------------------
@@ -1145,10 +1462,11 @@ class HashJoinExecutor:
         """Static bound on emission windows for one chunk (the dynamic
         ``pending.total`` governs actual trips; pool sides' worst case
         is the whole pool joining one probe row)."""
-        depth_l = self.left_pool_size if self.left_storage == "pool" \
-            else self.left_bucket_cap
-        depth_r = self.right_pool_size if self.right_storage == "pool" \
-            else self.right_bucket_cap
+        depth = {"pool": (self.left_pool_size, self.right_pool_size),
+                 "keyed": (self.left_table_size, self.right_table_size),
+                 "dense": (self.left_bucket_cap, self.right_bucket_cap)}
+        depth_l = depth[self.left_storage][0]
+        depth_r = depth[self.right_storage][1]
         worst = chunk_cap * max(depth_l, depth_r) * 2 + chunk_cap
         return -(-worst // self.out_capacity)
 
@@ -1178,6 +1496,11 @@ class HashJoinExecutor:
             return s._replace(table=table, count=count, pool_pos=pool_pos,
                               overflow=s.overflow + lost)
 
+        def reclaim_keyed(s: KeyedSideState) -> KeyedSideState:
+            table, (rows,), lost = s.table.reclaimed((s.rows,))
+            return s._replace(table=table, rows=rows,
+                              overflow=s.overflow + lost)
+
         sides = {}
         with jax.named_scope(f"{self.scope}/reclaim"):
             for name in ("left", "right"):
@@ -1185,6 +1508,9 @@ class HashJoinExecutor:
                 if isinstance(s, PoolSideState):
                     table, fn = s.table, reclaim_pool
                     live = s.head - s.tail
+                elif isinstance(s, KeyedSideState):
+                    table, fn = s.table, reclaim_keyed
+                    live = table.count().astype(jnp.int64)
                 else:
                     table, fn = s.key_table, reclaim_dense
                     live = jnp.sum(s.count, dtype=jnp.int64)
@@ -1206,6 +1532,8 @@ class HashJoinExecutor:
         with jax.named_scope(f"{self.scope}/clean"):
             if isinstance(s, PoolSideState):
                 cleaned = self._clean_pool(s, threshold)
+            elif isinstance(s, KeyedSideState):
+                cleaned = self._clean_keyed(s, rule.expr, side, threshold)
             else:
                 cleaned = self._clean_dense(s, rule.expr, side, threshold)
         return state._replace(**{side: cleaned})
@@ -1270,6 +1598,22 @@ class HashJoinExecutor:
             table=TagTable(tags, size), count=count, pool_pos=pool_pos,
             tail=tail, overflow=s.overflow + lost,
             cleaned_rows=s.cleaned_rows + (tail - s.tail),
+        )
+
+    def _clean_keyed(self, s: KeyedSideState, expr, side: str,
+                     threshold) -> KeyedSideState:
+        """Give up a keyed side's slots whose row has expired."""
+        schema = self.left_schema if side == "left" else self.right_schema
+        size = s.table.size
+        vals, null = split_col(expr.eval(Chunk(
+            s.rows, jnp.zeros((size,), jnp.int8), s.table.occupied,
+            schema)))
+        stale = s.table.occupied & (vals < threshold)
+        if null is not None:
+            stale = stale & ~null
+        return s._replace(
+            table=s.table.clear_where(stale),
+            cleaned_rows=s.cleaned_rows + jnp.sum(stale, dtype=jnp.int64),
         )
 
     def _clean_dense(self, s: SideState, expr, side: str,

@@ -618,8 +618,17 @@ def check_counter_values(name: str, labels: list[str],
                     f"{name}/{label}: emit overflow ({v} output rows "
                     "dropped) — increase out_capacity"
                 )
-            hint = "ring_size" if "Ring" in label or "AppendOnly" in label \
-                else "table/bucket capacity"
+            if kind == "minput_overflow":
+                hint = ("the materialised input of a min/max over a "
+                        "retractable input (its table's size)")
+            elif kind == "distinct_overflow":
+                hint = "the DISTINCT dedup table (distinct_table_size)"
+            elif "HashAgg" in label:
+                hint = "the group table (agg_table_size)"
+            elif "Ring" in label or "AppendOnly" in label:
+                hint = "ring_size"
+            else:
+                hint = "table/bucket capacity"
             raise RuntimeError(
                 f"{name}/{label}: state overflow ({v} rows dropped) — "
                 f"increase {hint}"

@@ -43,7 +43,6 @@ def make_engine() -> Engine:
         mv_ring_size=1 << 15,
         topn_pool_size=1 << 11,
         topn_emit_capacity=1 << 10,
-        minput_bucket_cap=128,
     ))
 
 

@@ -52,7 +52,6 @@ def make_engine() -> Engine:
         join_out_capacity=1 << 13,
         mv_table_size=1 << 11, mv_ring_size=1 << 13,
         topn_pool_size=1 << 10, topn_emit_capacity=1 << 9,
-        minput_bucket_cap=64,
     ))
 
 

@@ -120,7 +120,7 @@ HOP = ("HOP(bid, date_time, INTERVAL '2' SECOND, INTERVAL '10' SECOND) "
 VIEWS = {
     "count": ("SELECT auction, window_start, count(*) AS n FROM " + HOP),
     # the outer aggregate takes the inner one's retractions: max over a
-    # retractable input, ~11 counts a group in its materialized buckets
+    # retractable input, ~11 counts a group in its materialised input
     "retractable_max": (
         "SELECT window_start, lane, max(n) AS n FROM (SELECT auction % 128 "
         "AS lane, window_start, count(*) AS n FROM " + HOP + ") GROUP BY "

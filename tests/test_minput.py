@@ -67,7 +67,7 @@ def test_retractable_minmax_ground_truth():
         [("g", InputRef(0))],
         [AggCall("min", InputRef(1), "mn"), AggCall("max", InputRef(1), "mx")],
         table_size=64, emit_capacity=64,
-        retractable_input=True, minput_bucket_cap=8,
+        retractable_input=True, minput_table_size=32,
     )
     st = agg.init_state()
     acc: dict = {}
@@ -93,16 +93,19 @@ def test_retractable_minmax_ground_truth():
     assert int(st.overflow) == 0
 
 
-def test_minput_bucket_overflow_is_loud():
+def test_minput_table_overflow_is_loud():
+    """A materialised-input table with no slot left counts the row it
+    lost on its OWN counter, not the group table's."""
     agg = HashAggExecutor(
         SCHEMA, [("g", InputRef(0))],
         [AggCall("min", InputRef(1), "mn")],
         table_size=64, emit_capacity=64,
-        retractable_input=True, minput_bucket_cap=2,
+        retractable_input=True, minput_table_size=2,
     )
     st = agg.init_state()
     st, _ = agg.apply(st, make_chunk([(1, 1), (1, 2), (1, 3)], [0, 0, 0]))
-    assert int(st.overflow) == 1  # third value found no bucket space
+    assert int(st.minput_overflow) == 1  # the third value found no slot
+    assert int(st.overflow) == 0
 
 
 def test_sql_min_over_retractable_cascade():

@@ -377,15 +377,20 @@ def _groups(st):
     keys = np.asarray(st.table.key_cols[0])[occ]
     cols = [np.asarray(p)[occ] for p in st.prims]
     cols += [np.asarray(st.row_count)[occ], np.asarray(st.dirty)[occ]]
-    buckets = [
-        [tuple(sorted(v[o])) for v, o in zip(np.asarray(vals)[occ],
-                                             np.asarray(mocc)[occ])]
-        for vals, mocc in zip(st.minput_vals, st.minput_occ)
-    ]
+    # materialised input: the (value, multiplicity) pairs a group holds
+    inputs = []
+    for mt, cnt in zip(st.minput_tables, st.minput_counts):
+        held: dict = {}
+        live = np.asarray(mt.occupied) & (np.asarray(cnt) > 0)
+        for g, v, n in zip(np.asarray(mt.key_cols[0])[live],
+                           np.asarray(mt.key_cols[-1])[live],
+                           np.asarray(cnt)[live]):
+            held.setdefault(int(g), []).append((int(v), int(n)))
+        inputs.append(held)
     out = {}
     for i, k in enumerate(keys):
         out[int(k)] = tuple(c[i].item() for c in cols) \
-            + tuple(b[i] for b in buckets)
+            + tuple(tuple(sorted(h.get(int(k), ()))) for h in inputs)
     assert len(out) == len(keys)
     return out
 
@@ -537,7 +542,7 @@ def test_hash_agg_rep_tiles_retractable_minmax_and_filter(accel_branch):
                   AggCall("sum", col("v"), "s", filter=col("v") > 20),
                   count_star()],
             table_size=4096, emit_capacity=8, retractable_input=True,
-            minput_bucket_cap=16)
+            minput_table_size=8192)
 
     chunks = [_gv_chunk(g * 7919, v, cap),
               _gv_chunk(g2 * 7919, v2, cap, ops=ops2)]

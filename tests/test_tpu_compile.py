@@ -281,3 +281,57 @@ def test_view_read_gather_stacked_strings(one_chip):
         [jax.ShapeDtypeStruct((4, size), jnp.int64),
          jax.ShapeDtypeStruct((4, size, 24), jnp.uint8),
          jax.ShapeDtypeStruct((4, size), jnp.int32)])
+
+
+# -- the q5 join cell at its own sizes (benchmark/configs/nexmark_q5.json)
+
+@pytest.fixture(scope="module")
+def q5_join_job():
+    import json
+
+    cfg = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "configs", "nexmark_q5.json")))
+    eng = Engine(RwConfig.from_dict(cfg["server"]["config_json"]))
+    for stmt in cfg["sources_sql"]:
+        eng.execute(stmt)
+    eng.execute(cfg["view"]["sql"])
+    return eng.jobs[0], cfg["system_params"]["chunks_per_barrier"]
+
+
+def test_q5_join_window(q5_join_job, one_chip):
+    """The inner aggregate runs on panes under the DAG runtime too: the
+    window program's scatters take a tile of representatives."""
+    job, chunks = q5_join_job
+    _, hlo = _compile(job._multi_prog(chunks), one_chip, job.states,
+                      {"bid": K0}, text=True)
+    _assert_narrow_apply(hlo, 8192)
+
+
+def test_q5_join_barrier(q5_join_job, one_chip):
+    """The flush chain: the counts' changes through the max's
+    materialised input, the keyed join side and the view, in one
+    program; no scatter under the join's scopes is wider than a chunk
+    of changes (the keyed side's stores are written a chunk at a time,
+    the masked passes are elementwise)."""
+    job, _ = q5_join_job
+    _, hlo = _compile(job._make_barrier_prog(), one_chip, job.states,
+                      EPOCH, text=True)
+    for phase in ("insert", "delete", "probe", "emit"):
+        widths = _apply_scatter_widths(hlo, phase, "HashJoin")
+        assert max(widths.values(), default=0) <= 4 * 8192, (phase, widths)
+    assert "extreme" in hlo and "HashJoin.5/delete" in hlo
+
+
+def test_q5_join_maintain(q5_join_job, one_chip):
+    """Every store of the plan is reclaimed by tiles of movers: the
+    count tables, the max's materialised input, the keyed side."""
+    from risingwave_tpu.state import hash_table
+
+    job, _ = q5_join_job
+    _, hlo = _compile(job._make_maintain_prog(), one_chip, job.states,
+                      text=True)
+    for ex in ("HashAgg", "HashJoin"):
+        widths = _apply_scatter_widths(hlo, "reclaim", ex)
+        assert len(widths) >= 4, widths
+        assert max(widths.values()) <= hash_table.RECLAIM_TILE, (ex, widths)
