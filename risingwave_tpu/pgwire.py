@@ -25,6 +25,7 @@ clients fall back cleanly.
 
 from __future__ import annotations
 
+import contextlib
 import socket
 import socketserver
 import struct
@@ -118,6 +119,49 @@ def _substitute_params(sql: str, params: list,
         out.append(ch)
         i += 1
     return "".join(out)
+
+
+class EngineLock:
+    """The engine lock the served node's ticker, the pgwire sessions
+    and the scrape share, with a count of the statements that want it:
+    the ticker sends no window ahead of its barrier while one does
+    (``Engine.tick``'s ``ahead``).  A statement is counted from asking
+    for the lock to releasing it, so while the ticker holds the lock
+    every statement counted is waiting for it."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._count = threading.Lock()
+        self.statements = 0
+
+    def acquire(self) -> bool:
+        return self._lock.acquire()
+
+    def release(self) -> None:
+        self._lock.release()
+
+    def __enter__(self):
+        self._lock.acquire()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._lock.release()
+        return False
+
+    @contextlib.contextmanager
+    def statement(self):
+        """Hold the lock for one statement, its wait in
+        ``read.lock_wait``."""
+        self._add(1)
+        try:
+            with GLOBAL_TRACE.held(self, "read.lock_wait"):
+                yield
+        finally:
+            self._add(-1)
+
+    def _add(self, step: int) -> None:
+        with self._count:
+            self.statements += step
 
 
 class _Handler(socketserver.BaseRequestHandler):
@@ -227,7 +271,7 @@ class _Handler(socketserver.BaseRequestHandler):
                                 trial = _substitute_params(
                                     dsql, [None] * nparams, doids
                                 )
-                                with lock:
+                                with lock.statement():
                                     cols, _ = engine.query(trial)
                                 if cols:
                                     self._row_description(f, cols)
@@ -243,7 +287,7 @@ class _Handler(socketserver.BaseRequestHandler):
                                 )
                             # eager execution so RowDescription is
                             # exact; Execute drains the cache
-                            with lock:
+                            with lock.statement():
                                 cols, rows = engine.query(p["sql"])
                             p["cols"], p["rows"] = cols, rows
                             if cols:
@@ -288,8 +332,7 @@ class _Handler(socketserver.BaseRequestHandler):
                 kind=sql.split(None, 1)[0].upper() if sql.strip() else "",
         ) as sp:
             if "rows" not in p:
-                with GLOBAL_TRACE.held(self.server.engine_lock,
-                                       "read.lock_wait"):
+                with self.server.engine_lock.statement():
                     with GLOBAL_TRACE.span("read.execute"):
                         p["cols"], p["rows"] = engine.query(sql)
             sp.set(rows=len(p["rows"] or ()))
@@ -418,7 +461,7 @@ class PgServer(socketserver.ThreadingTCPServer):
     daemon_threads = True
 
     def __init__(self, engine, host: str = "127.0.0.1", port: int = 4566,
-                 engine_lock: threading.Lock | None = None,
+                 engine_lock: EngineLock | None = None,
                  password: str | None = None):
         super().__init__((host, port), _Handler)
         self.engine = engine
@@ -429,7 +472,7 @@ class PgServer(socketserver.ThreadingTCPServer):
         # shared catalog — same effective serialization for DDL).  The
         # lock must be installed BEFORE accepting: callers sharing it
         # with a barrier ticker pass it here
-        self.engine_lock = engine_lock or threading.Lock()
+        self.engine_lock = engine_lock or EngineLock()
 
 
 class SimpleClient:
@@ -568,7 +611,7 @@ class SimpleClient:
 
 
 def pg_serve(engine, host: str = "127.0.0.1", port: int = 4566,
-             engine_lock: threading.Lock | None = None,
+             engine_lock: EngineLock | None = None,
              password: str | None = None) -> PgServer:
     """Start serving in a background thread; returns the server handle
     (ref pg_serve, pg_server.rs:338)."""

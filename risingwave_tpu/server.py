@@ -126,12 +126,13 @@ def _wait_for_sigint() -> None:
 
 class SingleNode:
     def __init__(self, config=None, data_dir: str | None = None):
+        from risingwave_tpu.pgwire import EngineLock
         from risingwave_tpu.sql.engine import Engine
 
         self.engine = Engine(config, data_dir=data_dir)
         self._stop = threading.Event()
         self._ticker: threading.Thread | None = None
-        self._lock = threading.Lock()
+        self._lock = EngineLock()
 
     # -- barrier loop ---------------------------------------------------
     def _tick_loop(self) -> None:
@@ -158,7 +159,8 @@ class SingleNode:
         """One barrier of the loop, one ``tick-<n>`` span tree: the
         root runs from asking for the engine lock (``tick.lock_wait``
         is what reads and scrapes take out of every tick) to
-        ``Engine.tick`` returning."""
+        ``Engine.tick`` returning.  Another tick follows, so the tick
+        may send the next window ahead while no statement waits."""
         eng = self.engine
         if not eng.jobs or all(map(eng.ingest_waits, eng.jobs)):
             return  # nothing to drive: no barrier, no tree
@@ -166,7 +168,9 @@ class SingleNode:
                                metrics=self.engine.metrics):
             with GLOBAL_TRACE.held(self._lock, "tick.lock_wait"):
                 if self.engine.jobs:
-                    self.engine.tick(barriers=1)
+                    self.engine.tick(
+                        barriers=1,
+                        ahead=lambda: self._lock.statements == 0)
 
     def start(self, host: str = "127.0.0.1", port: int = 4566,
               ticker: bool = True):
@@ -217,9 +221,11 @@ class SingleNode:
         try:
             with self._lock:
                 if self.engine.jobs:
+                    # a window the ticker sent ahead seals first; then
                     # chunks_per_barrier=0: flush/commit what already
                     # flowed, pull nothing new on the way out (tick's
                     # batch boundary also drains the upload queue)
+                    self.engine.settle("stop")
                     self.engine.tick(barriers=1, chunks_per_barrier=0)
         finally:
             try:

@@ -15,9 +15,10 @@ scheduler (SURVEY.md §2.4), and the batch local-execution mode
 from __future__ import annotations
 
 import dataclasses
+import sys
 import time
 from collections import deque
-from typing import Any, Sequence
+from typing import Any, Callable, Sequence
 
 import numpy as np
 
@@ -299,6 +300,12 @@ class Engine:
 
         result = None
         for text, stmt in parse_with_text(sql):
+            # a window the served ticker sent ahead is sealed and
+            # durable before any statement sees the jobs
+            self.settle(
+                "flush" if isinstance(stmt, ast.FlushStatement)
+                else "ddl" if isinstance(stmt, self._LOGGED_DDL)
+                else "statement")
             # the statement's raw SQL, recorded as the catalog entry's
             # definition (re-parseable — job export/adoption ships it)
             self._stmt_text = text
@@ -2169,17 +2176,29 @@ class Engine:
 
     # -- the global barrier loop ----------------------------------------
     def tick(self, barriers: int = 1,
-             chunks_per_barrier: int | None = None) -> None:
+             chunks_per_barrier: int | None = None,
+             ahead: Callable[[], bool] | None = None) -> None:
         """Advance every streaming job (meta's PeriodicBarriers analog).
 
         One span tree a call: the served node's ``_tick_loop`` opens the
         ``tick`` root (it also times its wait for the engine lock) and
         this attaches under it; called with no trace active (an
-        in-process engine, ``FLUSH``, tests) it opens the root itself."""
+        in-process engine, ``FLUSH``, tests) it opens the root itself.
+
+        ``ahead`` is the served ticker's, which alone knows another
+        tick follows: once the last barrier has sealed its snapshot and
+        the upload's device reads are queued, an eligible job's next
+        window is dispatched (``window_ahead``) while ``ahead()`` says
+        no statement waits for the lock, and only then is the epoch
+        drained — the host's half of the checkpoint runs under the
+        window, and the next tick starts at its barrier.  Any other
+        caller first settles what is ahead (``settle``)."""
         if chunks_per_barrier is None:
             chunks_per_barrier = int(
                 self.system_params.get("chunks_per_barrier")
             )
+        if ahead is None:
+            self.settle("tick")
         # runtime-mutable cadence (ref ALTER SYSTEM SET applies live)
         cadence = self._barrier_cadence()
         stall_hook = self._storage_stall_hook \
@@ -2187,13 +2206,20 @@ class Engine:
         with GLOBAL_TRACE.root("tick", "tick", metrics=self.metrics,
                                barriers=barriers) as sp:
             rows = 0
+            crossed = []
             for _ in range(barriers):
+                crossed = []
                 for job in self.jobs:
-                    if self.ingest_waits(job, chunks_per_barrier):
+                    if job.window_ahead is None \
+                            and self.ingest_waits(job, chunks_per_barrier):
                         continue
                     job.write_stall_hook = stall_hook
                     rows += self._job_barrier(job, chunks_per_barrier,
                                               cadence)
+                    crossed.append(job)
+            if ahead is not None:
+                for job in crossed:
+                    self._window_ahead(job, chunks_per_barrier, ahead)
             # batch boundary = durability point: uploads sealed inside
             # the window pipelined against the barrier loop; they must
             # land before tick() returns (tests/FLUSH/restart
@@ -2205,6 +2231,59 @@ class Engine:
                 self._export_checkpoint_gauges(job)
             sp.set(rows=rows, epoch=max(
                 (j.sealed_epoch for j in self.jobs), default=0))
+
+    def _window_ahead(self, job, chunks_per_barrier: int,
+                      ahead: Callable[[], bool]) -> None:
+        """Dispatch ``job``'s next window before its sealed epoch is
+        drained, where all of this holds: the window is one
+        asynchronous dispatch, the barrier just sealed a snapshot, the
+        window brings chunks (``ingest_waits``), and no statement waits
+        for the lock — a waiting read then sees the sealed epoch on a
+        tree with nothing in flight, as it would without this.  The
+        window waits for the upload's device reads to be queued: the
+        chip runs programs in order, and a delta's gather behind the
+        window would wait for all of it."""
+        eligible = (chunks_per_barrier > 0 and not job.paused
+                    and job.sealed_snapshot
+                    and job.window_one_dispatch(chunks_per_barrier)
+                    and not self.ingest_waits(job, chunks_per_barrier))
+        went = False
+        if eligible:
+            up = job._uploader
+            if up is not None:
+                with GLOBAL_TRACE.span("wait_dispatched", job=job.name):
+                    up.wait_dispatched()
+            if ahead():
+                t0 = time.perf_counter()
+                with GLOBAL_TRACE.span("run_chunks", metrics=self.metrics,
+                                       job=job.name, ahead=1) as sp:
+                    rows = job.run_chunks(chunks_per_barrier)
+                    sp.set(rows=rows)
+                job.window_ahead = (rows, time.perf_counter() - t0)
+                went = True
+        # a series for every job the served ticker drives, so a share
+        # of 0 reads as 0 and not as absent
+        self.metrics.inc("barrier_windows_ahead_total", int(went),
+                         job=job.name)
+
+    def settle(self, by: str) -> None:
+        """Complete every window the served ticker dispatched ahead:
+        its barrier, seal and drain, as the next tick would have.  Any
+        holder of the engine lock but the scrape calls this before it
+        touches a job (``by``: ``statement``, ``ddl``, ``flush``,
+        ``tick``, ``stop``, ``recover``), so a statement runs against a
+        sealed, durable epoch with nothing in flight."""
+        jobs = [job for job in self.jobs if job.window_ahead is not None]
+        if not jobs:
+            return
+        cadence = self._barrier_cadence()
+        with GLOBAL_TRACE.span("settle", metrics=self.metrics, by=by):
+            for job in jobs:
+                self._job_barrier(job, 0, cadence)
+                job.drain_uploads()
+                self._export_checkpoint_gauges(job)
+                self.metrics.inc("barrier_windows_settled_total", 1,
+                                 job=job.name, by=by)
 
     def ingest_waits(self, job, chunks_per_barrier: int | None = None
                      ) -> bool:
@@ -2244,12 +2323,19 @@ class Engine:
             job.metrics = self.metrics
         name = job.name
         t0 = time.perf_counter()
-        with GLOBAL_TRACE.span("run_chunks", metrics=self.metrics,
-                               job=name) as sp:
-            # traceable sources batch the whole inter-barrier window
-            # into one dispatch (q1 host-overhead fix)
-            rows = job.run_chunks(chunks_per_barrier)
-            sp.set(rows=rows)
+        if job.window_ahead is not None:
+            # dispatched at the end of the last tick (``_window_ahead``):
+            # its rows and dispatch time count at the barrier that seals
+            # them
+            (rows, dispatch), job.window_ahead = job.window_ahead, None
+        else:
+            with GLOBAL_TRACE.span("run_chunks", metrics=self.metrics,
+                                   job=name) as sp:
+                # traceable sources batch the whole inter-barrier
+                # window into one dispatch (q1 host-overhead fix)
+                rows = job.run_chunks(chunks_per_barrier)
+                sp.set(rows=rows)
+            dispatch = time.perf_counter() - t0
         t1 = time.perf_counter()
         if fenced:
             # Exchange-lite: a partitioned barrier consumes EXACTLY to
@@ -2271,7 +2357,7 @@ class Engine:
         t3 = time.perf_counter()
         self.metrics.inc("stream_rows_total", rows, job=name)
         self._observe_barrier(
-            name, t3 - t0, dispatch=t1 - t0,
+            name, dispatch + t3 - t1, dispatch=dispatch,
             source_drain=(t2 - t1) if fenced else None,
             seal=t3 - t2,
         )
@@ -2476,7 +2562,14 @@ class Engine:
 
     def recover(self) -> None:
         """Restore every job from its last committed checkpoint
-        (ref §3.5: meta-driven recovery across all streaming jobs)."""
+        (ref §3.5: meta-driven recovery across all streaming jobs).  A
+        window ahead is settled first; where its barrier fails, the
+        rewind below is what resolves it."""
+        try:
+            self.settle("recover")
+        except Exception as e:  # noqa: BLE001 — the rewind resolves it
+            print(f"recover: the window ahead did not settle ({e!r}); "
+                  "rewinding past it", file=sys.stderr)
         for job in self.jobs:
             job.recover()
 

@@ -358,15 +358,16 @@ class CheckpointStore:
             return fn, caps
 
     def _fetch_delta(self, job_name: str, leaves, nblocks, lanes, dirty,
-                     payload: dict) -> tuple[dict, dict]:
+                     payload: dict, dispatched) -> tuple[dict, dict]:
         """Fill ``payload`` with the dirty runs of a delta checkpoint,
         keyed ``r_<leaf>_<first element>`` in leaf then element order.
         One algorithm, three sources a leaf: a host array is cut where
         it is; a device leaf with at most its capacity of dirty blocks
         has those gathered on the device; any other dirty device leaf
         crosses whole.  Everything that crosses does so in ONE
-        ``device_get``.  Returns the bytes that crossed, by path, and
-        the fetch span's ``blocks`` / ``whole_leaves``."""
+        ``device_get``, after ``dispatched()``.  Returns the bytes that
+        crossed, by path, and the fetch span's ``blocks`` /
+        ``whole_leaves``."""
         block = self.block_elems
         fn, caps = self._gather_fn(job_name, leaves, nblocks)
         starts = [np.zeros(c, np.int32) for c in caps]
@@ -405,6 +406,7 @@ class CheckpointStore:
                 tuple(leaves[i] for i in part),
                 tuple(starts[i] for i in part),
             )))
+        dispatched()
         fetched = jax.device_get(
             [gathered[i] for i in windows] + [leaves[i] for i in whole]
         )
@@ -434,7 +436,7 @@ class CheckpointStore:
     # -- checkpoint save: prepare (fetch) / commit (write) --------------
     def prepare(self, job_name: str, epoch: int, leaves, shapes,
                 treedef, source_state: dict, digests=None,
-                lanes=None) -> dict:
+                lanes=None, dispatched=lambda: None) -> dict:
         """Stage one epoch's payload on the host.
 
         ``leaves`` may be device arrays of any shape (they are read as
@@ -446,7 +448,9 @@ class CheckpointStore:
         extraction below walks rows and never emits a run crossing a
         shard boundary.  After this returns, the caller may freely
         mutate or donate the device buffers — everything needed by
-        ``commit`` is host-resident."""
+        ``commit`` is host-resident.  ``dispatched`` is called once
+        every device program of the fetch is queued, before the host
+        waits on the transfer."""
         from risingwave_tpu.storage.digest import lane_block_count
 
         block = self.block_elems
@@ -490,9 +494,9 @@ class CheckpointStore:
         with GLOBAL_TRACE.span("ckpt_prepare.fetch", job=job_name,
                                kind=kind) as span:
             if kind == "full":
-                host = jax.device_get(
-                    [jnp.asarray(x).reshape(-1) for x in leaves]
-                )
+                flat = [jnp.asarray(x).reshape(-1) for x in leaves]
+                dispatched()
+                host = jax.device_get(flat)
                 for i, (h, s) in enumerate(zip(host, shapes)):
                     payload[f"leaf_{i}"] = np.asarray(h).reshape(s)
                 moved = {"whole": sum(
@@ -501,7 +505,8 @@ class CheckpointStore:
                 counts = {"blocks": 0, "whole_leaves": len(host)}
             else:
                 moved, counts = self._fetch_delta(
-                    job_name, leaves, nblocks, lanes, dirty, payload
+                    job_name, leaves, nblocks, lanes, dirty, payload,
+                    dispatched,
                 )
             span.set(bytes=sum(moved.values()), **counts)
         if self.metrics is not None:

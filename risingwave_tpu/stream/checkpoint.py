@@ -11,10 +11,14 @@ epoch — shadow update dispatched, (epoch, digest vector, shadow leaf
 refs, source/spill state) enqueued — and returns immediately.  The
 uploader thread then:
 
-1. fetches the epoch's payload device→host (the digest diff picks the
-   dirty runs; ``CheckpointStore.prepare``), then marks the task
-   FETCHED — the next shadow update donates the shadow buffers, so it
-   must wait for this point and no further;
+1. reads the epoch's digests and queues the device programs of the
+   fetch (a delta's gather), then marks the task DISPATCHED — the
+   chip runs programs in order, so a window the served ticker sends
+   ahead (``Engine.tick``) waits for this point, not to stand before
+   the gather; then fetches the payload device→host (the digest diff
+   picks the dirty runs; ``CheckpointStore.prepare``) and marks the
+   task FETCHED — the next shadow update donates the shadow buffers,
+   so it must wait for this point and no further;
 2. encodes + writes the npz/meta objects and commits the manifest
    (``CheckpointStore.commit``), then ACKS the epoch.
 
@@ -68,6 +72,7 @@ class UploadTask:
     #: crash between tier and job save leaves the tier ahead, which
     #: recovery rewinds; the reverse order loses absorbed groups)
     spill: list = field(default_factory=list)
+    dispatched: threading.Event = field(default_factory=threading.Event)
     fetched: threading.Event = field(default_factory=threading.Event)
     done: threading.Event = field(default_factory=threading.Event)
     error: Exception | None = None
@@ -136,14 +141,23 @@ class CheckpointUploader:
     def wait_fetched(self, timeout: float = 600.0) -> None:
         """Block until every queued task's device→host fetch completed
         — the shadow buffers are about to be donated."""
+        self._wait_tasks("fetched", timeout)
+
+    def wait_dispatched(self, timeout: float = 600.0) -> None:
+        """Block until every queued task's fetch programs are in the
+        device's queue — the next window program is about to join it."""
+        self._wait_tasks("dispatched", timeout)
+
+    def _wait_tasks(self, point: str, timeout: float) -> None:
         with self._cv:
             tasks = list(self._pending)
         deadline = time.monotonic() + timeout
         for t in tasks:
-            if not t.fetched.wait(max(0.0, deadline - time.monotonic())):
+            if not getattr(t, point).wait(
+                    max(0.0, deadline - time.monotonic())):
                 raise TimeoutError(
-                    f"{self.job_name}: upload fetch of epoch {t.epoch} "
-                    f"did not complete within {timeout}s"
+                    f"{self.job_name}: upload of epoch {t.epoch} not "
+                    f"{point} within {timeout}s"
                 )
         self._raise_if_failed()
 
@@ -263,6 +277,7 @@ class CheckpointUploader:
                         self.job_name, task.epoch, task.leaves,
                         task.shapes, task.treedef, task.source_state,
                         digests=digests, lanes=task.lanes,
+                        dispatched=task.dispatched.set,
                     )
                 # host payload materialized: the shadow may be donated
                 task.fetched.set()
@@ -295,6 +310,7 @@ class CheckpointUploader:
                 except Exception:  # noqa: BLE001 — best-effort reap
                     pass
                 task.error = e
+                task.dispatched.set()
                 task.fetched.set()
                 task.done.set()
                 with self._cv:

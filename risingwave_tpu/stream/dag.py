@@ -701,16 +701,7 @@ class DagJob(BarrierLoop):
         (``fused_fallbacks``) so the degradation is observable."""
         if self.paused or n <= 0:
             return 0
-        reason = None
-        if not self.sources:
-            reason = "no_sources"
-        elif self.staged:
-            reason = "staged"
-        elif not all(
-            hasattr(src, "impl") and hasattr(src, "next_base")
-            for src in self.sources.values()
-        ):
-            reason = "host_chunk_source"
+        reason = self._unfused_reason()
         if reason is not None or n == 1:
             if reason is not None and n > 1:
                 count = self.fused_fallbacks.get(reason, 0) + 1
@@ -737,6 +728,20 @@ class DagJob(BarrierLoop):
             rows += reader.cap * n * k
         self.states = prog(self.states, k0s)
         return rows
+
+    def _unfused_reason(self) -> str | None:
+        """Why a window cannot be one fused dispatch, else None."""
+        if not self.sources:
+            return "no_sources"
+        if self.staged:
+            return "staged"
+        if not all(hasattr(src, "impl") and hasattr(src, "next_base")
+                   for src in self.sources.values()):
+            return "host_chunk_source"
+        return None
+
+    def window_one_dispatch(self, n: int) -> bool:
+        return n > 1 and self._unfused_reason() is None
 
     def _multi_prog(self, n: int):
         """The jitted n-round window program (linear or mesh), cached

@@ -140,6 +140,12 @@ class BarrierLoop:
         #: wait; one that brings none crosses, and the next pass looks
         #: again
         self.ingest_hold: str | None = None
+        #: (rows, dispatch seconds) of a window the served ticker
+        #: dispatched ahead of its barrier (``Engine.tick``), else None:
+        #: the next barrier seals it, whoever crosses it
+        self.window_ahead: tuple[int, float] | None = None
+        #: whether the last barrier sealed a snapshot the shadow holds
+        self.sealed_snapshot = False
         #: view label -> (used slots at the last pass, most they grew
         #: by between two passes)
         self._view_levels: dict[str, tuple[int, int]] = {}
@@ -172,6 +178,11 @@ class BarrierLoop:
     def _run_maintain(self) -> None:
         """Dispatch the maintain program over ``self.states`` (a
         runtime without one keeps this)."""
+
+    def window_one_dispatch(self, n: int) -> bool:
+        """Whether ``run_chunks(n)`` is one asynchronous device
+        dispatch (what a window sent ahead of its barrier must be)."""
+        return False
 
     def _init_states(self):
         """A fresh state tree, placed where the programs expect it."""
@@ -262,6 +273,7 @@ class BarrierLoop:
             self.stall_seconds += self.write_stall_hook()
 
         epoch_val = barrier.epoch.prev.value
+        self.sealed_snapshot = False
         with GLOBAL_TRACE.span("inject_barrier.dispatch", job=self.name):
             outs = self._cross_barrier(epoch_val)
         if barrier.is_checkpoint:
@@ -273,6 +285,7 @@ class BarrierLoop:
             if self._ckpts_since_snapshot >= self.snapshot_interval:
                 self._ckpts_since_snapshot = 0
                 self._commit_checkpoint(epoch_val)
+                self.sealed_snapshot = True
         # cheap ack poll keeps committed_epoch (and deferred sink
         # delivery) advancing while uploads complete in the background
         self._process_upload_acks()
@@ -503,6 +516,8 @@ class BarrierLoop:
         checkpoints live under ``ckpt_key`` — a partition's lineage,
         not the job name."""
         self._counters = None
+        # a window still ahead of its barrier is rewound with the rest
+        self.window_ahead = None
         # the rewound view's levels are the next maintenance pass's to
         # read: a hold of the state that is gone must not outlive it
         self.ingest_hold = None
@@ -763,6 +778,9 @@ class StreamingJob(BarrierLoop):
         self.source.offset += self.source.cap * (n - 1)
         self.states = prog(self.states, k0)
         return self.source.cap * n
+
+    def window_one_dispatch(self, n: int) -> bool:
+        return self._fused is not None
 
     def _multi_prog(self, n: int):
         """The jitted n-chunk window program (generator + step under

@@ -488,6 +488,10 @@ def test_single_node_read_tree_counters_and_drop(tmp_path):
             c.query(stmt)
         for _ in range(2):
             node._tick_once()
+        # the served tick sent its next window ahead: settle it outside
+        # any trace, so the read's tree is the read's alone (a read that
+        # finds one settles it under ``read.execute``: test_window_ahead)
+        node.engine.settle("tick")
         GLOBAL_TRACE.clear()
         _, rows = c.query("SELECT window_start, max_price, bids FROM q7")
         (spans,) = _trees("read-").values()
