@@ -53,7 +53,6 @@ from risingwave_tpu.state.hash_table import gather_key
 from risingwave_tpu.stream.hash_agg import HashAggExecutor
 from risingwave_tpu.stream.materialize import (
     MaterializeExecutor,
-    MvState,
     _scatter_col,
 )
 
@@ -174,9 +173,7 @@ def clear_vnodes(executors, states, vnodes, n_vnodes: int):
             )
             stale = st.table.occupied & member[vn]
             cleared += int(jnp.sum(stale))
-            new_states[i] = MvState(
-                st.table.clear_where(stale), st.values, st.overflow
-            )
+            new_states[i] = st._replace(table=st.table.clear_where(stale))
     return tuple(new_states), cleared
 
 
@@ -425,6 +422,6 @@ def transplant(executors, states, slices: dict[int, dict]):
                 _scatter_col(store, slots, _to_dev(col))
                 for store, col in zip(st.values, sl["values"])
             )
-            new_states[i] = MvState(table, values, st.overflow)
+            new_states[i] = st._replace(table=table, values=values)
         moved += n
     return tuple(new_states), moved

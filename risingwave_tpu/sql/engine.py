@@ -3301,7 +3301,6 @@ class Engine:
             vnodes_of_ints,
         )
         from risingwave_tpu.state.hash_table import HashTable
-        from risingwave_tpu.stream.materialize import MvState
 
         member = vnode_member_mask(vn_set, n_vn)
         key0 = st.table.key_cols[0]
@@ -3311,7 +3310,7 @@ class Engine:
         table = HashTable(st.table.key_cols, occ,
                           jnp.asarray(st.table.tombstone),
                           st.table.size)
-        return MvState(table, st.values, st.overflow)
+        return st._replace(table=table)
 
     def collect_join_metrics(self) -> None:
         """Export join-path observability into the Prometheus registry.

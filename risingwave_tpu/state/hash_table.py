@@ -59,9 +59,9 @@ RECLAIM_BATCH = 4096
 #: fixed cost (a join retires ten times an aggregate's slots a barrier:
 #: PERF.md §6, PR 35)
 TAG_RECLAIM_TILE = 4096
-#: widths a ``TagTable`` probe loop narrows to as a chunk's rows resolve
-#: (``_probe_loop``): the open rows go on in a tile of the next width
-#: once they fit it
+#: widths a probe loop narrows to as a chunk's rows resolve
+#: (``_probe_loop``, for ``HashTable`` and ``TagTable``): the open rows go
+#: on in a tile of the next width once they fit it
 STRAGGLER_TILES = (1024, 128)
 
 #: trace-time probe accounting: how many table-probe loops a compiled
@@ -176,16 +176,23 @@ def _probe_loop(step, shared, rows: tuple, inputs: tuple, max_iters: int):
     a chunk is ten times its mean (at load 0.41, 8,192 rows: 2.6 slots a
     row, 30-45 rounds): so once no more rows are open than the next of
     ``STRAGGLER_TILES`` holds they are gathered into a tile of that
-    width, go on there, and are put back.  The first round is unrolled
-    into the enclosing program: at sane load factors most rows resolve
-    at once, and the common case should not pay a loop iteration's fixed
-    overhead (PERF.md §6, PR 27).  Returns ``(shared, rows, rounds)``."""
+    width, go on there, and are put back.  A resolved row does nothing
+    in a round, so the rounds probe the same slots in the same order at
+    any width.  The first round is unrolled into the enclosing program:
+    at sane load factors most rows resolve at once, and the common case
+    should not pay a loop iteration's fixed overhead (PERF.md §6).
+    Returns ``(shared, rows, rounds, wide)``: ``rounds`` all of them,
+    ``wide`` those at the chunk's full width (the first included);
+    ``max_iters`` bounds ``rounds``."""
     shared, rows = step(shared, rows, inputs)
     return _narrowing_rounds(step, shared, rows, inputs, jnp.int32(1),
                              max_iters, STRAGGLER_TILES)
 
 
 def _narrowing_rounds(step, shared, rows, inputs, iters, max_iters, tiles):
+    """``_probe_loop``'s rounds from ``iters`` on at the width of
+    ``rows``, then in the narrower ``tiles``.  Returns ``(shared, rows,
+    iters, wide)``, ``wide`` the count when this width's rounds ended."""
     width = rows[0].shape[0]
     tiles = [t for t in tiles if t < width]
     more_than = tiles[0] if tiles else 0
@@ -202,19 +209,20 @@ def _narrowing_rounds(step, shared, rows, inputs, iters, max_iters, tiles):
 
     shared, rows, iters = jax.lax.while_loop(
         cond, body, (shared, rows, iters))
+    wide = iters
     if not tiles:
-        return shared, rows, iters
+        return shared, rows, iters, wide
     at = mask_indices(~rows[0], tiles[0], width)
     picked = at < width
     safe = jnp.minimum(at, width - 1)
     few = tuple(x[safe] for x in rows)
     few = (few[0] | ~picked,) + few[1:]
-    shared, few, iters = _narrowing_rounds(
-        step, shared, few, tuple(x[safe] for x in inputs), iters,
+    shared, few, iters, _ = _narrowing_rounds(
+        step, shared, few, jax.tree.map(lambda x: x[safe], inputs), iters,
         max_iters, tiles[1:])
     to = jnp.where(picked, at, width)
     rows = tuple(x.at[to].set(y, mode="drop") for x, y in zip(rows, few))
-    return shared, rows, iters
+    return shared, rows, iters, wide
 
 
 def _reclaim(size: int, live, tomb, home, emptied, reinsert, occupied_of,
@@ -394,7 +402,7 @@ class HashTable:
         paths (join probes) must accumulate the count into an error
         counter so maintenance fails loudly instead of silently
         dropping matches."""
-        table, slots, found, overflow = self._probe(
+        _, slots, found, overflow, _ = self._probe(
             key_cols, valid, insert=False, hashes=hashes
         )
         return slots, found, jnp.sum((overflow & valid).astype(jnp.int64))
@@ -413,38 +421,50 @@ class HashTable:
         - ``inserted bool [cap]`` — row claimed a fresh slot;
         - ``overflow bool [cap]`` — table was full for this row.
         """
+        return self._probe(key_cols, valid, insert=True, hashes=hashes)[:4]
+
+    def lookup_or_insert_rounds(self, key_cols: Sequence,
+                                valid: jnp.ndarray,
+                                hashes: jnp.ndarray | None = None):
+        """``lookup_or_insert`` and its probe rounds: ``(table', slots,
+        inserted, overflow, (wide, tile))``, int32 counts of the rounds
+        run at the chunk's full width and in straggler tiles (an
+        executor's device tallies, ``fragment.PROBE_ROUND_ATTRS``)."""
         return self._probe(key_cols, valid, insert=True, hashes=hashes)
 
     # ------------------------------------------------------------------
     def _probe(self, key_cols: Sequence, valid: jnp.ndarray, insert: bool,
                hashes: jnp.ndarray | None = None):
+        """Every row of the chunk probes its chain in lockstep rounds
+        (``_probe_loop``, which narrows to the stragglers).  Returns
+        ``(table', slots, inserted | found, overflow, rounds)``, with
+        ``rounds`` = ``(wide, tile)`` int32: rounds at the chunk's full
+        width, the first included, and rounds in a straggler tile."""
         PROBE_STATS["lookup_or_insert" if insert else "lookup"] += 1
         size = self.size
         cap = valid.shape[0]
         if hashes is None:
             hashes = hash64_columns(key_cols)
         h = (hashes % np.uint64(size)).astype(jnp.int32)
-        row_idx = jnp.arange(cap, dtype=jnp.int32)
         sentinel = jnp.int32(size)
+        tombstone = self.tombstone
 
         # probe-length bound: at sane load factors chains are a handful
         # of slots; a pathological (near-full) table must degrade to
         # overflow counters, not O(size) loop iterations
         max_iters = min(size + 2, 1024)
 
-        def cond(carry):
-            _, _, _, done, _, _, iters = carry
-            return jnp.any(~done) & (iters < max_iters)
-
-        def body(carry):
-            occupied, key_store, slots, done, inserted, off, iters = carry
+        def step(shared, rows, inputs):
+            occupied, key_store = shared
+            done, slots, inserted, off = rows
+            h, keys = inputs
+            width = done.shape[0]
             cand = (h + off) % size
             occ = occupied[cand]
-            tomb = self.tombstone[cand] & ~occ
-            stored = tuple(_gather_key(c, cand) for c in key_store)
+            tomb = tombstone[cand] & ~occ
             match = occ
-            for s, k in zip(stored, key_cols):
-                match = match & _keys_equal(s, k)
+            for c, k in zip(key_store, keys):
+                match = match & _keys_equal(_gather_key(c, cand), k)
             hit = ~done & match
             slots = jnp.where(hit, cand, slots)
             done = done | hit
@@ -452,62 +472,54 @@ class HashTable:
                 # only a *true-empty* slot (no tombstone) is claimable:
                 # claiming a tombstone could shadow the same key further
                 # along a probe chain.  Intra-chunk claim races resolve
-                # by scatter-min of the row index into a chunk-sized
-                # scratch (hashed by candidate slot): exact for same-slot
-                # contenders; cross-slot scratch collisions only delay a
-                # row to the next iteration.  O(cap), never touching a
-                # table-sized array.
+                # by scatter-min of the row index into a scratch of four
+                # times the round's width (hashed by candidate slot):
+                # exact for same-slot contenders; cross-slot scratch
+                # collisions only delay a row to the next round.  Never
+                # a table-sized array.
                 want = ~done & ~occ & ~tomb
-                m = 4 * cap
+                row_idx = jnp.arange(width, dtype=jnp.int32)
+                m = 4 * width
                 scratch_idx = cand % m
-                claim = jnp.full((m,), cap, jnp.int32).at[
+                claim = jnp.full((m,), width, jnp.int32).at[
                     jnp.where(want, scratch_idx, m)
-                ].min(jnp.where(want, row_idx, cap), mode="drop")
+                ].min(jnp.where(want, row_idx, width), mode="drop")
                 won = want & (claim[scratch_idx] == row_idx)
                 pos = jnp.where(won, cand, sentinel)
                 occupied = occupied.at[pos].set(True, mode="drop")
                 key_store = tuple(
                     _scatter_key(c, pos, k, size)
-                    for c, k in zip(key_store, key_cols)
+                    for c, k in zip(key_store, keys)
                 )
                 slots = jnp.where(won, cand, slots)
                 inserted = inserted | won
                 done = done | won
-                # losers of the claim re-check cand next iteration (it is
-                # now occupied — match if same key, else advance);
-                # tombstones are skipped, keeping probe chains intact
-                advance = (~done & occ & ~match) | (~done & tomb)
+                # losers of the claim re-check cand next round (it is
+                # now occupied — match if same key, else advance)
             else:
                 # probe-only: true-empty slot ⇒ key absent ⇒ miss
-                miss = ~done & ~occ & ~tomb
-                done = done | miss
-                advance = (~done & occ & ~match) | (~done & tomb)
+                done = done | (~done & ~occ & ~tomb)
+            # tombstones are skipped, keeping probe chains intact
+            advance = ~done & ((occ & ~match) | tomb)
             off = jnp.where(advance, off + 1, off)
-            return occupied, key_store, slots, done, inserted, off, iters + 1
+            return (occupied, key_store), (done, slots, inserted, off)
 
-        init = (
-            self.occupied,
-            self.key_cols,
-            jnp.full((cap,), sentinel, jnp.int32),
+        rows = (
             ~valid,
+            jnp.full((cap,), sentinel, jnp.int32),
             jnp.zeros((cap,), jnp.bool_),
             jnp.zeros((cap,), jnp.int32),
-            jnp.int32(0),
         )
-        # the first probe round is unrolled into the enclosing program:
-        # at sane load factors most rows resolve immediately, and the
-        # common case should not pay a loop iteration's fixed overhead
-        # (a few µs on the v5e: PERF.md §6, PR 27)
-        carry = body(init)
-        occupied, key_store, slots, done, inserted, _, _ = jax.lax.while_loop(
-            cond, body, carry
-        )
+        (occupied, key_store), (done, slots, inserted, _), n, wide = \
+            _probe_loop(step, (self.occupied, self.key_cols), rows,
+                        (h, tuple(key_cols)), max_iters)
         overflow = ~done
-        found = valid & done & ~inserted & (slots < size)
+        rounds = (wide, n - wide)
         if insert:
             table = HashTable(key_store, occupied, self.tombstone, size)
-            return table, slots, inserted, overflow
-        return self, slots, found, overflow
+            return table, slots, inserted, overflow, rounds
+        found = valid & done & (slots < size)
+        return self, slots, found, overflow, rounds
 
     # ------------------------------------------------------------------
     def clear_where(self, pred: jnp.ndarray) -> "HashTable":
@@ -717,7 +729,7 @@ class TagTable:
             jnp.zeros((cap,), jnp.int32),
         )
         home = tag_home(tag_vals, size)
-        tags, (done, slots, inserted, _), _ = _probe_loop(
+        tags, (done, slots, inserted, _), _, _ = _probe_loop(
             step, self.tags, rows, (tag_vals, home), max_iters)
         overflow = ~done
         found = valid & done & ~inserted & (slots < size)
@@ -866,7 +878,7 @@ class TagTable:
             jnp.full((cap,), sentinel, jnp.int32),
             zeros,
         )
-        (tags, steps), rows, iters = _probe_loop(
+        (tags, steps), rows, iters, _ = _probe_loop(
             step, (self.tags, jnp.int32(0)), rows, (chunk_rank, base),
             max_iters)
         done, slots, inserted, existed, _, target, _, head_slot, _ = rows

@@ -39,6 +39,7 @@ from risingwave_tpu.stream.fragment import (
     Fragment,
     JOIN_GAUGE_ATTRS,
     JOIN_TALLY_ATTRS,
+    PROBE_ROUND_ATTRS,
     WM_NONE,
     WM_SAFE_FLOOR,
     collect_counters,
@@ -1056,7 +1057,7 @@ class DagJob(BarrierLoop):
             for side_name in ("left", "right"):
                 s = getattr(jstate, side_name)
                 for attr in COUNTER_ATTRS + JOIN_TALLY_ATTRS \
-                        + JOIN_GAUGE_ATTRS:
+                        + JOIN_GAUGE_ATTRS + tuple(PROBE_ROUND_ATTRS):
                     if hasattr(s, attr):
                         if attr not in COUNTER_ATTRS:
                             sides[len(labels)] = side_name

@@ -51,6 +51,13 @@ GAUGE_ATTRS = ("live_groups", "tombstones", "table_slots",
 JOIN_TALLY_ATTRS = ("insert_rows", "delete_rows", "probe_steps",
                     "emit_rows", "cleaned_rows", "reclaim_slots")
 JOIN_GAUGE_ATTRS = ("live_rows", "tombstones", "table_slots")
+#: tallies of a ``HashTable`` probe that runs at a chunk's width on the
+#: barrier path (a view's upsert, a keyed join side): its rounds at that
+#: width and in straggler tiles (``hash_table._probe_loop``), summed over
+#: a job as ``hash_table_probe_rounds_total{job,width}``, ``width`` the
+#: value here; skipped like the tallies above
+PROBE_ROUND_ATTRS = {"probe_wide_rounds": "chunk",
+                     "probe_tile_rounds": "tile"}
 #: a pk-keyed view's levels, from ``MaterializeExecutor.levels`` (no
 #: field of its state: a checkpoint keeps its leaves): gauges
 #: ``materialize_<attr>{job}``, and what ``BarrierLoop`` holds a job's
@@ -78,7 +85,8 @@ def collect_counters(executors, states):
     for i, ex in enumerate(executors):
         st = states[i]
         with executor_scope(i, ex, "counters"):
-            for attr in COUNTER_ATTRS + TALLY_ATTRS + GAUGE_ATTRS:
+            for attr in COUNTER_ATTRS + TALLY_ATTRS + GAUGE_ATTRS \
+                    + tuple(PROBE_ROUND_ATTRS):
                 if hasattr(st, attr):
                     labels.append(f"{ex}.{attr}")
                     vals.append(getattr(st, attr).astype(jnp.int64))

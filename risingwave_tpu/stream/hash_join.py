@@ -327,6 +327,10 @@ class KeyedSideState(NamedTuple):
     live_rows: jnp.ndarray
     tombstones: jnp.ndarray
     table_slots: jnp.ndarray
+    #: the update's probe rounds at the chunk's width and in straggler
+    #: tiles (``fragment.PROBE_ROUND_ATTRS``)
+    probe_wide_rounds: jnp.ndarray
+    probe_tile_rounds: jnp.ndarray
 
 
 class PoolSideState(NamedTuple):
@@ -622,6 +626,7 @@ class HashJoinExecutor:
             emit_rows=_zero64(), cleaned_rows=_zero64(),
             reclaim_slots=_zero64(), live_rows=_zero64(),
             tombstones=_zero64(), table_slots=jnp.int64(size),
+            probe_wide_rounds=_zero64(), probe_tile_rounds=_zero64(),
         )
 
     def _pool_side_state(self, schema: Schema, keys: Sequence[Expr],
@@ -807,7 +812,8 @@ class HashJoinExecutor:
             touch = touch & ~null_keys  # a NULL join key matches nothing
         pk = [conform_col(chunk.columns[i], schema[i].nullable, cap)
               for i in row_key]
-        table, slots, claimed, over = side.table.lookup_or_insert(pk, touch)
+        table, slots, claimed, over, (wide, tile) = \
+            side.table.lookup_or_insert_rounds(pk, touch)
         ok = touch & ~over
         with jax.named_scope(f"{self.scope}/delete"):
             # the chunk's rows by slot, in arrival order within a slot
@@ -838,6 +844,10 @@ class HashJoinExecutor:
             + jnp.sum((ok & (signs > 0)).astype(jnp.int64)),
             delete_rows=side.delete_rows
             + jnp.sum((ok & (signs < 0)).astype(jnp.int64)) - n_missing,
+            probe_wide_rounds=side.probe_wide_rounds
+            + wide.astype(jnp.int64),
+            probe_tile_rounds=side.probe_tile_rounds
+            + tile.astype(jnp.int64),
         )
 
     def _update_side_pool(self, side: PoolSideState, chunk: Chunk,

@@ -80,6 +80,10 @@ class MvState(NamedTuple):
     table: HashTable
     values: tuple  # dense [size] column stores (all output columns)
     overflow: jnp.ndarray
+    #: the upsert's probe rounds at the chunk's width and in straggler
+    #: tiles (int64 tallies, ``fragment.PROBE_ROUND_ATTRS``)
+    probe_wide_rounds: jnp.ndarray
+    probe_tile_rounds: jnp.ndarray
 
 
 class MaterializeExecutor(Executor):
@@ -106,7 +110,9 @@ class MaterializeExecutor(Executor):
         values = tuple(
             _empty_value_col(f, self.table_size) for f in self.in_schema
         )
-        return MvState(table, values, jnp.zeros((), jnp.int64))
+        # one buffer a leaf: the step donates its state
+        zeros = [jnp.zeros((), jnp.int64) for _ in range(3)]
+        return MvState(table, values, *zeros)
 
     def apply(self, state: MvState, chunk: Chunk):
         pk_cols = [chunk.column(i) for i in self.pk_indices]
@@ -115,9 +121,8 @@ class MaterializeExecutor(Executor):
         del_rows = chunk.valid & is_del
         ins_rows = chunk.valid & is_ins
 
-        table, slots, _, overflow = state.table.lookup_or_insert(
-            pk_cols, chunk.valid
-        )
+        table, slots, _, overflow, (wide, tile) = \
+            state.table.lookup_or_insert_rounds(pk_cols, chunk.valid)
         n_over = jnp.sum((overflow & chunk.valid).astype(jnp.int64))
         # per-slot conflict resolution honors INTRA-CHUNK ROW ORDER (the
         # reference applies conflicts row by row, materialize.rs): the
@@ -153,7 +158,11 @@ class MaterializeExecutor(Executor):
         # pass the changelog through: downstream (cascaded) MVs consume
         # this MV's change stream, exactly as the reference's dispatcher
         # forwards the materialize fragment's output to dependent jobs
-        return MvState(table, values, state.overflow + n_over), chunk
+        return MvState(
+            table, values, state.overflow + n_over,
+            state.probe_wide_rounds + wide.astype(jnp.int64),
+            state.probe_tile_rounds + tile.astype(jnp.int64),
+        ), chunk
 
     # -- maintenance ----------------------------------------------------
     def levels(self, state: MvState):
@@ -173,7 +182,7 @@ class MaterializeExecutor(Executor):
             from risingwave_tpu.state.hash_table import permute_dense
 
             values = tuple(permute_dense(v, moved) for v in state.values)
-            return MvState(fresh, values, state.overflow)
+            return state._replace(table=fresh, values=values)
 
         return jax.lax.cond(
             state.table.tombstone_count() > self.table_size // 4,

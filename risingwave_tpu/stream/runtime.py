@@ -39,6 +39,7 @@ from risingwave_tpu.stream.fragment import (
     Fragment,
     GAUGE_ATTRS,
     JOIN_GAUGE_ATTRS,
+    PROBE_ROUND_ATTRS,
     TALLY_ATTRS,
     VIEW_GAUGE_ATTRS,
 )
@@ -578,8 +579,10 @@ def check_counter_values(name: str, labels: list[str],
     out as ``hash_agg_<kind>_total{job}``, the levels of
     ``fragment.GAUGE_ATTRS`` as gauges ``hash_agg_<kind>{job}``, a join
     side's (``sides``: index in the vector -> side, from the runtime
-    that collected them) as ``hash_join_<kind>[_total]{job,side}``, and
-    all are otherwise skipped, as ``.pending`` is.
+    that collected them) as ``hash_join_<kind>[_total]{job,side}``, the
+    probe rounds of ``fragment.PROBE_ROUND_ATTRS`` as
+    ``hash_table_probe_rounds_total{job,width}``, and all are otherwise
+    skipped, as ``.pending`` is.
     """
     kinds = [label.rsplit(".", 1)[-1] for label in labels]
     join_side = [(sides or {}).get(i) for i in range(len(labels))]
@@ -588,8 +591,12 @@ def check_counter_values(name: str, labels: list[str],
         tallies: dict[str, int] = {}
         joins: dict[tuple, int] = {}
         views: dict[str, int] = {}
+        rounds: dict[str, int] = {}
         for kind, side, v in zip(kinds, join_side, values):
-            if side is not None:
+            if kind in PROBE_ROUND_ATTRS:
+                width = PROBE_ROUND_ATTRS[kind]
+                rounds[width] = rounds.get(width, 0) + int(v)
+            elif side is not None:
                 joins[kind, side] = joins.get((kind, side), 0) + int(v)
             elif kind in TALLY_ATTRS + GAUGE_ATTRS:
                 tallies[kind] = tallies.get(kind, 0) + int(v)
@@ -609,6 +616,9 @@ def check_counter_values(name: str, labels: list[str],
                               job=name, kind=kind)
         for kind, v in views.items():
             metrics.set_gauge(f"materialize_{kind}", v, job=name)
+        for width, v in rounds.items():
+            metrics.set_counter("hash_table_probe_rounds_total", v,
+                                job=name, width=width)
         for kind, v in tallies.items():
             if kind in GAUGE_ATTRS:
                 metrics.set_gauge(f"hash_agg_{kind}", v, job=name)
@@ -616,7 +626,7 @@ def check_counter_values(name: str, labels: list[str],
                 metrics.set_counter(f"hash_agg_{kind}_total", v, job=name)
     residual = []
     for label, kind, side, v in zip(labels, kinds, join_side, values):
-        if side is not None \
+        if side is not None or kind in PROBE_ROUND_ATTRS \
                 or kind in TALLY_ATTRS + GAUGE_ATTRS + VIEW_GAUGE_ATTRS:
             continue
         if kind == "pending":

@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from risingwave_tpu.state.hash_table import HashTable
 
@@ -104,3 +105,107 @@ def test_rehash_reclaims_tombstones():
     assert int(fresh.count()) == 5
     slots2, found = fresh.lookup([_i64(keys)], _valid(10))
     assert list(np.asarray(found)) == [False] * 5 + [True] * 5
+
+
+def _mix(keys):
+    """A fixed 64-bit hash of int64 keys (splitmix64's finaliser): the
+    test hands the table its hashes, so the reference needs no other."""
+    with np.errstate(over="ignore"):
+        x = keys.astype(np.uint64) + np.uint64(0x9E3779B97F4A7C15)
+        x = (x ^ (x >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
+        x = (x ^ (x >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
+        return x ^ (x >> np.uint64(31))
+
+
+def _plain_probe(occupied, tombstone, stored, keys, home, valid, insert,
+                 max_iters):
+    """Linear probing in lockstep rounds, one row at a time: each open
+    row looks at its next slot; a match resolves it; among the rows
+    that want one true-empty slot the lowest row claims it, and the
+    others look at it again next round; an occupied slot of another key
+    or a tombstone moves a row on.  Returns ``(occupied, stored, slots,
+    inserted | found, overflow, rounds)``."""
+    size = occupied.size
+    occupied, stored = occupied.copy(), stored.copy()
+    n = keys.size
+    done = ~valid
+    slots = np.full(n, size, np.int64)
+    won = np.zeros(n, np.bool_)
+    off = np.zeros(n, np.int64)
+    rounds = 0
+    while not done.all() and rounds < max_iters:
+        rounds += 1
+        cand = (home + off) % size
+        occ = occupied[cand]
+        tomb = tombstone[cand] & ~occ
+        match = occ & (stored[cand] == keys)
+        for r in np.flatnonzero(~done):
+            if match[r]:
+                slots[r], done[r] = cand[r], True
+            elif not occ[r] and not tomb[r]:
+                if not insert:
+                    done[r] = True
+                elif not occupied[cand[r]]:
+                    occupied[cand[r]], stored[cand[r]] = True, keys[r]
+                    slots[r], won[r], done[r] = cand[r], True, True
+            else:
+                off[r] += 1
+    overflow = ~done
+    if insert:
+        return occupied, stored, slots, won, overflow, rounds
+    return occupied, stored, slots, valid & done & (slots < size), \
+        overflow, rounds
+
+
+@pytest.mark.parametrize("insert", [True, False], ids=["insert", "lookup"])
+def test_narrowed_probe_equals_plain_linear_probe(insert):
+    """A chunk of 8,192 rows, twice the widest straggler tile, into a
+    table at load 0.70 with tombstones: present keys, deleted keys,
+    absent keys repeated within the chunk, rows not valid.  The table
+    has 512 slots, no more than the narrowest tile's claim scratch (4 x
+    128), so the device's claim race is exact at every width, as the
+    reference's is."""
+    size, cap = 512, 8192
+    rng = np.random.default_rng(40)
+    pool = rng.choice(1 << 40, 390, replace=False).astype(np.int64)
+    present, gone, absent = pool[:360], pool[:40], pool[360:]
+    t = HashTable.create([jnp.zeros((1,), jnp.int64)], size)
+    t, slots, _, over = t.lookup_or_insert(
+        [_i64(present)], _valid(360), hashes=jnp.asarray(_mix(present)))
+    assert not np.asarray(over).any()
+    t = t.clear_slots(slots, jnp.asarray(np.arange(360) < 40))
+    occupied = np.asarray(t.occupied)
+    tombstone = np.asarray(t.tombstone)
+    stored = np.asarray(t.key_cols[0])
+    assert (occupied | tombstone).sum() / size == pytest.approx(0.70, 0.01)
+
+    kinds = rng.choice(3, cap, p=[0.6, 0.1, 0.3])
+    keys = np.where(kinds == 0, rng.choice(present[40:], cap),
+                    np.where(kinds == 1, rng.choice(gone, cap),
+                             rng.choice(absent, cap)))
+    valid = rng.random(cap) < 0.9
+    hashes = _mix(keys)
+    want = _plain_probe(occupied, tombstone, stored, keys,
+                        (hashes % np.uint64(size)).astype(np.int64), valid,
+                        insert, max_iters=size + 2)
+    w_occupied, w_stored, w_slots, w_flag, w_over, w_rounds = want
+
+    args = ([_i64(keys)], jnp.asarray(valid), jnp.asarray(hashes))
+    if insert:
+        t2, slots, flag, over = t.lookup_or_insert(*args)
+        assert (np.asarray(t2.occupied) == w_occupied).all()
+        got = np.asarray(t2.key_cols[0])
+        assert (got[w_occupied] == w_stored[w_occupied]).all()
+        n_over = int(np.asarray(over).sum())
+    else:
+        slots, flag, n_over = t.lookup_counted(*args)
+    assert (np.asarray(slots) == w_slots).all()
+    assert (np.asarray(flag) == w_flag).all()
+    assert int(n_over) == int(w_over.sum()) == 0
+
+    # the tail the tiles exist for was there, and the chunk's width ran
+    # only the first few rounds of it
+    _, _, _, _, (wide, tile) = t._probe(*args[:2], insert=insert,
+                                        hashes=args[2])
+    assert int(wide) + int(tile) == w_rounds > 8
+    assert int(wide) * 3 <= w_rounds

@@ -79,7 +79,7 @@ def placed(mv, slots) -> MvState:
     table = HashTable(
         state.table.key_cols, state.table.occupied.at[at].set(True),
         state.table.tombstone, state.table.size)
-    return MvState(table, values, state.overflow)
+    return state._replace(table=table, values=values)
 
 
 def applied(mv, steps) -> MvState:
@@ -311,3 +311,72 @@ def test_mesh_view_reads_the_same_rows():
         == sorted(want)
     moved = _moved(eng, "v")
     assert moved["gathered"] + moved["whole"] > 0
+
+
+def _versioned(rows, cap) -> Chunk:
+    """A chunk of ``(key, op, version)`` rows: a row's values are made
+    from its key and version, so an update is told from what it
+    replaced."""
+    k = np.asarray([r[0] for r in rows], np.int64)
+    v = np.asarray([r[2] for r in rows], np.int64)
+    n = np.asarray([None if x % 3 == 0 else int(x * 3 + y)
+                    for x, y in zip(k, v)], object)
+    s = np.asarray([f"r{x}v{y}" for x, y in zip(k, v)], object)
+    return Chunk.from_numpy(
+        SCHEMA, [k, n, s, (k + v) / 4],
+        ops=np.asarray([r[1] for r in rows], np.int8), capacity=cap)
+
+
+def _row(k, v) -> tuple:
+    return (k, None if k % 3 == 0 else k * 3 + v, f"r{k}v{v}", (k + v) / 4)
+
+
+def test_upsert_at_high_fill_equals_a_dict():
+    """The view's upsert into a table at fill 0.61 (tombstones
+    included): one 8,192-row chunk of interleaved U-/U+ pairs, inserts,
+    deletes, and keys changed more than once, whose last change in row
+    order decides.  Its probe runs long enough to narrow to stragglers."""
+    from risingwave_tpu.common.chunk import OP_UPDATE_DELETE as U_
+    from risingwave_tpu.common.chunk import OP_UPDATE_INSERT as U
+
+    cap = 8192
+    mv = MaterializeExecutor(SCHEMA, pk_indices=[0], table_size=1 << 13)
+    step = jax.jit(lambda st, ch: mv.apply(st, ch)[0])
+    rng = np.random.default_rng(40)
+    keys = rng.permutation(20_000)
+    want: dict[int, tuple] = {}
+
+    def apply(state, rows):
+        for k, op, v in rows:
+            if op in (OP_INSERT, U):
+                want[k] = _row(k, v)
+            else:
+                want.pop(k, None)
+        return step(state, _versioned(rows, cap))
+
+    state = apply(mv.init_state(), [(k, OP_INSERT, 0) for k in keys[:5000]])
+    state = apply(state, [(k, OP_DELETE, 0) for k in keys[:200]])
+    used = np.asarray(state.table.occupied | state.table.tombstone).mean()
+    assert used == pytest.approx(0.61, abs=0.01)
+
+    live, new = iter(keys[200:5000]), iter(keys[5000:])
+    groups = (
+        [[(k, U_, 0), (k, U, 1)] for k in (next(live) for _ in range(2096))]
+        + [[(next(new), OP_INSERT, 2)] for _ in range(1000)]
+        + [[(next(live), OP_DELETE, 0)] for _ in range(1000)]
+        + [[(k, OP_DELETE, 0), (k, OP_INSERT, 3)]
+           for k in (next(live) for _ in range(300))]
+        + [[(k, OP_INSERT, 3), (k, OP_DELETE, 3)]
+           for k in (next(new) for _ in range(300))]
+        + [[(k, U_, 0), (k, U, 4), (k, U_, 4), (k, U, 5)]
+           for k in (next(live) for _ in range(200))])
+    rows = [r for i in rng.permutation(len(groups)) for r in groups[i]]
+    assert len(rows) == cap
+    before = int(state.probe_wide_rounds), int(state.probe_tile_rounds)
+    state = apply(state, rows)
+
+    assert int(state.overflow) == 0
+    assert sorted(mv.to_host(state)) == sorted(want.values())
+    wide = int(state.probe_wide_rounds) - before[0]
+    tile = int(state.probe_tile_rounds) - before[1]
+    assert 1 <= wide < tile
