@@ -7,7 +7,10 @@ the reference's default freshness envelope (checkpoint every barrier).
 - q1: stateless project over the bid stream
 - q5: sliding-window (hop) bid counts per auction  (windowed hash agg)
 - q7: tumbling-window max price                    (windowed hash agg)
-- q8: windowed person × auction join
+- q8: a q8-style windowed person × auction join (a 1 s tumble, no
+  GROUP BY, both sides append-only pools); upstream's Nexmark q8 text
+  is the benchmark's ``nexmark_q8`` configuration
+  (``benchmark/configs/nexmark_q8.json``)
 
 Measures the asked query (``RWT_BENCH_QUERY=q1|q5|q7|q8|all``, default
 q7) in THIS process — a chip belongs to one process — and prints one

@@ -99,9 +99,12 @@ def _last_base0_auction_id(event_number: jnp.ndarray) -> jnp.ndarray:
     return epoch * AUCTION_PROPORTION + offset
 
 
-def _next_base0_person_id(event_id: jnp.ndarray, stream: int) -> jnp.ndarray:
-    """A person among the last ACTIVE_PEOPLE (canonical nextBase0PersonId)."""
-    num_people = _last_base0_person_id(event_id) + 1
+def _next_base0_person_id(event_number: jnp.ndarray, event_id: jnp.ndarray,
+                          stream: int) -> jnp.ndarray:
+    """A person among the last ACTIVE_PEOPLE (canonical nextBase0PersonId):
+    the people so far follow ``event_number``, the draw among them is
+    keyed by ``event_id``."""
+    num_people = _last_base0_person_id(event_number) + 1
     active = jnp.minimum(num_people, ACTIVE_PEOPLE)
     lo = num_people - active
     return lo + _rand_int(event_id, stream, ACTIVE_PEOPLE + 1).clip(max=active)
@@ -286,8 +289,11 @@ class NexmarkGenerator:
         hot_b = _rand_int(eid, 3, HOT_BIDDER_RATIO) > 0
         hot_bidder = (_last_base0_person_id(n) // HOT_BIDDER_RATIO) * \
             HOT_BIDDER_RATIO + 1
-        bidder = jnp.where(hot_b, hot_bidder, _next_base0_person_id(eid, 4)) + \
-            FIRST_PERSON_ID
+        # the cold bidder's people still follow the keyed id: the
+        # benchmark's accepted bid reference mirrors it
+        bidder = jnp.where(
+            hot_b, hot_bidder, _next_base0_person_id(eid, eid, 4)
+        ) + FIRST_PERSON_ID
         price = _next_price(eid, 5)
         channel = _gather_str(self._channels, _rand_int(eid, 6, len(_CHANNELS)))
         url = _gather_str(self._urls, _rand_int(eid, 7, 32))
@@ -313,8 +319,11 @@ class NexmarkGenerator:
         hot = _rand_int(eid, 12, HOT_SELLER_RATIO) > 0
         hot_seller = (_last_base0_person_id(n) // HOT_SELLER_RATIO) * \
             HOT_SELLER_RATIO
-        seller = jnp.where(hot, hot_seller, _next_base0_person_id(eid, 13)) + \
-            FIRST_PERSON_ID
+        # a cold seller is one of the people registered by event ``n``,
+        # whatever the seed
+        seller = jnp.where(
+            hot, hot_seller, _next_base0_person_id(n, eid, 13)
+        ) + FIRST_PERSON_ID
         category = FIRST_CATEGORY_ID + _rand_int(eid, 14, NUM_CATEGORIES)
         ts = self._timestamp(n)
         # canonical: expires = ts + rand over ~ next in-flight auction horizon

@@ -2356,6 +2356,8 @@ class Engine:
             job.inject_barrier()
         t3 = time.perf_counter()
         self.metrics.inc("stream_rows_total", rows, job=name)
+        for table, n in job.take_source_rows().items():
+            self.metrics.inc("source_rows_total", n, job=name, table=table)
         self._observe_barrier(
             name, dispatch + t3 - t1, dispatch=dispatch,
             source_drain=(t2 - t1) if fenced else None,

@@ -22,8 +22,10 @@ so the natural delta is *dirty blocks of those arrays*:
    only those blocks cross to the host, all leaves in one transfer;
    adjacent blocks coalesce into runs there and are written as a delta
    file.  A leaf with more dirty blocks than its capacity crosses
-   whole.  Device→host traffic and disk bytes scale with the epoch's
-   actual write set, not the state size.  A device slice with static
+   whole, and its runs at most ``RUN_GAP_BYTES`` apart are written as
+   one, clean blocks and all: a member costs more than those bytes.
+   Device→host traffic and disk bytes scale with the epoch's actual
+   write set, not the state size.  A device slice with static
    bounds is never used: it is a program per run, compiled at the
    barrier that first needs it.
 3. Every ``full_interval`` checkpoints (or when >50% of blocks are
@@ -95,20 +97,38 @@ from risingwave_tpu.storage.integrity import (
 _normalize_u64 = normalize_u64
 
 
+#: clean bytes between two dirty runs of a leaf that crossed whole at
+#: or below which the runs are written as one member.  A member costs
+#: ``encode_npz`` ~40 us on a v5e host, what ~40 KB of bytes cost the
+#: encode and the write together; a leaf whose dirty blocks are strewn
+#: over it (a string column's 512-byte blocks under 16 k new rows) was
+#: ~10 k members of a few blocks each
+RUN_GAP_BYTES = 1 << 15
+
+
 def _leaf_block_count(shape, dtype, block: int) -> int:
     return leaf_block_count(shape, block)
 
 
-def _dirty_runs(leaf_dirty: np.ndarray, rows: int, m: int, block: int):
+def _dirty_runs(leaf_dirty: np.ndarray, rows: int, m: int, block: int,
+                gap: int = 0):
     """Adjacent dirty blocks of one leaf coalesced into runs, as
-    ``(first element, end element, blocks)``.  A lane leaf (``rows`` >
-    1) is walked a shard row at a time, so no run crosses a shard
-    boundary; a run that reaches a row's ragged tail ends with it."""
+    ``(first element, end element, blocks)``; runs at most ``gap``
+    clean blocks apart are one run, the clean blocks' bytes with it.  A
+    lane leaf (``rows`` > 1) is walked a shard row at a time, so no run
+    crosses a shard boundary; a run that reaches a row's ragged tail
+    ends with it."""
     nb_row = leaf_dirty.shape[0] // rows
     for r in range(rows):
         row_dirty = leaf_dirty[r * nb_row:(r + 1) * nb_row]
         edges = np.flatnonzero(np.diff(np.r_[False, row_dirty, False]))
-        for b, e in zip(edges[::2], edges[1::2]):
+        starts, ends = edges[::2], edges[1::2]
+        if not starts.size:
+            continue
+        split = starts[1:] - ends[:-1] > gap
+        starts = starts[np.r_[True, split]]
+        ends = ends[np.r_[split, True]]
+        for b, e in zip(starts, ends):
             yield (r * m + int(b) * block,
                    r * m + min(int(e) * block, m), int(e - b))
 
@@ -384,8 +404,12 @@ class CheckpointStore:
                 continue
             n = int(np.prod(np.shape(x)))
             rows, m = ln if ln else (1, n)
-            runs[i] = list(_dirty_runs(leaf_dirty, rows, m, block))
-            if ids.size <= caps[i]:
+            gathered_leaf = ids.size <= caps[i]
+            runs[i] = list(_dirty_runs(
+                leaf_dirty, rows, m, block,
+                0 if gathered_leaf else RUN_GAP_BYTES // (
+                    block * np.dtype(x.dtype).itemsize)))
+            if gathered_leaf:
                 # lane leaves restart their blocks at every shard row;
                 # a window that would run past the leaf's end (its
                 # ragged tail) starts earlier instead

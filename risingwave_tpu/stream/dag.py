@@ -43,6 +43,7 @@ from risingwave_tpu.stream.fragment import (
     WM_NONE,
     WM_SAFE_FLOOR,
     collect_counters,
+    watermark_scope,
 )
 from risingwave_tpu.stream.runtime import (
     BarrierLoop,
@@ -677,7 +678,9 @@ class DagJob(BarrierLoop):
         rows = 0
         for name, k in self._pulls:
             for _ in range(k):
-                rows += self.run_chunk(name)
+                n = self.run_chunk(name)
+                self._pulled(name, n)
+                rows += n
         return rows
 
     def run_chunks(self, n: int) -> int:
@@ -726,6 +729,7 @@ class DagJob(BarrierLoop):
             # next_base() consumed one cap block; skip the other n*k-1
             k0s[nm] = jnp.int64(reader.next_base())
             reader.offset += reader.cap * (n * k - 1)
+            self._pulled(nm, reader.cap * n * k)
             rows += reader.cap * n * k
         self.states = prog(self.states, k0s)
         return rows
@@ -830,6 +834,7 @@ class DagJob(BarrierLoop):
                 for s in range(self.n_shards):
                     arr[i, s] = reader.next_base()
             base_cols.append(self._place(jnp.asarray(arr.T)))
+            self._pulled(nm, reader.cap * n * k * self.n_shards)
             rows += reader.cap * n * k * self.n_shards
         self.states = prog(self.states, *base_cols)
         return rows
@@ -949,7 +954,8 @@ class DagJob(BarrierLoop):
                         continue
                     lst = list(new_states[j])
                     for k, ex2 in enumerate(dn.fragment.executors):
-                        lst[k] = ex2.on_watermark(lst[k], wm)
+                        with watermark_scope(k, ex2):
+                            lst[k] = ex2.on_watermark(lst[k], wm)
                     new_states[j] = tuple(lst)
 
     def _upstream_wm(self, new_states: list, ref: Ref, src_col: int):

@@ -40,7 +40,8 @@ COUNTER_ATTRS = ("inconsistency", "overflow", "emit_overflow",
 #: ``hash_agg_<attr>_total`` and neither sums nor raises on them
 TALLY_ATTRS = ("apply_chunks", "rep_rows", "rep_tiles",
                "reclaim_passes", "reclaim_slots",
-               "minput_changes", "flush_rounds")
+               "minput_changes", "flush_rounds",
+               "cleaned_groups", "emit_rows")
 #: levels on the same vector, as the last maintenance pass found them:
 #: exported as gauges ``hash_agg_<attr>{job}``, skipped like the tallies
 GAUGE_ATTRS = ("live_groups", "tombstones", "table_slots",
@@ -72,6 +73,13 @@ def executor_scope(i: int, ex, phase: str):
     compiled code is the same with or without it."""
     cls = type(ex).__name__.removesuffix("Executor")
     return jax.named_scope(f"{cls}.{i}/{phase}")
+
+
+def watermark_scope(i: int, ex):
+    """The scope of an executor's ``on_watermark``: ``<Executor>.<i>/
+    watermark``, or what the executor calls the pass
+    (``HashAgg.<i>/clean``: the groups a window close retires)."""
+    return executor_scope(i, ex, getattr(ex, "watermark_phase", "watermark"))
 
 
 def collect_counters(executors, states):
@@ -246,7 +254,7 @@ class Fragment:
             )
             wm = Watermark(ex.ts_col, val)
             for j, ex2 in enumerate(self.executors):
-                with executor_scope(j, ex2, "watermark"):
+                with watermark_scope(j, ex2):
                     new_states[j] = ex2.on_watermark(new_states[j], wm)
         return tuple(new_states)
 

@@ -139,6 +139,10 @@ class AggState(NamedTuple):
     #: flush calls that had a group to emit (a barrier's drain rounds)
     minput_changes: jnp.ndarray = ()
     flush_rounds: jnp.ndarray = ()
+    #: groups the watermark retired (``clean_below``), and rows a flush
+    #: handed downstream (a U-/U+ pair counts two)
+    cleaned_groups: jnp.ndarray = ()
+    emit_rows: jnp.ndarray = ()
     #: rows lost to a full materialised-input / distinct dedup table
     #: (``fragment.COUNTER_ATTRS``: the barrier raises, naming the store)
     minput_overflow: jnp.ndarray = ()
@@ -244,8 +248,10 @@ class HashAggExecutor(Executor):
 
     emits_on_apply = False
     emits_on_flush = True
-    #: the scope of ``maybe_rehash`` in a device profile
+    #: the scopes of ``maybe_rehash`` and ``on_watermark`` (the
+    #: watermark's ``clean_below``) in a device profile
     maintain_phase = "reclaim"
+    watermark_phase = "clean"
 
     def __init__(
         self,
@@ -467,6 +473,8 @@ class HashAggExecutor(Executor):
             rep_tiles=jnp.zeros((), jnp.int64),
             minput_changes=jnp.zeros((), jnp.int64),
             flush_rounds=jnp.zeros((), jnp.int64),
+            cleaned_groups=jnp.zeros((), jnp.int64),
+            emit_rows=jnp.zeros((), jnp.int64),
             minput_overflow=jnp.zeros((), jnp.int64),
             distinct_overflow=jnp.zeros((), jnp.int64),
             reclaim_passes=jnp.zeros((), jnp.int64),
@@ -1063,6 +1071,7 @@ class HashAggExecutor(Executor):
             emitted=emitted,
             flush_rounds=state.flush_rounds
             + jnp.any(slot_live).astype(jnp.int64),
+            emit_rows=state.emit_rows + jnp.sum(valid, dtype=jnp.int64),
         ), out
 
     def _closed_mask(self, state: AggState) -> jnp.ndarray:
@@ -1114,6 +1123,7 @@ class HashAggExecutor(Executor):
             ),
             flush_rounds=state.flush_rounds
             + jnp.any(slot_live).astype(jnp.int64),
+            emit_rows=state.emit_rows + jnp.sum(live, dtype=jnp.int64),
         ), out
 
     def pending_dirty(self, state: AggState) -> jnp.ndarray:
@@ -1269,4 +1279,6 @@ class HashAggExecutor(Executor):
             distinct_counts=d_counts,
             minput_tables=m_tables,
             minput_counts=m_counts,
+            cleaned_groups=state.cleaned_groups
+            + jnp.sum(stale, dtype=jnp.int64),
         )

@@ -145,6 +145,10 @@ class BarrierLoop:
         #: dispatched ahead of its barrier (``Engine.tick``), else None:
         #: the next barrier seals it, whoever crosses it
         self.window_ahead: tuple[int, float] | None = None
+        #: source name -> rows pulled since ``take_source_rows`` last
+        #: ran (a runtime of several sources keeps it; ``Engine`` counts
+        #: it as ``source_rows_total{job,table}`` beside the job's rows)
+        self.source_rows: dict[str, int] = {}
         #: whether the last barrier sealed a snapshot the shadow holds
         self.sealed_snapshot = False
         #: view label -> (used slots at the last pass, most they grew
@@ -224,6 +228,14 @@ class BarrierLoop:
         return None
 
     # -- driving --------------------------------------------------------
+    def take_source_rows(self) -> dict[str, int]:
+        """Rows pulled from each source since the last call."""
+        taken, self.source_rows = self.source_rows, {}
+        return taken
+
+    def _pulled(self, source: str, rows: int) -> None:
+        self.source_rows[source] = self.source_rows.get(source, 0) + rows
+
     def chunk_round(self) -> int:
         """One scheduling round (one chunk for a single-source job)."""
         return self.run_chunk()

@@ -13,7 +13,10 @@ from risingwave_tpu.storage.sst import (
     merge_scan,
     write_sst,
 )
-from risingwave_tpu.storage.checkpoint_store import CheckpointStore
+from risingwave_tpu.storage.checkpoint_store import (
+    RUN_GAP_BYTES,
+    CheckpointStore,
+)
 
 
 def test_native_codec_builds():
@@ -225,25 +228,31 @@ def test_incremental_checkpoint_gc_keeps_chain_base(tmp_path):
     assert epoch == 6 and loaded["a"][6] == 6 and loaded["a"][3] == 3
 
 
-def _plain_delta(old_leaves, new_leaves, lanes, block):
+def _plain_delta(old_leaves, new_leaves, lanes, block, whole=()):
     """The delta payload cut by plain numpy from host copies of two
     states: every block whose elements differ, adjacent ones joined
-    into runs that never cross a shard row."""
+    into runs that never cross a shard row; in a leaf that crossed
+    whole (``whole``: its indices), runs no more than
+    ``RUN_GAP_BYTES`` of clean blocks apart joined too."""
     out = {}
     for i, (a, b, ln) in enumerate(zip(old_leaves, new_leaves, lanes)):
         fa, fb = np.asarray(a).reshape(-1), np.asarray(b).reshape(-1)
         rows, m = ln if ln else (1, fb.size)
+        gap = RUN_GAP_BYTES // (block * fb.itemsize) if i in whole else 0
         for r in range(rows):
             ra, rb = fa[r * m:(r + 1) * m], fb[r * m:(r + 1) * m]
-            run = None
-            for k in range(max(1, -(-m // block)) + 1):
+            runs = []          # [first block, end block)
+            for k in range(max(1, -(-m // block))):
                 blk = slice(k * block, min((k + 1) * block, m))
-                if k * block < m and not np.array_equal(ra[blk], rb[blk]):
-                    run = blk.start if run is None else run
-                elif run is not None:
-                    out[f"r_{i}_{r * m + run}"] = \
-                        rb[run:min(k * block, m)].copy()
-                    run = None
+                if np.array_equal(ra[blk], rb[blk]):
+                    continue
+                if runs and k - runs[-1][1] <= gap:
+                    runs[-1][1] = k + 1
+                else:
+                    runs.append([k, k + 1])
+            for b0, b1 in runs:
+                out[f"r_{i}_{r * m + b0 * block}"] = \
+                    rb[b0 * block:min(b1 * block, m)].copy()
     return out
 
 
@@ -315,7 +324,7 @@ def test_delta_fetch_gathers_what_plain_numpy_cuts(tmp_path, dtype, tail):
         assert prep["kind"] == "delta"
         _assert_payload_is(prep, _plain_delta(
             [host["s"], host["x"]], [new["s"], new["x"]], [None, None],
-            block))
+            block, whole=() if fits else (1,)))
         # within its capacity the leaf crossed as two blocks' worth,
         # over it whole; the program that gathers was compiled once
         crossed = _moved(reg, "j", "gathered") - before
@@ -385,6 +394,48 @@ def test_delta_fetch_of_a_lane_leaf(tmp_path, m, placed):
         for k in host:
             assert loaded[k].tobytes() == host[k].tobytes()
     assert store._gather_fns["j"][0]._cache_size() == 1
+
+
+def test_strewn_dirty_blocks_are_written_as_few_runs(tmp_path):
+    """A string column's bytes under new rows strewn over its slots: one
+    dirty 512-byte block in three, each a run of its own, would be a ZIP
+    member apiece.  Past its gather capacity the leaf crosses whole and
+    runs closer than ``RUN_GAP_BYTES`` are written as one; runs farther
+    apart stay two.  Every chain loads back byte for byte."""
+    import jax
+    import jax.numpy as jnp
+
+    block = 512
+    store = CheckpointStore(str(tmp_path), keep_epochs=8, block_elems=block)
+    rows = 65536
+    names = (np.arange(rows * 24, dtype=np.int64) % 251).astype(
+        np.uint8).reshape(rows, 24)
+
+    def save(epoch, host):
+        leaves, treedef = jax.tree.flatten([jnp.asarray(host)])
+        prep = store.prepare("j", epoch, leaves, [host.shape], treedef, {})
+        store.commit(prep)
+        return prep
+
+    save(1, names)
+    strewn = names.copy()
+    strewn[::64] ^= 1             # every third block of the first 1,000
+    strewn = strewn.copy()
+    strewn[rows // 4:] = names[rows // 4:]
+    prep = save(2, strewn)
+    assert prep["kind"] == "delta"
+    assert list(prep["payload"]) == ["r_0_0"]
+    assert prep["payload"]["r_0_0"].size < rows * 24 // 4 + block
+    apart = strewn.copy()
+    apart[:1024] += 1             # 48 blocks from the start, and one far
+    apart[-1] += 1
+    prep = save(3, apart)
+    assert prep["kind"] == "delta"
+    last = (rows * 24 // block - 1) * block
+    assert sorted(prep["payload"]) == ["r_0_0", f"r_0_{last}"]
+    for epoch, host in ((2, strewn), (3, apart)):
+        _, loaded, _ = store.load("j", epoch)
+        assert loaded[0].tobytes() == host.tobytes()
 
 
 def test_delta_of_host_arrays_dispatches_no_device_program(

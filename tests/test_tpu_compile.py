@@ -335,3 +335,60 @@ def test_q5_join_maintain(q5_join_job, one_chip):
         widths = _apply_scatter_widths(hlo, "reclaim", ex)
         assert len(widths) >= 4, widths
         assert max(widths.values()) <= hash_table.RECLAIM_TILE, (ex, widths)
+
+
+# -- the q8 cell at its own sizes (benchmark/configs/nexmark_q8.json)
+
+@pytest.fixture(scope="module")
+def nexmark_q8_job():
+    import json
+
+    cfg = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "configs", "nexmark_q8.json")))
+    eng = Engine(RwConfig.from_dict(cfg["server"]["config_json"]))
+    for stmt in cfg["sources_sql"]:
+        eng.execute(stmt)
+    eng.execute(cfg["view"]["sql"])
+    return eng.jobs[0], cfg["system_params"]["chunks_per_barrier"]
+
+
+def _string_leaves(states) -> int:
+    return sum(x.nbytes for x in jax.tree.leaves(states)
+               if x.dtype == jnp.uint8 and x.ndim == 2)
+
+
+def test_nexmark_q8_window(nexmark_q8_job, one_chip):
+    """Both generated sources in one window program, the person's name
+    (``[slots, 24]`` uint8 in the aggregate's key, the keyed join side
+    and the view) taking its logical bytes on the chip: the program's
+    arguments are the state tree, within 1 % of its logical size."""
+    job, rounds = nexmark_q8_job
+    mem = _compile(job._multi_prog(rounds), one_chip, job.states,
+                   {"person": K0, "auction": K0})
+    logical = sum(x.nbytes for x in jax.tree.leaves(job.states))
+    assert _string_leaves(job.states) > 80 << 20
+    assert mem.argument_size_in_bytes < 1.01 * logical
+
+
+def test_nexmark_q8_barrier(nexmark_q8_job, one_chip):
+    """A window close in the barrier program, named: the watermark's
+    ``clean_below`` of both aggregates sits under ``HashAgg.<i>/clean``."""
+    job, _ = nexmark_q8_job
+    _, hlo = _compile(job._make_barrier_prog(), one_chip, job.states,
+                      EPOCH, text=True)
+    assert "HashAgg.0/clean" in hlo
+
+
+def test_nexmark_q8_maintain(nexmark_q8_job, one_chip):
+    """A close's 200 k retired groups are given back by tiles of movers,
+    in both aggregates and the keyed side."""
+    from risingwave_tpu.state import hash_table
+
+    job, _ = nexmark_q8_job
+    _, hlo = _compile(job._make_maintain_prog(), one_chip, job.states,
+                      text=True)
+    for ex in ("HashAgg", "HashJoin"):
+        widths = _apply_scatter_widths(hlo, "reclaim", ex)
+        assert len(widths) >= 4, widths
+        assert max(widths.values()) <= hash_table.RECLAIM_TILE, (ex, widths)

@@ -671,7 +671,8 @@ def test_window_program_names_generator_and_every_executor():
     assert applied(window) == {0, 1, 2}
     assert applied(barrier) - {3} == {4}
     barrier = " ".join(barrier)
-    for phase in ("flush", "watermark", "counters"):
+    # an aggregate's watermark pass is its window close: ``clean``
+    for phase in ("flush", "clean", "counters"):
         assert f"HashAgg.2/{phase}" in barrier
     maintain = " ".join(locs(job.fragment._maintain.lower(job.states)))
     assert "HashAgg.2/reclaim" in maintain
