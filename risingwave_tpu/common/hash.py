@@ -91,6 +91,35 @@ def _key_words(col) -> list[jnp.ndarray]:
     return [col.view(_unsigned_view(col.dtype))]
 
 
+def _str_words(col: StrCol) -> list[jnp.ndarray]:
+    """A string column as ``uint64`` words: its bytes, eight to a word,
+    then its length.  Bytes at and after a row's length count as zero, so
+    a slot reused with stale padding can never split equal strings."""
+    cap, width = col.data.shape
+    words = width // 8 + (1 if width % 8 else 0)
+    padded = jnp.pad(col.data, ((0, 0), (0, words * 8 - width)))
+    byte_idx = jnp.arange(words * 8, dtype=jnp.int32)
+    masked = jnp.where(byte_idx[None, :] < col.lens[:, None], padded, 0)
+    w64 = masked.reshape(cap, words, 8).astype(jnp.uint64)
+    shifts = (np.arange(8, dtype=np.uint64) * 8)
+    folded = jnp.sum(w64 << shifts[None, None, :], axis=-1, dtype=jnp.uint64)
+    return [folded[:, k] for k in range(words)] + [col.lens.astype(jnp.uint64)]
+
+
+def key_words(columns: Sequence) -> list[jnp.ndarray]:
+    """What ``hash64_columns`` folds, unhashed: ``[cap]`` unsigned words
+    that are equal for two rows exactly when their key columns are equal
+    as the hash sees them (a string by its bytes and length, a float
+    canonicalized, a NULL by its flag): what tells apart two keys that
+    share a hash."""
+    out: list[jnp.ndarray] = []
+    for raw in columns:
+        for col in normalize_null_col(raw):
+            out.extend(_str_words(col) if isinstance(col, StrCol)
+                       else _key_words(col))
+    return out
+
+
 def crc32_columns(columns: Sequence, init: int = 0xFFFFFFFF) -> jnp.ndarray:
     """crc32 over the little-endian bytes of each row's key columns.
 
@@ -234,21 +263,13 @@ def hash64_finish(state: jnp.ndarray) -> jnp.ndarray:
 
 def _hash64_one(col, state, seed):
     if isinstance(col, StrCol):
-        cap, width = col.data.shape
+        cap = col.data.shape[0]
         if state is None:
             state = jnp.full((cap,), np.uint64(seed) ^ _MIX_K1, jnp.uint64)
-        # fold 8-byte words; bytes at/after lens are masked to zero so
-        # slot reuse with stale padding can never split equal strings
-        words = width // 8 + (1 if width % 8 else 0)
-        padded = jnp.pad(col.data, ((0, 0), (0, words * 8 - width)))
-        byte_idx = jnp.arange(words * 8, dtype=jnp.int32)
-        masked = jnp.where(byte_idx[None, :] < col.lens[:, None], padded, 0)
-        w64 = masked.reshape(cap, words, 8).astype(jnp.uint64)
-        shifts = (np.arange(8, dtype=np.uint64) * 8)
-        folded = jnp.sum(w64 << shifts[None, None, :], axis=-1, dtype=jnp.uint64)
-        for k in range(words):
-            state = _mix64(state ^ folded[:, k] * _MIX_K1)
-        state = _mix64(state ^ col.lens.astype(jnp.uint64))
+        *folded, lens = _str_words(col)
+        for w in folded:
+            state = _mix64(state ^ w * _MIX_K1)
+        state = _mix64(state ^ lens)
     else:
         for w in _key_words(col):
             u = w.astype(jnp.uint64)

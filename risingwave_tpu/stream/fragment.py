@@ -50,7 +50,8 @@ GAUGE_ATTRS = ("live_groups", "tombstones", "table_slots",
 #: the DAG runtime's vector: ``hash_join_<attr>_total{job,side}`` and
 #: gauges ``hash_join_<attr>{job,side}``; skipped like the aggregate's
 JOIN_TALLY_ATTRS = ("insert_rows", "delete_rows", "probe_steps",
-                    "emit_rows", "cleaned_rows", "reclaim_slots")
+                    "emit_rows", "cleaned_rows", "reclaim_slots",
+                    "sorted_probe_chunks")
 JOIN_GAUGE_ATTRS = ("live_rows", "tombstones", "table_slots")
 #: tallies of a ``HashTable`` probe that runs at a chunk's width on the
 #: barrier path (a view's upsert, a keyed join side): its rounds at that

@@ -52,12 +52,19 @@ the side's changelog (``sql/planner.py`` ``resolve_join``):
   join key is part of its stream key, so that many rows share a key
   (Nexmark q5: 110 k ``(auction, window)`` counts under seven window
   starts).  One row a slot of a ``HashTable`` over the stream key: a
-  retraction finds its row in O(1) by what identifies it, and the other
-  side's change at key *w* finds the rows of *w* by a masked pass over
-  the slots (``key == w``, then the residual predicate), compacted into
-  the emission windows; nothing is ``[chunk, bucket]``.  The cost of a
-  probe is the table, so the planner takes this store only where the
-  other side holds at most a row a key and so sends few rows.
+  retraction finds its row in O(1) by what identifies it; nothing is
+  ``[chunk, bucket]``.  The other side's chunk finds a key's rows in
+  one of two ways.  With no residual, and ``SORTED_PROBE_MIN_ROWS``
+  rows or more to probe (Nexmark q8's sellers), it sorts the slots once
+  by join-key hash, O(size log size), and finds each probe row's run
+  by a binary search, O(log size) a row; pair *j* of a row is the slot
+  at its run's start + *j* (``_keyed_sorted_probe``; tally
+  ``sorted_probe_chunks``).  A run groups the slots by 32 bits of the
+  hash, so it may hold another key: the emission drops a pair whose
+  keys differ.  Otherwise (a residual filters a key's run, or a chunk
+  of few rows) it makes a masked pass over the slots a row (``key ==
+  w``, then the residual), compacted into the emission windows: O(size)
+  a row (Nexmark q5's max side).
 - *dense* (``SideState``): every other retractable side, the
   ``[size, bucket_cap]`` buckets described above.
 
@@ -96,7 +103,7 @@ from risingwave_tpu.common.chunk import (
     conform_col,
     split_col,
 )
-from risingwave_tpu.common.hash import hash64_columns
+from risingwave_tpu.common.hash import hash64_columns, key_words
 
 
 def _null_stripped_keys(key_cols):
@@ -264,6 +271,16 @@ def _group_totals(group: jnp.ndarray, values: jnp.ndarray) -> jnp.ndarray:
 
 #: ring rows a pool side's ``clean`` looks at (and retires) at a time
 CLEAN_TILE = 4096
+#: probe rows from which a chunk sorts a keyed build side (no residual)
+#: instead of passing over its slots once a row.  On the v5e at 2^20
+#: slots the hash, sort and search of a chunk took 2.68 ms, the masked
+#: pass 0.66 ms a matching row (its count, 44 us, and the emission's
+#: search of it, 21 x 29.2 us) and 44 us a row that matches nothing:
+#: even at 4.1 rows, rounded up for chunks whose rows do not all match
+#: (PERF.md §6, the keyed side's sorted probe)
+SORTED_PROBE_MIN_ROWS = 6
+#: the sort key of an empty slot or a NULL key; a key's own is below it
+_SORT_LAST = np.uint32(0xFFFFFFFF)
 
 
 class JoinClean(NamedTuple):
@@ -331,6 +348,9 @@ class KeyedSideState(NamedTuple):
     #: tiles (``fragment.PROBE_ROUND_ATTRS``)
     probe_wide_rounds: jnp.ndarray
     probe_tile_rounds: jnp.ndarray
+    #: chunks of the other side that found their pairs here by a binary
+    #: search of the slots sorted by join-key hash (``_keyed_sorted_probe``)
+    sorted_probe_chunks: jnp.ndarray
 
 
 class PoolSideState(NamedTuple):
@@ -440,8 +460,13 @@ class JoinEmit(NamedTuple):
     probe_hash: jnp.ndarray  # uint64 [cap]
     m: jnp.ndarray           # int32 [cap] live build rows per probe row
     #: first live rank of each probe row's key on a pool build side (its
-    #: lower ranks have retired); zeros for a dense build side
+    #: lower ranks have retired); where its run starts in ``order`` on a
+    #: keyed build side probed by sort; zeros otherwise
     base: jnp.ndarray        # int32 [cap]
+    #: a keyed build side's slots sorted by join-key hash (``[size]``, or
+    #: ``[1]`` where the plan has a residual), read where ``by_sort``
+    order: jnp.ndarray       # int32
+    by_sort: jnp.ndarray     # bool ()
     up_cnt: jnp.ndarray      # int32 [cap] up-transition rows per probe row
     up_end: jnp.ndarray      # int32 [cap] inclusive cumsum
     U: jnp.ndarray           # int32 total up-transition rows
@@ -627,6 +652,7 @@ class HashJoinExecutor:
             reclaim_slots=_zero64(), live_rows=_zero64(),
             tombstones=_zero64(), table_slots=jnp.int64(size),
             probe_wide_rounds=_zero64(), probe_tile_rounds=_zero64(),
+            sorted_probe_chunks=_zero64(),
         )
 
     def _pool_side_state(self, schema: Schema, keys: Sequence[Expr],
@@ -1008,17 +1034,24 @@ class HashJoinExecutor:
             self._pair_chunk(pcols, bcols, side, cap * B))
         return ok.reshape(cap, B)
 
+    def _join_keys(self, cols, side: str):
+        """(``side``'s join-key columns over its columns ``cols``, ``[n]``
+        each, any-key-null mask | None)."""
+        schema = self.left_schema if side == "left" else self.right_schema
+        keys = self.left_keys if side == "left" else self.right_keys
+        n = jax.tree.leaves(cols[0])[0].shape[0]
+        rows = Chunk(cols, jnp.zeros((n,), jnp.int8),
+                     jnp.ones((n,), jnp.bool_), schema)
+        return _null_stripped_keys([e.eval(rows) for e in keys])
+
     def _keyed_matcher(self, probe_cols, key_cols, build: KeyedSideState,
                        side: str):
         """``hit_of(r) -> bool [size]``: the slots of a keyed build side
         that probe row ``r`` pairs with (join keys equal, the residual
         holds): one masked pass over the slots."""
         size = build.table.size
-        bschema = self.right_schema if side == "left" else self.left_schema
-        bkeys = self.right_keys if side == "left" else self.left_keys
-        stored = Chunk(build.rows, jnp.zeros((size,), jnp.int8),
-                       build.table.occupied, bschema)
-        bkey_cols, _ = _null_stripped_keys([e.eval(stored) for e in bkeys])
+        bkey_cols, _ = self._join_keys(
+            build.rows, "right" if side == "left" else "left")
         if self.residual is not None:
             prefs, brefs = self._residual_split(side)
             bcols = [c if i in brefs else None
@@ -1044,6 +1077,67 @@ class HashJoinExecutor:
             return hit
 
         return hit_of
+
+    def _keyed_sorted_probe(self, build: KeyedSideState, side: str,
+                            probe_hash, joinable):
+        """``(m, lo, order, by_sort)`` of a chunk probing a keyed build
+        side with no residual: the slots sorted once by the top 32 bits
+        of their join key's hash (``order``; empty slots and NULL keys
+        last), then each probe row's run ``[lo, lo + m)`` in that order
+        found by a binary search of both its ends.  A run may hold
+        another key that shares those bits: ``m`` bounds the row's
+        pairs, and the emission drops a pair whose keys differ.  Only
+        the hash is a sort key (a sort compared on the key's four 64-bit
+        words did not compile for the v5e in 200 s: PERF.md §6).
+        ``by_sort`` is false, and nothing is sorted, where the chunk has
+        fewer than ``SORTED_PROBE_MIN_ROWS`` rows to probe."""
+        size = build.table.size
+        cap = joinable.shape[0]
+        bkey_cols, bnull = self._join_keys(
+            build.rows, "right" if side == "left" else "left")
+        live = build.table.occupied if bnull is None \
+            else build.table.occupied & ~bnull
+
+        def key32(h):
+            return jnp.minimum((h >> 32).astype(jnp.uint32),
+                               _SORT_LAST - 1)
+
+        want = jnp.concatenate([key32(probe_hash)] * 2)
+        # the first cap searches find where a run starts, the rest where
+        # it ends (the first slot past every key <= the probe's)
+        upper = jnp.arange(2 * cap) >= cap
+
+        def probe(_):
+            k = jnp.where(live, key32(hash64_columns(bkey_cols)),
+                          _SORT_LAST)
+            k, order = jax.lax.sort(
+                (k, jnp.arange(size, dtype=jnp.int32)), num_keys=1)
+
+            def halve(_, bounds):
+                lo, hi = bounds
+                go = lo < hi
+                mid = (lo + hi) // 2
+                v = k[jnp.minimum(mid, size - 1)]
+                right = go & ((v < want) | ((v == want) & upper))
+                return (jnp.where(right, mid + 1, lo),
+                        jnp.where(go & ~right, mid, hi))
+
+            bound, _ = jax.lax.fori_loop(
+                0, size.bit_length(), halve,
+                (jnp.zeros((2 * cap,), jnp.int32),
+                 jnp.full((2 * cap,), size, jnp.int32)))
+            lo = bound[:cap]
+            return (jnp.where(joinable, bound[cap:] - lo, 0), lo, order,
+                    jnp.bool_(True))
+
+        def skip(_):
+            return (jnp.zeros((cap,), jnp.int32),
+                    jnp.zeros((cap,), jnp.int32),
+                    jnp.zeros((size,), jnp.int32), jnp.bool_(False))
+
+        n_probe = jnp.sum(joinable, dtype=jnp.int32)
+        return jax.lax.cond(n_probe >= SORTED_PROBE_MIN_ROWS, probe, skip,
+                            None)
 
     # -- output-centric windowed emission --------------------------------
     def apply_begin(self, state: JoinState, chunk: Chunk, side: str):
@@ -1091,11 +1185,26 @@ class HashJoinExecutor:
             # probe the build (other) side: per-row key slot + live rows
             build_storage = self.storage_of(
                 "right" if side == "left" else "left")
+            order = jnp.zeros((1,), jnp.int32)
+            by_sort = jnp.bool_(False)
+            base = jnp.zeros((cap,), jnp.int32)
             if build_storage == "keyed":
-                # keyed build side: one masked pass over its slots a
-                # probe row (the planner takes this store where the
-                # probing side sends few rows)
                 bsize = other.table.size
+                n_probe = jnp.sum(joinable, dtype=jnp.int32)
+                m = jnp.zeros((cap,), jnp.int32)
+                steps = jnp.zeros((), jnp.int64)
+                if self.residual is None:
+                    # one sort of the build side's slots, then a binary
+                    # search a probe row
+                    m, base, order, by_sort = self._keyed_sorted_probe(
+                        other, side, probe_hash, joinable)
+                    steps = by_sort * (bsize + 2 * bsize.bit_length()
+                                       * n_probe.astype(jnp.int64))
+                    other = other._replace(
+                        sorted_probe_chunks=other.sorted_probe_chunks
+                        + by_sort.astype(jnp.int64))
+                # else (a residual filters a key's run, or a chunk of
+                # few rows): one masked pass over the slots a row
                 sel = mask_indices(joinable, cap, cap)
                 hit_of = self._keyed_matcher(
                     chunk.columns, key_cols, other, side)
@@ -1104,16 +1213,15 @@ class HashJoinExecutor:
                     r = sel[i]
                     return m.at[r].set(jnp.sum(hit_of(r), dtype=jnp.int32))
 
-                n_probe = jnp.sum(joinable, dtype=jnp.int32)
-                m = jax.lax.fori_loop(
-                    0, n_probe, count_row, jnp.zeros((cap,), jnp.int32))
+                m = jax.lax.cond(
+                    by_sort, lambda m: m,
+                    lambda m: jax.lax.fori_loop(0, n_probe, count_row, m), m)
+                steps = steps + jnp.where(
+                    by_sort, 0, n_probe.astype(jnp.int64) * bsize)
                 safe = jnp.zeros((cap,), jnp.int32)
                 probe_over = jnp.zeros((), jnp.int64)
                 rank_to_idx = jnp.zeros((cap, 1), jnp.int32)
-                base = jnp.zeros((cap,), jnp.int32)
-                own2 = own2._replace(
-                    probe_steps=own2.probe_steps
-                    + n_probe.astype(jnp.int64) * bsize)
+                own2 = own2._replace(probe_steps=own2.probe_steps + steps)
             elif build_storage == "pool":
                 # pool build side: ONE fused-table probe of the key's HEAD
                 # entry (hash, 0) yields its degree; rows are addressed at
@@ -1141,7 +1249,6 @@ class HashJoinExecutor:
                 # first, stable: bool sort of the occupancy bitmap only)
                 rank_to_idx = jnp.argsort(~occ, axis=1, stable=True) \
                     .astype(jnp.int32)
-                base = jnp.zeros((cap,), jnp.int32)
 
             # section 1: (probe × build) pairs
             pair_cnt = m if self.emit_pairs else jnp.zeros_like(m)
@@ -1205,6 +1312,8 @@ class HashJoinExecutor:
             probe_hash=probe_hash,
             m=m,
             base=base,
+            order=order,
+            by_sort=by_sort,
             up_cnt=up_cnt,
             up_end=up_end,
             U=U,
@@ -1220,8 +1329,8 @@ class HashJoinExecutor:
         own2 = own2._replace(
             emit_rows=own2.emit_rows + total.astype(jnp.int64))
         new_state = JoinState(
-            left=own2 if side == "left" else state.left,
-            right=own2 if side == "right" else state.right,
+            left=own2 if side == "left" else other,
+            right=own2 if side == "right" else other,
             emit_overflow=state.emit_overflow
             + probe_over.astype(jnp.int64),
             chunks=state.chunks + 1,
@@ -1294,42 +1403,58 @@ class HashJoinExecutor:
         build_rows, build_index = build_rows
         probe_bound = jnp.int64(0)
         if isinstance(build_index, KeyedSideState):
-            # keyed build side: pair j of probe row r is the j-th slot
-            # r's masked pass finds.  The window's pairs belong to a
-            # few consecutive probe rows (those with a match): one pass
-            # each, its running count searched for the ranks wanted
             bsize = build_index.table.size
-            keys = self.left_keys if side == "left" else self.right_keys
-            schema = self.left_schema if side == "left" \
-                else self.right_schema
-            key_cols, _ = _null_stripped_keys([
-                e.eval(Chunk(p.probe_cols, jnp.zeros((cap,), jnp.int8),
-                             jnp.ones((cap,), jnp.bool_), schema))
-                for e in keys])
-            hit_of = self._keyed_matcher(
-                p.probe_cols, key_cols, build_index, side)
-            has = p.m > 0
-            nz_rank = jnp.cumsum(has, dtype=jnp.int32) - 1
-            nz_sel = mask_indices(has, cap, cap)
-            n_in = jnp.sum(in_pairs, dtype=jnp.int32)
-            first = jnp.argmax(in_pairs).astype(jnp.int32)
-            k_lo = nz_rank[r[first]]
-            k_hi = nz_rank[r[jnp.maximum(first + n_in - 1, 0)]]
 
-            def locate(t, bslot):
-                rk = nz_sel[jnp.minimum(k_lo + t, cap - 1)]
-                seen = _scan_slots(
-                    jax.lax.cumsum, jnp.add,
-                    hit_of(rk).astype(jnp.int32), 0)
-                at = jnp.searchsorted(
-                    seen, j + 1, side="left", method="scan")
-                return jnp.where(in_pairs & (r == rk),
-                                 at.astype(jnp.int32), bslot)
+            def by_order():
+                # pair j of probe row r: position base + j of the order,
+                # dropped where the slot's key only shares r's hash bits
+                bslot = p.order[jnp.clip(p.base[r] + j, 0,
+                                         p.order.shape[0] - 1)]
+                bkeys, _ = self._join_keys(
+                    jax.tree.map(lambda x: x[bslot], build_rows),
+                    "right" if side == "left" else "left")
+                pkeys, _ = self._join_keys(
+                    jax.tree.map(lambda x: x[r], p.probe_cols), side)
+                same = jnp.ones((out_cap,), jnp.bool_)
+                for bw, pw in zip(key_words(bkeys), key_words(pkeys)):
+                    same = same & (bw == pw)
+                return bslot, same
 
-            bslot = jax.lax.fori_loop(
-                0, jnp.where(n_in > 0, k_hi - k_lo + 1, 0), locate,
-                jnp.zeros((out_cap,), jnp.int32))
+            def by_pass():
+                # pair j of probe row r is the j-th slot r's masked pass
+                # finds.  The window's pairs belong to a few consecutive
+                # probe rows (those with a match): one pass each, its
+                # running count searched for the ranks wanted
+                key_cols, _ = self._join_keys(p.probe_cols, side)
+                hit_of = self._keyed_matcher(
+                    p.probe_cols, key_cols, build_index, side)
+                has = p.m > 0
+                nz_rank = jnp.cumsum(has, dtype=jnp.int32) - 1
+                nz_sel = mask_indices(has, cap, cap)
+                n_in = jnp.sum(in_pairs, dtype=jnp.int32)
+                first = jnp.argmax(in_pairs).astype(jnp.int32)
+                k_lo = nz_rank[r[first]]
+                k_hi = nz_rank[r[jnp.maximum(first + n_in - 1, 0)]]
+
+                def locate(t, bslot):
+                    rk = nz_sel[jnp.minimum(k_lo + t, cap - 1)]
+                    seen = _scan_slots(
+                        jax.lax.cumsum, jnp.add,
+                        hit_of(rk).astype(jnp.int32), 0)
+                    at = jnp.searchsorted(
+                        seen, j + 1, side="left", method="scan")
+                    return jnp.where(in_pairs & (r == rk),
+                                     at.astype(jnp.int32), bslot)
+
+                return jax.lax.fori_loop(
+                    0, jnp.where(n_in > 0, k_hi - k_lo + 1, 0), locate,
+                    jnp.zeros((out_cap,), jnp.int32)), \
+                    jnp.ones((out_cap,), jnp.bool_)
+
+            bslot, same = by_pass() if self.residual is not None \
+                else jax.lax.cond(p.by_sort, by_order, by_pass)
             bslot = jnp.minimum(bslot, bsize - 1)
+            valid_out = valid_out & (same | ~in_pairs)
 
             def build_val(store):
                 return jax.tree.map(lambda x: x[bslot], store)

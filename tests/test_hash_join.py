@@ -1,9 +1,12 @@
 """Streaming hash-join tests (inner join, retraction, multiset)."""
 
+from collections import Counter
+
 import numpy as np
+import pytest
 
 from risingwave_tpu.common.chunk import Chunk
-from risingwave_tpu.common.types import DataType, Schema
+from risingwave_tpu.common.types import DataType, Field, Schema
 from risingwave_tpu.expr.node import col
 from risingwave_tpu.stream.fragment import Fragment
 from risingwave_tpu.stream.hash_join import HashJoinExecutor, JoinClean
@@ -414,3 +417,183 @@ def test_pool_join_retraction_is_loud():
         - 1 10
     """), "left")
     assert int(st.left.inconsistency) == 1
+
+
+# -- a keyed build side probed by sort (no residual) ---------------------
+
+KL = Schema((Field("k", DataType.INT64, nullable=True),
+             Field("s", DataType.VARCHAR, str_width=8),
+             Field("a", DataType.INT64)))
+KR = Schema((Field("k", DataType.INT64, nullable=True),
+             Field("s", DataType.VARCHAR, str_width=8),
+             Field("b", DataType.INT64)))
+
+
+def _kchunk(schema, rows, ops, cap=32):
+    """A chunk of ``(k | None, s, value)`` rows with op codes ``ops``."""
+    import jax.numpy as jnp
+
+    from risingwave_tpu.common.chunk import NCol, StrCol
+
+    pad = cap - len(rows)
+    rows = list(rows) + [(None, "", 0)] * pad
+    k = NCol(jnp.asarray([r[0] or 0 for r in rows], jnp.int64),
+             jnp.asarray([r[0] is None for r in rows]))
+    data = np.zeros((cap, 8), np.uint8)
+    for i, r in enumerate(rows):
+        data[i, :len(r[1])] = np.frombuffer(r[1].encode(), np.uint8)
+    s = StrCol(jnp.asarray(data),
+               jnp.asarray([len(r[1]) for r in rows], jnp.int32))
+    v = jnp.asarray([r[2] for r in rows], jnp.int64)
+    return Chunk((k, s, v), jnp.asarray(list(ops) + [0] * pad, jnp.int8),
+                 jnp.asarray([True] * (cap - pad) + [False] * pad), schema)
+
+
+def _keyed_steps(case):
+    """``[(side, rows, ops, sorts) | ("reclaim",)]`` for one case: op 0
+    inserts, 1 deletes, 2/3 an update pair; ``sorts``: the chunk finds
+    its pairs by the sorted order (else by the masked pass)."""
+    from risingwave_tpu.stream.hash_join import SORTED_PROBE_MIN_ROWS as K
+
+    hot = [(7, "hot", i) for i in range(20)]
+    base = [("left", hot + [(1, "one", 100), (1, "uno", 101)],
+             [0] * 22, False)]
+    keys = [(7, "hot"), (1, "one"), (2, "two"), (1, "uno"), (7, "hot"),
+            (3, "hot"), (7, "ho"), (1, "one")]
+    # 12 rows: degrees 20, 1, 0, 1, 20, 0, 0, 1, ...; key 7's 60 pairs
+    # span windows of 8
+    big = [(*keys[i % 8], 500 + i) for i in range(12)]
+    probe = ("right", big, [0] * 12, True)
+    small = ("right", big[:K - 1], [0] * (K - 1), False)
+    assert 1 < K <= 12
+    if case == "degrees":
+        return base + [probe, small]
+    if case == "tombstones":
+        # deleted slots tombstoned, then reclaimed (rows move), then new
+        # rows: the probe sorts whatever slots the side holds now
+        return base + [
+            ("left", hot[::2] + [(1, "one", 100)], [1] * 11, False),
+            probe,
+            ("reclaim",),
+            ("left", [(7, "hot", 50), (2, "two", 51), (1, "one", 100)],
+             [0] * 3, False),
+            probe]
+    if case == "deletes":
+        # the probing chunk retracts what it inserted before, and an
+        # update pair moves a row from key 1 to key 7
+        return base + [probe, (
+            "right", big[1:] + [(1, "one", 900), (7, "hot", 900)],
+            [1] * 11 + [2, 3], True)]
+    if case == "nulls":
+        # NULL keys match nothing on either side, and do not count
+        # towards the rows a chunk sorts for
+        return [("left", hot[:3] + [(None, "hot", 9), (1, "one", 100)],
+                 [0] * 5, False),
+                ("right", big + [(None, "hot", 1), (None, "", 3)],
+                 [0] * 14, True),
+                ("right", big[:K - 1] + [(None, "hot", 1)], [0] * K,
+                 False)]
+    if case == "empty":
+        return base + [("right", [], [], False)]
+    assert case == "collisions"
+    # the hash reads the first key column only: (1, "one") and (1, "uno")
+    # of the build side share a hash, so their rows interleave in one
+    # run, and the probe's (7, "ho") finds the run of (7, "hot"); each
+    # probe row pairs with its own key's rows only
+    return base + [probe]
+
+
+def _keyed_joins():
+    """{"sorted": (join, begin, emit), "masked": ...}: a keyed left side
+    probed with no residual, and with one that holds wherever the keys
+    are equal (which keeps the masked pass); ``begin`` and ``emit``
+    jitted, so that a step compiles nothing new."""
+    import jax
+
+    from risingwave_tpu.expr.node import InputRef
+
+    out = {}
+    for name, residual in (("sorted", None),
+                           ("masked", InputRef(0) == InputRef(3))):
+        j = HashJoinExecutor(
+            KL, KR, [col("k"), col("s")], [col("k"), col("s")],
+            table_size=64, bucket_cap=8, out_capacity=8,
+            left_storage="keyed", left_row_key=[0, 1, 2])
+        j.residual = residual
+        out[name] = (j, jax.jit(j.apply_begin, static_argnums=2),
+                     jax.jit(lambda st, p, w, side, j=j: j.emit_window(
+                         j.build_rows_of(st, side), p, w, side),
+                         static_argnums=3))
+    return out
+
+
+@pytest.fixture(scope="module")
+def keyed_joins():
+    return _keyed_joins()
+
+
+@pytest.mark.parametrize("case", [
+    "degrees", "tombstones", "deletes", "nulls", "empty", "collisions"])
+def test_keyed_build_side_probe(case, keyed_joins, monkeypatch):
+    """A keyed left side probed by the right side with no residual:
+    what each chunk emits equals a brute-force join of what the sides
+    held and the masked pass (the path a residual keeps), through
+    emission windows of 8 rows; the side counts each chunk that found
+    its pairs by the sorted order."""
+    import jax.numpy as jnp
+
+    from risingwave_tpu.stream import hash_join
+
+    joins = keyed_joins
+    if case == "collisions":
+        plain = hash_join.hash64_columns
+        monkeypatch.setattr(hash_join, "hash64_columns",
+                            lambda cols: plain(list(cols)[:1]))
+        joins = _keyed_joins()
+    states = {n: j.init_state() for n, (j, _, _) in joins.items()}
+    left: Counter = Counter()
+    right: Counter = Counter()
+    sorted_chunks = 0
+
+    def norm(row):
+        return tuple(x.item() if hasattr(x, "item") else x for x in row)
+
+    for step in _keyed_steps(case):
+        if step == ("reclaim",):
+            for n, (j, _, _) in joins.items():
+                states[n] = j.maybe_rehash(states[n])
+                assert int(states[n].left.tombstones) > 0
+            continue
+        side, rows, ops, sorts = step
+        want: Counter = Counter()
+        for r, o in zip(rows, ops):
+            if r[0] is None:
+                continue
+            op = 0 if o in (0, 3) else 1
+            held = left if side == "right" else right
+            for h, n in held.items():
+                if h[:2] == r[:2]:
+                    want[(op,) + ((*h, *r) if side == "right"
+                                  else (*r, *h))] += n
+        for r, o in zip(rows, ops):
+            if r[0] is not None:
+                (left if side == "left" else right)[r] += \
+                    1 if o in (0, 3) else -1
+        left, right = +left, +right
+        chunk = _kchunk(KL if side == "left" else KR, rows, ops)
+        for n, (j, begin, emit) in joins.items():
+            st, pending = begin(states[n], chunk, side)
+            assert bool(pending.by_sort) == (sorts and n == "sorted")
+            got: Counter = Counter()
+            for w in range(-(-int(pending.total) // 8) or 1):
+                win, bound = emit(st, pending, jnp.int32(w), side)
+                assert int(bound) == 0
+                got.update(norm(r) for r in win.to_rows())
+            assert got == want, (case, n, step)
+            states[n] = st
+        sorted_chunks += sorts
+    for n, st in states.items():
+        assert int(st.left.inconsistency) == 0 and int(st.left.overflow) == 0
+        assert int(st.emit_overflow) == 0
+    assert int(states["sorted"].left.sorted_probe_chunks) == sorted_chunks
+    assert int(states["masked"].left.sorted_probe_chunks) == 0

@@ -111,6 +111,11 @@ def test_q5_view_equals_reference_past_window_and_lag(accel_tuned):
         assert int(side.cleaned_rows) > 0 and int(side.delete_rows) > 0
     assert isinstance(join.left, KeyedSideState)
     assert int(join.left.table.tombstone_count()) == 0
+    # the residual filters a key's run, so the max side keeps the masked
+    # pass and never sorts the keyed side
+    assert int(join.left.sorted_probe_chunks) == 0
+    assert eng.metrics.get("hash_join_sorted_probe_chunks_total",
+                           job="q5", side="left") == 0
     # the max holds a group a window still open, and its materialised
     # input the distinct counts of those windows: cleaned, not grown
     (top,) = [st for st in aggs if st.minput_tables]

@@ -141,6 +141,11 @@ def test_q8_view_equals_reference_past_two_closes(accel_tuned, seeded, seed):
     # generator drew them ~2e10 ids past anyone)
     per_window = len(want_rows) / len({r[2] for r in want_rows})
     assert per_window > 100
+    # the join has no residual: A's chunks find their persons in P by one
+    # sort of P's slots each, not by a masked pass over them a row
+    assert int(join.left.sorted_probe_chunks) > 0
+    assert eng.metrics.get("hash_join_sorted_probe_chunks_total",
+                           job="nexmark_q8", side="left") > 0
     for st in _states(job, AggState) + [join.left, join.right]:
         assert int(st.overflow) == 0 and int(st.inconsistency) == 0
 

@@ -373,11 +373,15 @@ def test_nexmark_q8_window(nexmark_q8_job, one_chip):
 
 def test_nexmark_q8_barrier(nexmark_q8_job, one_chip):
     """A window close in the barrier program, named: the watermark's
-    ``clean_below`` of both aggregates sits under ``HashAgg.<i>/clean``."""
+    ``clean_below`` of both aggregates sits under ``HashAgg.<i>/clean``;
+    A's chunks find P's rows by one sort of the keyed side's 2^20 slots
+    under ``HashJoin.<i>/probe`` (PERF.md §6)."""
     job, _ = nexmark_q8_job
     _, hlo = _compile(job._make_barrier_prog(), one_chip, job.states,
                       EPOCH, text=True)
     assert "HashAgg.0/clean" in hlo
+    assert re.search(r'= \(u32\[1048576\][^\n]* sort\([^\n]*op_name="'
+                     r'[^"]*HashJoin\.\d+/probe/', hlo)
 
 
 def test_nexmark_q8_maintain(nexmark_q8_job, one_chip):
